@@ -127,7 +127,7 @@ def _manifest_counters(outdir: str) -> dict:
 def run_smoke(timeout_s: float = 600.0, verbose: bool = True) -> int:
     """Returns 0 on success; raises on any failed leg."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GOLEFT_TPU_PROBE="0")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("GOLEFT_TPU_FAULTS", None)  # hermetic (leg 3 adds it)
     from ..io.remote_stub import StubServer
 

@@ -69,7 +69,7 @@ def _sharded_gram_fn(mesh):
     """shard_map'd version of the Gram step: rows split over the
     ``data`` axis, partials psummed on device — one collective instead
     of a host gather per chunk."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(chunk, mean, q):
@@ -141,21 +141,18 @@ def sharded_pca(chunks_fn, k: int = 5, *, iters: int = 32,
 
         mesh = Mesh(np.asarray(jax.local_devices()), ("data",))
     if mesh is not None and np.prod(mesh.devices.shape) > 1:
-        try:
-            sharded = _sharded_gram_fn(mesh)
-            n_dev = int(np.prod(mesh.devices.shape))
+        sharded = _sharded_gram_fn(mesh)
+        n_dev = int(np.prod(mesh.devices.shape))
 
-            def gram(chunk, mean_a, q):  # noqa: F811 — sharded override
-                rows = chunk.shape[0]
-                pad = (-rows) % n_dev
-                if pad:
-                    # pad with mean rows: centered contribution is zero
-                    chunk = np.concatenate(
-                        [chunk, np.broadcast_to(mean_a, (pad,) +
-                                                mean_a.shape)], axis=0)
-                return sharded(chunk, mean_a, q)
-        except Exception:  # noqa: BLE001 — shard_map unavailable: jit path
-            gram = _chunk_gram
+        def gram(chunk, mean_a, q):  # noqa: F811 — sharded override
+            rows = chunk.shape[0]
+            pad = (-rows) % n_dev
+            if pad:
+                # pad with mean rows: centered contribution is zero
+                chunk = np.concatenate(
+                    [chunk, np.broadcast_to(mean_a, (pad,) +
+                                            mean_a.shape)], axis=0)
+            return sharded(chunk, mean_a, q)
 
     # ---- block power iteration on the Gram operator ----
     rng = np.random.default_rng(seed)

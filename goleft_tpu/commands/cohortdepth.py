@@ -27,6 +27,7 @@ from ..io.bai import read_bai, query_voffset
 from ..io.bam import open_bam_file
 from .depth import _decode_shard_segments
 from ..io.fai import read_fai, write_fai
+from ..obs import get_registry
 from ..ops.coverage import bucket_size, window_bounds
 from ..utils.decode_scaling import auto_processes, effective_cores
 from ..ops.depth_pipeline import shard_depth_pipeline
@@ -268,9 +269,7 @@ def cohort_matrix_blocks(
     sharding = None
     S_pad = S
     if engine != "hybrid":
-        from ..utils.device_guard import devices_with_watchdog
-
-        devs = devices_with_watchdog()
+        devs = jax.devices()
         n_dev = len(devs)
         if n_dev > 1:
             from jax.sharding import Mesh, NamedSharding, \
@@ -427,6 +426,20 @@ def cohort_matrix_blocks(
             keep[i, :n] = True  # pre-filtered in decode()
         return seg_s, seg_e, keep
 
+    def put_sharded(args):
+        """Place one packed batch across the mesh's devices, and say
+        in the metrics where it landed: how many devices hold a shard
+        and how many sample rows each holds (S_pad rows on every one
+        would be copies, not a split)."""
+        args = tuple(jax.device_put(a, sharding) for a in args)
+        shards = args[0].addressable_shards
+        reg = get_registry()
+        reg.gauge("cohortdepth.batch_devices").set(
+            len({sh.device for sh in shards}))
+        reg.gauge("cohortdepth.batch_shard_rows").set(
+            shards[0].data.shape[0])
+        return args
+
     def run_pipeline(args, c, s, e):
         w0 = s // window * window
         sums = np.asarray(_batched_pipeline(
@@ -446,7 +459,7 @@ def cohort_matrix_blocks(
                     pending = submit_decodes(ex, *compute_regions[ri + 1])
                 args = pack_segblock(segs)
                 if sharding is not None:
-                    args = tuple(jax.device_put(a, sharding) for a in args)
+                    args = put_sharded(args)
                 yield run_pipeline(args, c, s, e)
 
     # ---- prefetched variants: the async staging pipeline ----
@@ -472,7 +485,7 @@ def cohort_matrix_blocks(
             # asynchronous dispatch on the producer thread: the H2D
             # copy of shard k+1 overlaps shard k's compute
             if sharding is not None:
-                return tuple(jax.device_put(a, sharding) for a in args)
+                return put_sharded(args)
             return tuple(jax.device_put(a) for a in args)
 
     def blocks_prefetched():

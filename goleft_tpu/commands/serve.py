@@ -1,7 +1,9 @@
 """serve: the long-running warm-mesh coverage daemon.
 
-Dispatch brings the backend up once (under the device_guard probe +
-watchdog like every device command); from then on each request reuses
+Dispatch takes the backend once (utils/device_guard.take_backend, like
+every device command: without a chip, and without the CPU asked for,
+the worker exits non-zero before it announces a port); from then on
+each request reuses
 the live mesh and the process-wide jit cache — no per-invocation
 bring-up, no cold compiles after the first request of each geometry.
 Concurrent requests micro-batch into coalesced device passes

@@ -764,8 +764,7 @@ def run_chaos(timeout_s: float = 900.0, verbose: bool = True) -> int:
     any failed leg."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     env = dict(os.environ,
-               JAX_PLATFORMS="cpu",     # CI has no accelerator
-               GOLEFT_TPU_PROBE="0")    # don't pay a probe timeout
+               JAX_PLATFORMS="cpu")     # CI has no accelerator
     env.pop("GOLEFT_TPU_FAULTS", None)  # hermetic
     t0 = time.monotonic()
     with tempfile.TemporaryDirectory(prefix="goleft_chaos_") as d:
@@ -786,8 +785,7 @@ def run_smoke(timeout_s: float = 600.0, verbose: bool = True) -> int:
     """Returns 0 on success; raises on any failed step."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     env = dict(os.environ,
-               JAX_PLATFORMS="cpu",     # CI has no accelerator
-               GOLEFT_TPU_PROBE="0")    # don't pay a probe timeout
+               JAX_PLATFORMS="cpu")     # CI has no accelerator
     env.pop("GOLEFT_TPU_FAULTS", None)  # hermetic
     t0 = time.monotonic()
     with tempfile.TemporaryDirectory(prefix="goleft_fleet_") as d:

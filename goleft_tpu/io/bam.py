@@ -957,6 +957,11 @@ class BamWriter:
         body += bytes(packed) + b"\xff" * l_seq
         self._w.write(struct.pack("<i", len(body)) + body)
 
+    def write_encoded(self, records: bytes) -> None:
+        """Append records that are already BAM-encoded (each with its
+        block_size prefix) — bulk fixtures build them as one array."""
+        self._w.write(records)
+
     def close(self) -> None:
         self._w.close()
 

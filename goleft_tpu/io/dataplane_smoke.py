@@ -339,7 +339,6 @@ def run_smoke(timeout_s: float = 900.0, verbose: bool = True) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",     # CI has no accelerator
-               GOLEFT_TPU_PROBE="0",    # don't pay a probe timeout
                # cache replication is authenticated (pushes carry an
                # HMAC keyed by the shared fleet secret); the fleets
                # and the federation all inherit this env

@@ -90,7 +90,7 @@ def run_smoke(timeout_s: float = 480.0, verbose: bool = True) -> int:
     from ..mapping.pipeline import parse_tuples
 
     t_start = time.monotonic()
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GOLEFT_TPU_PROBE="0")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     with tempfile.TemporaryDirectory(prefix="goleft_mapsmk_") as d:
         ref, fastq, truth = _make_fixture(d)
         tuples_p = os.path.join(d, "tuples.tsv")

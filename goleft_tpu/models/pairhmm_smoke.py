@@ -78,7 +78,7 @@ def run_smoke(timeout_s: float = 180.0, verbose: bool = True) -> int:
     from ..models.candidates import read_candidates
     from ..serve.client import ServeClient
 
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GOLEFT_TPU_PROBE="0")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     deadline = time.monotonic() + timeout_s
 
     def run_cli(*args):

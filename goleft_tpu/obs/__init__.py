@@ -13,9 +13,6 @@ prefetched cohort, warm serve batch):
   - :mod:`~goleft_tpu.obs.manifest` — the per-run evidence document
   - :mod:`~goleft_tpu.obs.logging` — ``goleft-tpu.*`` logger tree +
     the CLI's ``--log-level`` config
-  - :mod:`~goleft_tpu.obs.ledger` / :mod:`~goleft_tpu.obs.sentinel` —
-    the longitudinal perf ledger (``PERF_LEDGER.jsonl``) and the
-    regression sentinel behind ``goleft-tpu perf``
   - :mod:`~goleft_tpu.obs.prometheus` — text-exposition rendering of
     a registry snapshot (the serve daemon's ``/metrics?format=prom``)
 
@@ -142,7 +139,7 @@ class InstrumentedDispatch:
         from .memplane import TRACKER as MEM_TRACKER
 
         family = family_of_dispatch(self._obs_name)
-        cache_size = getattr(self.__wrapped__, "_cache_size", None)
+        cache_size = self.__wrapped__._cache_size
         # the memory plane shares this seam: buffers born during the
         # dispatch are attributed to its family (a bare yield until a
         # sampler arms the tracker)

@@ -215,10 +215,9 @@ class CompileTracker:
                           trigger or family)
         size0 = None
         if cache_size_fn is not None:
-            try:
-                size0 = int(cache_size_fn())
-            except Exception:  # noqa: BLE001 — private-ish jax API
-                size0 = None
+            # jit._cache_size is private jax API: if an upgrade drops
+            # it this raises, it never goes quiet
+            size0 = int(cache_size_fn())
         self._ctx.stack.append(ob)
         t0 = time.perf_counter()
         try:
@@ -228,10 +227,7 @@ class CompileTracker:
             self._ctx.stack.pop()
             delta = 0
             if size0 is not None:
-                try:
-                    delta = max(0, int(cache_size_fn()) - size0)
-                except Exception:  # noqa: BLE001 — same API caveat
-                    delta = 0
+                delta = max(0, int(cache_size_fn()) - size0)
             # one compile seen by both detectors is ONE compile
             n = max(delta, len(ob.log_names))
             self._record(ob, n, t0, t1)
@@ -428,7 +424,9 @@ def ensure_log_hook() -> bool:
     ``jax_log_compiles=True``, a WARNING handler on logger "jax" with
     ``propagate=False`` (count quietly, don't spray stderr), and the
     ``jax._src.dispatch`` logger disabled (jax_log_compiles also
-    elevates its per-op "Finished tracing/MLIR/XLA" chatter).
+    elevates its per-op "Finished tracing/MLIR/XLA" chatter), and
+    ``jax.monitoring`` listeners feeding ``xla.compile_seconds_total``
+    / ``xla.cache_hits_total`` / ``xla.cache_misses_total``.
     ``GOLEFT_TPU_NO_COMPILE_HOOK=1`` opts out entirely."""
     global _HOOK
     if _HOOK is not None:
@@ -442,10 +440,7 @@ def ensure_log_hook() -> bool:
             return True
         import jax
 
-        try:
-            jax.config.update("jax_log_compiles", True)
-        except Exception:  # noqa: BLE001 — config drift: degrade to
-            return False   # the cache-delta detector only
+        jax.config.update("jax_log_compiles", True)
         lg = logging.getLogger("jax")
         if lg.level > logging.WARNING or lg.level == logging.NOTSET:
             lg.setLevel(logging.WARNING)
@@ -463,6 +458,26 @@ def ensure_log_hook() -> bool:
                     and getattr(other, "stream", None) is sys.stderr:
                 lg.removeHandler(other)
         logging.getLogger("jax._src.dispatch").disabled = True
+        # jax's own monitoring feed, for what the log text cannot say:
+        # seconds spent in the backend compiler (or in fetching the
+        # program from the persistent cache instead), and whether the
+        # persistent cache answered — seam or no seam around the jit
+        reg = get_registry()
+
+        def _on_event(event, **_kw):
+            if event == "/jax/compilation_cache/cache_hits":
+                reg.counter("xla.cache_hits_total").inc()
+            elif event == "/jax/compilation_cache/cache_misses":
+                reg.counter("xla.cache_misses_total").inc()
+
+        def _on_duration(event, duration, **_kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                reg.counter("xla.compile_seconds_total").inc(
+                    round(duration, 6))
+
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration)
         _HOOK = h
     return True
 

@@ -233,8 +233,7 @@ def _leg_events_journal(router_url, journal, verbose):
 def run_smoke(timeout_s: float = 600.0, verbose: bool = True) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     env = dict(os.environ,
-               JAX_PLATFORMS="cpu",     # CI has no accelerator
-               GOLEFT_TPU_PROBE="0")    # don't pay a probe timeout
+               JAX_PLATFORMS="cpu")     # CI has no accelerator
     env.pop("GOLEFT_TPU_FAULTS", None)  # hermetic
     from ..resilience.smoke import _make_cohort
 

@@ -27,8 +27,8 @@ REQUIRED_KEYS = ("schema", "ts", "argv", "env", "backend", "spans",
                  "metrics", "trace_id")
 
 #: current writer version. Minor bumps (1.x) ADD fields and must stay
-#: readable by every 1.* consumer (the perf ledger ingests manifests
-#: from many rounds); a major bump means the REQUIRED_KEYS contract
+#: readable by every 1.* consumer (readers meet manifests from many
+#: versions); a major bump means the REQUIRED_KEYS contract
 #: itself changed and old readers must refuse loudly.
 SCHEMA_PREFIX = "goleft-tpu.run-manifest/"
 SCHEMA_MAJOR = 1
@@ -109,14 +109,14 @@ def write_manifest(path: str, **kw) -> dict:
 
 
 def load_manifest(path: str) -> dict:
-    """Parse + validate a manifest (the bench's and the perf ledger's
-    ingestion entry): the REQUIRED_KEYS must be present and the
+    """Parse + validate a manifest (the bench's ingestion entry): the
+    REQUIRED_KEYS must be present and the
     backend block must carry either provenance fields or an explicit
     error.
 
     Schema policy: any ``goleft-tpu.run-manifest/1.x`` revision loads
-    (minor revisions only add fields — ledger ingestion must survive
-    manifests written by future rounds); a different major is rejected
+    (minor revisions only add fields — a reader must survive
+    manifests written by later versions); a different major is rejected
     with a clear error instead of being half-parsed.
     """
     with open(path) as fh:

@@ -187,7 +187,7 @@ def _leg_supervisor_recycle(verbose):
     """Leg 3: a fleet with --mem-recycle-mb below the worker's
     baseline recycles it; the memory_recycle event survives into the
     journal and the real events CLI."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GOLEFT_TPU_PROBE="0")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("GOLEFT_TPU_FAULTS", None)
     cap_mb = 64.0  # far below any live worker's baseline
     with tempfile.TemporaryDirectory(prefix="goleft_memsmk_") as d:

@@ -21,7 +21,7 @@ def backend_provenance(refresh: bool = False) -> dict:
     Cached after the first successful look: the answer cannot change
     within a process, and the hot device-span path reads it per
     dispatch. NOTE: calling this initializes the jax backend — CLI
-    paths only reach it after device_guard bring-up.
+    device commands only reach it after ``take_backend``.
     """
     global _cached
     with _lock:

@@ -76,8 +76,7 @@ def run_smoke(timeout_s: float = 180.0, verbose: bool = True) -> int:
     from .manifest import load_manifest
 
     env = dict(os.environ,
-               JAX_PLATFORMS="cpu",     # CI has no accelerator;
-               GOLEFT_TPU_PROBE="0")    # don't pay a probe timeout
+               JAX_PLATFORMS="cpu")     # CI has no accelerator
     with tempfile.TemporaryDirectory(prefix="goleft_obs_") as d:
         bam, fai = _make_fixture(d)
         trace_p = os.path.join(d, "trace.json")

@@ -85,8 +85,7 @@ def _run(args, env, timeout_s):
 def run_smoke(timeout_s: float = 240.0, verbose: bool = True) -> int:
     """Returns 0 on success; raises on any failed step."""
     env = dict(os.environ,
-               JAX_PLATFORMS="cpu",     # CI has no accelerator;
-               GOLEFT_TPU_PROBE="0")    # don't pay a probe timeout
+               JAX_PLATFORMS="cpu")     # CI has no accelerator
     with tempfile.TemporaryDirectory(prefix="goleft_dec_") as d:
         crams, fai = make_cram_cohort(d)
         base_cmd = [sys.executable, "-m", "goleft_tpu", "cohortdepth",

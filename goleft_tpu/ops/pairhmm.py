@@ -55,13 +55,6 @@ compiles O(#buckets) programs, not O(#shapes). ``forward_pairs`` is
 the host entry: encode → bucket → per-bucket dispatch (the
 ``pairhmm`` fault-injection site, retried under a RetryPolicy) →
 scatter back to input order.
-
-A Pallas inner-loop variant (``pallas_forward_bucket``) mirrors
-ops/pallas_coverage.py's pattern — one pair per sequential grid step,
-diagonal buffers live in VMEM as (1, Rpad) lane vectors, the
-haplotype diagonal maintained by a shift-in register instead of a
-per-step gather. EXPERIMENTAL like its coverage sibling: correctness
-is pinned in interpret mode; the XLA wavefront is the product path.
 """
 
 from __future__ import annotations
@@ -326,7 +319,7 @@ def forward_pairs(reads, quals, haps, *,
     bucketed, each bucket runs one vmapped wavefront dispatch — the
     ``pairhmm`` fault-injection site, executed under ``policy`` (a
     resilience.RetryPolicy; None = the default retry-once policy) so
-    transient device/tunnel faults are re-attempted. A permanently
+    transient device faults are re-attempted. A permanently
     failing bucket raises resilience.RetriesExhausted with NaN left in
     its slots only if ``policy`` is given with ``allow_partial`` via
     :func:`forward_pairs_partial` (the quarantine path callers use).
@@ -402,8 +395,7 @@ def forward_pairs_partial(reads, quals, haps, *,
                     signature={"r_pad": r_pad, "h_pad": h_pad,
                                "b": b, "rescale": rescale,
                                "dtype": dtype.name},
-                    cache_size_fn=lambda: getattr(
-                        _FORWARD_JIT, "_cache_size", lambda: 0)()
+                    cache_size_fn=lambda: _FORWARD_JIT._cache_size()
                     if _FORWARD_JIT is not None else 0,
                     trigger="pairhmm_forward"):
                 contribs, shifts = obs.dispatch(
@@ -431,172 +423,3 @@ def forward_pairs_partial(reads, quals, haps, *,
 def total_cells(reads, haps) -> int:
     """DP cell count Σ |read|·|hap| — the GCUPS denominator."""
     return int(sum(len(r) * len(h) for r, h in zip(reads, haps)))
-
-
-# ---------------------------------------------------------------------------
-# Pallas inner-loop variant (EXPERIMENTAL — see module docstring)
-
-_LANES = 128
-
-
-def pallas_forward_bucket(reads_p, pm, px, rlens, haps, hlens, trans,
-                          interpret: bool = False):
-    """The wavefront's inner loop as a Pallas TPU kernel: one pair per
-    sequential grid step, the three diagonal buffers held as (1, Rpad)
-    lane vectors in registers/VMEM, and the haplotype anti-diagonal
-    maintained by a shift-in register (hb'[i] = hb[i-1], new base
-    entering at lane 0) instead of a per-step gather — the same
-    VMEM-resident carry pattern ops/pallas_coverage.py establishes.
-
-    Array layout matches :func:`_forward_bucket_impl` except lanes pad
-    to 128 (host side pads; extra lanes are masked like any other
-    padding). f32 only, always rescaled. Returns (contribs (B, S),
-    shifts (B, S) int32) with S = r1 + hcap padded to a lane multiple
-    — feed them to the same host-side f64 fold as the XLA path.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, r1 = reads_p.shape
-    hcap = haps.shape[1]
-    rpad = ((r1 + _LANES - 1) // _LANES) * _LANES
-    hpad = ((hcap + _LANES - 1) // _LANES) * _LANES
-    spad = ((r1 + hcap + _LANES - 1) // _LANES) * _LANES
-
-    def pad_lanes(a, width, fill):
-        out = np.full((b, width), fill, a.dtype)
-        out[:, :a.shape[1]] = a
-        return out
-
-    reads32 = pad_lanes(reads_p.astype(np.int32), rpad, int(N_CODE))
-    pm_p = pad_lanes(np.asarray(pm, np.float32), rpad, 0.0)
-    px_p = pad_lanes(np.asarray(px, np.float32), rpad, 0.0)
-    haps32 = pad_lanes(haps.astype(np.int32), hpad, int(N_CODE))
-    lens = np.stack([np.asarray(rlens, np.int32),
-                     np.asarray(hlens, np.int32)], axis=1)
-    tr = np.asarray(trans, np.float32).reshape(1, -1)
-    below = np.float32(2.0 ** -SCALE_EXP)
-    above = np.float32(2.0 ** SCALE_EXP)
-    f_up = np.float32(2.0 ** SCALE_EXP)
-    f_dn = np.float32(2.0 ** -SCALE_EXP)
-
-    def kernel(lens_ref, read_ref, pm_ref, px_ref, hap_ref, tr_ref,
-               out_ref):
-        rlen = lens_ref[0, 0]
-        hlen = lens_ref[0, 1]
-        t_mm = tr_ref[0, 0]
-        t_mi = tr_ref[0, 1]
-        t_im = tr_ref[0, 2]
-        t_ii = tr_ref[0, 3]
-        ii = jax.lax.broadcasted_iota(jnp.int32, (1, rpad), 1)
-        read = read_ref[0][None, :]
-        pmv = pm_ref[0][None, :]
-        pxv = px_ref[0][None, :]
-        inv_h = 1.0 / hlen.astype(jnp.float32)
-        zero_row = jnp.zeros((1, rpad), jnp.float32)
-        zero_i = jnp.zeros((1, rpad), jnp.int32)
-
-        def shift1(x):
-            return jnp.concatenate([x[:, :1] * 0, x[:, :-1]], axis=1)
-
-        def scale_fix(s_to, s_from):
-            d = jnp.clip(s_to - s_from, _DMIN, _DMAX)
-            return jnp.exp2((SCALE_EXP * d).astype(jnp.float32))
-
-        def step(k, carry):
-            m1, i1, d1, s1, m2, i2, d2, s2, hb, cs, ss = carry
-            # shift-in: lane i takes lane i-1's hap base; hap[k-1]
-            # (the diag's new j=k position, clamped+masked) enters
-            new_hb = jnp.where(
-                k - 1 < hlen,
-                pl.load(hap_ref,
-                        (pl.ds(0, 1),
-                         pl.ds(jnp.minimum(k - 1, hcap - 1), 1)))[0, 0],
-                jnp.int32(N_CODE))
-            hb = jnp.concatenate(
-                [jnp.full((1, 1), new_hb, jnp.int32), hb[:, :-1]],
-                axis=1)
-            jj = k - ii
-            valid = ((ii >= 1) & (ii <= rlen)
-                     & (jj >= 1) & (jj <= hlen))
-            is_match = ((read == hb) | (read == N_CODE)
-                        | (hb == N_CODE))
-            prior = jnp.where(is_match, pmv, pxv)
-            mk = prior * ((t_mm * shift1(m2) + t_im * shift1(i2)
-                           + t_im * shift1(d2))
-                          * scale_fix(s1, shift1(s2)))
-            ik = ((t_mi * shift1(m1) + t_ii * shift1(i1))
-                  * scale_fix(s1, shift1(s1)))
-            dk = t_mi * m1 + t_ii * d1
-            mk = jnp.where(valid, mk, 0.0)
-            ik = jnp.where(valid, ik, 0.0)
-            dk = jnp.where(valid, dk, 0.0)
-            d0 = jnp.where(k <= hlen, inv_h, 0.0)
-            dk = jnp.where(ii == 0, d0, dk)
-            live = (k - rlen >= 1) & (k - rlen <= hlen)
-            sel = ((ii == rlen) & (jj >= 1) & (jj <= hlen))
-            contrib = jnp.where(
-                live,
-                jnp.sum(jnp.where(sel, mk + ik, 0.0),
-                        dtype=jnp.float32),
-                jnp.float32(0.0))
-            s_r = jnp.sum(jnp.where(ii == rlen, s1, 0),
-                          dtype=jnp.int32)
-            # per-step emission: the host folds (contrib, scale)
-            # pairs with an exact f64 log-sum-exp, like the XLA path
-            cs = jax.lax.dynamic_update_slice(
-                cs, contrib.reshape(1, 1), (0, k))
-            ss = jax.lax.dynamic_update_slice(
-                ss, s_r.reshape(1, 1), (0, k))
-            mx = jnp.maximum(jnp.maximum(mk, ik), dk)
-            grow = ((mx > 0.0) & (mx < below)).astype(jnp.int32)
-            shrink = (mx > above).astype(jnp.int32)
-            f = jnp.where(grow == 1, f_up,
-                          jnp.where(shrink == 1, f_dn,
-                                    jnp.float32(1.0)))
-            s_base = s1 + grow - shrink
-            # zero lanes adopt the left neighbor's scale (see the XLA
-            # wavefront: keeps entering lanes at their feeder's scale)
-            s_new = jnp.where(mx > 0.0, s_base, shift1(s_base))
-            return (mk * f, ik * f, dk * f, s_new, m1, i1, d1, s1,
-                    hb, cs, ss)
-
-        d_init = jnp.where(ii == 0, inv_h, 0.0)
-        hb0 = jnp.full((1, rpad), jnp.int32(N_CODE))
-        init = (zero_row, zero_row, d_init, zero_i, zero_row,
-                zero_row, zero_row, zero_i, hb0,
-                jnp.zeros((1, spad), jnp.float32),
-                jnp.zeros((1, spad), jnp.int32))
-        out = jax.lax.fori_loop(1, r1 + hcap, step, init)
-        out_ref[0] = jnp.concatenate(
-            [out[9], out[10].astype(jnp.float32)], axis=0)
-
-    res = pl.pallas_call(
-        kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda t: (t, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, rpad), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, rpad), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, rpad), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, hpad), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8), lambda t: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 2, spad), lambda t: (t, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, 2, spad), jnp.float32),
-        interpret=interpret,
-    )(lens, reads32, pm_p, px_p, haps32,
-      np.concatenate([tr, np.zeros((1, 8 - tr.shape[1]), np.float32)],
-                     axis=1))
-    contribs = np.asarray(res[:, 0, :])
-    shifts = np.asarray(res[:, 1, :]).astype(np.int32)
-    return contribs, shifts

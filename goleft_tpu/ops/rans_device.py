@@ -66,12 +66,6 @@ an error), visible via ``decode.bucket_signatures`` /
 ``decode.bucket_cap_fallback_total`` and one log line when the cap
 first trips.
 
-An experimental Pallas variant (``pallas_decode0``) mirrors
-ops/pallas_coverage.py — one block per sequential grid step, lanes as
-a VMEM vector, the same round loop as a ``fori_loop``; correctness is
-pinned in interpret mode (this container is CPU-only), the XLA scan is
-the product path.
-
 ``DeviceBlockDecoder`` is the CRAM-facing object: io/cram.py hands it
 a container's raw (still compressed) blocks, supported rANS blocks
 batch-decode on device through a content-keyed plan Step at the
@@ -418,138 +412,6 @@ def _jitted_interleave():
     return fn
 
 
-# --------------------------------------------------------- Pallas path
-
-def pallas_decode0(payload, plen, states, slot_sym, slot_freq,
-                   slot_bias, inner_len, *, rounds, n_states,
-                   interpret: bool = False):
-    """The rANS scan as a Pallas kernel: one block per sequential grid
-    step, the N states as a lane vector, the round loop as a
-    ``fori_loop`` with (states, read pointer, output buffer) carried —
-    the same one-item-per-grid-step pattern as
-    ops/pairhmm.py::pallas_forward_bucket. EXPERIMENTAL like its
-    siblings: interpret-mode-pinned against the XLA scan (this
-    container is CPU-only); expansions (RLE/PACK) stay in the shared
-    XLA stages either way.
-
-    payload (B, P) int32, states (B, N) int32, slots (B, 4096) int32
-    → (B, rounds*N) int32 symbols.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, P = payload.shape
-    N = n_states
-    L = rounds * N
-
-    def kernel(meta_ref, states_ref, payload_ref, sym_ref, freq_ref,
-               bias_ref, out_ref):
-        plen_b = meta_ref[0, 0]
-        inner_b = meta_ref[0, 1]
-        pay = payload_ref[0, :]
-        sym = sym_ref[0, :]
-        sfreq = freq_ref[0, :]
-        sbias = bias_ref[0, :]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-
-        def round_fn(r, carry):
-            R, pos, outbuf = carry
-            active = (r * N + lanes) < inner_b
-            m = R & (TOTFREQ - 1)
-            s = jnp.take(sym, m[0, :], axis=0)[None, :]
-            f = jnp.take(sfreq, m[0, :], axis=0)[None, :]
-            bi = jnp.take(sbias, m[0, :], axis=0)[None, :]
-            # int32 is exact here: valid states stay < 2^31 (renorm
-            # bound) so freq*(x>>12)+bias < 2^31 and the x<<16 of a
-            # sub-2^15 state fits — the uint32 XLA path and this agree
-            # bit-for-bit on every well-formed stream
-            x = f * (R >> TF_SHIFT) + bi
-            want = active & (x < RANS_LOW)
-            avail = jnp.maximum(0, (plen_b - pos) // 2)
-            wi = want.astype(jnp.int32)
-            rank = jnp.cumsum(wi, axis=1, dtype=jnp.int32) - wi
-            need = want & (rank < avail)
-            offs = pos + 2 * rank
-            b0 = jnp.take(pay, jnp.clip(offs[0, :], 0, P - 1),
-                          axis=0)[None, :]
-            b1 = jnp.take(pay, jnp.clip(offs[0, :] + 1, 0, P - 1),
-                          axis=0)[None, :]
-            xr = (x << 16) | b0 | (b1 << 8)
-            x = jnp.where(need, xr, x)
-            R = jnp.where(active, x, R)
-            pos = pos + 2 * jnp.sum(need, dtype=jnp.int32)
-            outbuf = jax.lax.dynamic_update_slice(outbuf, s,
-                                                  (0, r * N))
-            return R, pos, outbuf
-
-        R0 = states_ref[0, :][None, :]
-        out0 = jnp.zeros((1, L), jnp.int32)
-        _, _, outbuf = jax.lax.fori_loop(
-            0, rounds, round_fn, (R0, jnp.int32(0), out0))
-        out_ref[0] = outbuf[0]
-
-    meta = jnp.stack([plen, inner_len], axis=1).astype(jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda t: (t, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, N), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, P), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, TOTFREQ), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, TOTFREQ), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, TOTFREQ), lambda t: (t, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, L), lambda t: (t, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, L), jnp.int32),
-        interpret=interpret,
-    )(meta, states, payload, slot_sym, slot_freq, slot_bias)
-
-
-def _pallas_scan_bytes(group: list[ParsedNx16], n_states: int,
-                       rounds: int, p_cap: int,
-                       interpret: bool) -> np.ndarray:
-    """Run a non-CAT group's rANS stage through the Pallas kernel,
-    returning (B, rounds*N) uint8 symbols (the XLA expansion stages
-    consume them unchanged)."""
-    import jax.numpy as jnp
-
-    B = len(group)
-    payload = np.zeros((B, p_cap), np.int32)
-    plen = np.zeros(B, np.int32)
-    states = np.zeros((B, n_states), np.int32)
-    ssym = np.zeros((B, TOTFREQ), np.int32)
-    sfreq = np.zeros((B, TOTFREQ), np.int32)
-    sbias = np.zeros((B, TOTFREQ), np.int32)
-    inner = np.zeros(B, np.int32)
-    ms = np.arange(TOTFREQ, dtype=np.int64)
-    for i, p in enumerate(group):
-        payload[i, :p.payload.shape[0]] = p.payload
-        plen[i] = p.payload.shape[0]
-        states[i] = p.states.astype(np.int64).astype(np.int32)
-        lut = _rx._slot_lut(p.freq.astype(np.int64),
-                            p.cum.astype(np.int64)).astype(np.int64)
-        ssym[i] = lut.astype(np.int32)
-        sfreq[i] = p.freq[lut]
-        sbias[i] = (ms - p.cum[lut]).astype(np.int32)
-        inner[i] = p.inner_len
-    got = pallas_decode0(
-        jnp.asarray(payload), jnp.asarray(plen), jnp.asarray(states),
-        jnp.asarray(ssym), jnp.asarray(sfreq), jnp.asarray(sbias),
-        jnp.asarray(inner), rounds=rounds, n_states=n_states,
-        interpret=interpret)
-    return np.asarray(got).astype(np.uint8)
-
-
 # ---------------------------------------------------------- batch glue
 
 def _signature(p: ParsedNx16) -> tuple:
@@ -601,8 +463,7 @@ def plan_signatures(p: ParsedNx16) -> list[tuple]:
     return [_signature(p)]
 
 
-def _decode_flat(plans: list[ParsedNx16], *, backend: str,
-                 interpret: bool, stage,
+def _decode_flat(plans: list[ParsedNx16], *, stage,
                  device_idx: set[int] | None = None) -> list:
     """The bucketed + vmapped dispatch over non-stripe plans.
 
@@ -678,41 +539,21 @@ def _decode_flat(plans: list[ParsedNx16], *, backend: str,
         # exact per-bucket compile attribution against the shared jit
         # object's own cache (one geometry = one cache entry)
         jit_fn = _jitted()
-        cache_size = getattr(jit_fn, "_cache_size", None)
-        if backend == "pallas" and not cat and not order1:
-            # the experimental kernel covers the ORDER0 rANS stage;
-            # ORDER1 buckets take the XLA scan either way
-            lit = _pallas_scan_bytes(grp, n, rounds, p_cap, interpret)
-            # expansions reuse the XLA stages by re-entering as CAT
-            # with the scan's output as payload
-            with TRACKER.observe("rans", signature=sig,
-                                 cache_size_fn=cache_size,
-                                 trigger="rans_decode"):
-                out, diag = jit_fn(
-                    lit, dev["plen"], dev["states"], dev["freq"],
-                    dev["inner"], dev["rle_tab"], dev["runs"],
-                    dev["rle_out"], dev["pmap"], dev["bits"],
-                    dev["final"], dev["ctx_index"], dev["ctx_freq"],
-                    dev["alphabet"],
-                    rounds=0, n_states=n, cat=True,
-                    rle=rle, pack=pack, order1=False, shift=TF_SHIFT,
-                    n_ctx_cap=n_ctx_cap, lit_cap=lit.shape[1],
-                    mid_cap=mid_cap, out_cap=out_cap)
-        else:
-            with TRACKER.observe("rans", signature=sig,
-                                 cache_size_fn=cache_size,
-                                 trigger="rans_decode"):
-                out, diag = jit_fn(
-                    dev["payload"], dev["plen"], dev["states"],
-                    dev["freq"], dev["inner"],
-                    dev["rle_tab"], dev["runs"], dev["rle_out"],
-                    dev["pmap"], dev["bits"], dev["final"],
-                    dev["ctx_index"], dev["ctx_freq"],
-                    dev["alphabet"],
-                    rounds=rounds, n_states=n, cat=cat, rle=rle,
-                    pack=pack, order1=order1, shift=shift,
-                    n_ctx_cap=n_ctx_cap, lit_cap=lit_cap,
-                    mid_cap=mid_cap, out_cap=out_cap)
+        cache_size = jit_fn._cache_size
+        with TRACKER.observe("rans", signature=sig,
+                             cache_size_fn=cache_size,
+                             trigger="rans_decode"):
+            out, diag = jit_fn(
+                dev["payload"], dev["plen"], dev["states"],
+                dev["freq"], dev["inner"],
+                dev["rle_tab"], dev["runs"], dev["rle_out"],
+                dev["pmap"], dev["bits"], dev["final"],
+                dev["ctx_index"], dev["ctx_freq"],
+                dev["alphabet"],
+                rounds=rounds, n_states=n, cat=cat, rle=rle,
+                pack=pack, order1=order1, shift=shift,
+                n_ctx_cap=n_ctx_cap, lit_cap=lit_cap,
+                mid_cap=mid_cap, out_cap=out_cap)
         diag = np.asarray(diag)
         keep = device_idx or ()
         # bulk host fetch only when no row of this bucket stays on
@@ -744,8 +585,7 @@ def _decode_flat(plans: list[ParsedNx16], *, backend: str,
     return results
 
 
-def decode_parsed(plans: list[ParsedNx16], *, backend: str = "scan",
-                  interpret: bool = False,
+def decode_parsed(plans: list[ParsedNx16], *,
                   stage=None) -> list[bytes]:
     """Decode parsed streams on device, bucketed + vmapped; returns
     bytes per stream, byte-identical to ``rans_nx16.decode``.
@@ -757,9 +597,6 @@ def decode_parsed(plans: list[ParsedNx16], *, backend: str = "scan",
     the interleave dispatch — only the final interleaved block is
     fetched to the host (plain rows fetch as before).
 
-    ``backend``: "scan" (the XLA product path) or "pallas" (the
-    experimental kernel for the ORDER0 rANS stage; ORDER1 and the
-    expansions take the XLA path).
     ``stage``: optional callable mapping a dict of host arrays to
     device arrays (parallel.prefetch.stage_block_arrays — the
     compressed-wire staging/accounting step); default stages without
@@ -779,9 +616,7 @@ def decode_parsed(plans: list[ParsedNx16], *, backend: str = "scan",
         else:
             spec.append(("plain", len(flat), p))
             flat.append(p)
-    decoded = _decode_flat(flat, backend=backend,
-                           interpret=interpret, stage=stage,
-                           device_idx=lane_idx)
+    decoded = _decode_flat(flat, stage=stage, device_idx=lane_idx)
 
     results: list[bytes | None] = [None] * len(plans)
     stripe_groups: dict[tuple, list[int]] = {}
@@ -823,9 +658,8 @@ def decode_parsed(plans: list[ParsedNx16], *, backend: str = "scan",
 
 
 def decode_streams(datas: list[bytes],
-                   expected_lens: list[int | None] | None = None,
-                   *, backend: str = "scan",
-                   interpret: bool = False) -> list[bytes | None]:
+                   expected_lens: list[int | None] | None = None
+                   ) -> list[bytes | None]:
     """Parse + device-decode many standalone Nx16 streams; None marks
     a stream that stays host-side (unsupported/corrupt layout, or a
     new bucket shape past the signature cap — the caller falls back
@@ -840,8 +674,7 @@ def decode_streams(datas: list[bytes],
         if p is not None and _admit_signatures(plan_signatures(p)):
             plans.append(p)
             order.append(i)
-    decoded = decode_parsed(plans, backend=backend,
-                            interpret=interpret)
+    decoded = decode_parsed(plans)
     for i, b in zip(order, decoded):
         results[i] = b
     return results
@@ -875,13 +708,10 @@ class DeviceBlockDecoder:
     prefetch byte counters and stage spans record it.
     """
 
-    def __init__(self, backend: str = "scan", interpret: bool = False,
-                 policy=None):
+    def __init__(self, policy=None):
         from ..plan import Executor
         from ..resilience.policy import DEFAULT_POLICY
 
-        self.backend = backend
-        self.interpret = interpret
         self._pex = Executor(policy=policy if policy is not None
                              else DEFAULT_POLICY)
         reg = get_registry()
@@ -933,14 +763,11 @@ class DeviceBlockDecoder:
                 tcrc = p.table_crc(tcrc)
             # the table CRC joins the content key: same payload bytes
             # under a different table is a different decode
-            key = ("decode", self.backend, len(plans), wire_c, crc,
-                   tcrc)
+            key = ("decode", len(plans), wire_c, crc, tcrc)
             decoded = self._pex.run(Step(
                 key=key, site="decode", span="decode.device",
                 attrs={"blocks": len(plans), "wire_bytes": wire_c},
-                fn=lambda: decode_parsed(
-                    plans, backend=self.backend,
-                    interpret=self.interpret, stage=self._stage)))
+                fn=lambda: decode_parsed(plans, stage=self._stage)))
             self._c_dev.inc(len(plans))
             self._c_wire_c.inc(wire_c)
             self._c_wire_u.inc(wire_u)

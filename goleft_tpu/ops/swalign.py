@@ -183,7 +183,7 @@ def sw_bucket(reads_p, rlens, wins, wlens, scores):
 def _sw_jit_cache_size() -> int:
     if _SW_JIT is None:
         return 0
-    return getattr(_SW_JIT, "_cache_size", lambda: 0)()
+    return _SW_JIT._cache_size()
 
 
 def sw_oracle(read_codes: np.ndarray, win_codes: np.ndarray,

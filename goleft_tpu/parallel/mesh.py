@@ -19,8 +19,6 @@ from __future__ import annotations
 
 import os
 
-from ..obs.logging import get_logger as _get_logger
-
 import jax
 import numpy as np
 from jax.sharding import Mesh
@@ -49,28 +47,23 @@ def make_mesh(n_devices: int | None = None,
     (``seq``) axis — which carries the cumsum-carry ppermute traffic of
     the sharded coverage kernel — maps to physically adjacent ICI
     neighbors on real TPU topologies, instead of the raw ``jax.devices()``
-    enumeration order (round-1 VERDICT weak #4). Falls back to a plain
-    reshape when the requested count is a strict subset of the process's
-    devices (subset meshes have no topology guarantee anyway).
+    enumeration order. A plain reshape is used on the CPU, and when the
+    requested count is a strict subset of the process's devices (subset
+    meshes have no topology guarantee anyway).
     """
     devs = jax.devices()
     n = n_devices or len(devs)
     if n > len(devs):
         raise ValueError(f"requested {n} devices, have {len(devs)}")
     d, s = best_grid(n, prefer_seq)
-    if n == len(devs):
-        try:
-            from jax.experimental import mesh_utils
+    if n == len(devs) and devs[0].platform != "cpu":
+        # virtual CPU devices have no topology to order by; on an
+        # accelerator a failure here is raised, never turned into
+        # enumeration order (which would lose ICI adjacency in silence)
+        from jax.experimental import mesh_utils
 
-            grid = mesh_utils.create_device_mesh((d, s), devices=devs)
-            return Mesh(grid, axis_names)
-        except Exception as e:  # noqa: BLE001 - virtual/CPU platforms
-            if devs[0].platform not in ("cpu",):
-                _get_logger("mesh").warning(
-                    "topology-aware mesh unavailable (%s); falling back "
-                    "to enumeration order — ICI adjacency not guaranteed",
-                    e,
-                )
+        return Mesh(mesh_utils.create_device_mesh((d, s), devices=devs),
+                    axis_names)
     grid = np.asarray(devs[:n]).reshape(d, s)
     return Mesh(grid, axis_names)
 
@@ -85,7 +78,7 @@ def init_distributed() -> None:
     distributed backend at all (SURVEY.md §2.5) — this is the rebuild's
     equivalent of an NCCL/MPI world init. Must run before anything
     initializes the XLA backend — the CLI dispatcher calls it ahead of
-    its device bring-up watchdog.
+    ``take_backend``.
     """
     global _distributed_up
 

@@ -17,24 +17,10 @@ bucketing (indexsplit-style even-data planning) produces exactly this.
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exports shard_map at top level (check_vma kwarg)
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental namespace, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_vma})
 
 
 def sharded_depth_fn(mesh: Mesh, shard_len: int, window: int,

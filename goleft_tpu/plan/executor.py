@@ -22,7 +22,7 @@ dispatch, the serve executors — runs its Steps through
 resilience/policy.py): same (key, thunk, cache, policy) →
 ``ShardResult`` contract both scheduler paths have used since PR 5.
 ``run_device_step`` is the serve executors' facade: one coalesced
-device dispatch as a retried Step, so a transient device/tunnel fault
+device dispatch as a retried Step, so a transient device fault
 costs one backoff instead of failing the whole batch.
 """
 

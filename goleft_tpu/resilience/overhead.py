@@ -4,9 +4,8 @@
 the full ``run_cohortdepth`` path three ways — plain, checkpointing
 into a fresh store, and resuming a fully-committed store — and
 reports the checkpointed/plain overhead fraction. ``bench.py`` records
-it as the ``cohort_resume_overhead`` entry (ledger-ingested like every
-other entry, so the perf sentinel tracks it round over round) and the
-chaos smoke asserts the ≤5% budget.
+it as the ``cohort_resume_overhead`` entry and the chaos smoke asserts
+the ≤5% budget.
 
 Best-of-N timing on every leg (the least-noise estimator the bench
 uses throughout); the fixture is sized so per-region journal fsyncs

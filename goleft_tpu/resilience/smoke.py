@@ -324,8 +324,7 @@ def _serve_checkpoint_leg(d, bams, fai, bed, env, verbose):
 def run_smoke(timeout_s: float = 180.0, verbose: bool = True) -> int:
     """Returns 0 on success; raises on any failed step."""
     env = dict(os.environ,
-               JAX_PLATFORMS="cpu",     # CI has no accelerator
-               GOLEFT_TPU_PROBE="0")    # don't pay a probe timeout
+               JAX_PLATFORMS="cpu")     # CI has no accelerator
     env.pop("GOLEFT_TPU_FAULTS", None)  # hermetic: no inherited plan
     with tempfile.TemporaryDirectory(prefix="goleft_chaos_") as d:
         bams, fai, bed = _make_cohort(d)

@@ -68,7 +68,7 @@ def _dispatch(metrics, name: str, fn, retry: bool = True, key=None,
     stage wall-clock PLUS a device-event span carrying backend/
     platform attributes, with the ``device`` fault site fired per
     attempt and transient failures retried under the default
-    RetryPolicy — a flaky device/tunnel blip costs one backoff instead
+    RetryPolicy — a transient device fault costs one backoff instead
     of failing every request that shared the batch. The wrapped calls
     fetch their results to host numpy before returning, so the span's
     extent already fences on the device work.

@@ -10,9 +10,9 @@ passes (serve/executors.py). Layered on top:
     without touching the batcher or the device (keys carry
     ``file_key`` identity — size + mtime_ns — so a rewritten BAM
     misses)
-  - /healthz: backend platform/device state (the device_guard probe's
-    cached verdict feeds the CLI bring-up; here the live backend is
-    reported) + draining flag
+  - /healthz: the live backend's platform/device state + draining
+    flag (a worker started through the CLI took its backend at
+    dispatch, or exited before announcing its port)
   - /metrics: request/response counters, queue depth, the batch-size
     histogram (the coalescing evidence), per-endpoint latency
     percentiles, stage wall-clocks, cache hit rates and the SLO block

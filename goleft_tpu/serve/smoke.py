@@ -58,8 +58,7 @@ def run_smoke(timeout_s: float = 120.0, verbose: bool = True) -> int:
     from .client import ServeClient
 
     env = dict(os.environ,
-               JAX_PLATFORMS="cpu",     # CI has no accelerator;
-               GOLEFT_TPU_PROBE="0")    # don't pay a probe timeout
+               JAX_PLATFORMS="cpu")     # CI has no accelerator
     deadline = time.monotonic() + timeout_s
     with tempfile.TemporaryDirectory(prefix="goleft_smoke_") as d:
         bam, fai = _make_fixture(d)

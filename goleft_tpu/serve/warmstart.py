@@ -113,8 +113,7 @@ def _cache_size_fn(family: str):
     if family == "pairhmm":
         from ..ops import pairhmm
 
-        return lambda: (getattr(pairhmm._FORWARD_JIT, "_cache_size",
-                                lambda: 0)()
+        return lambda: (pairhmm._FORWARD_JIT._cache_size()
                         if pairhmm._FORWARD_JIT is not None else 0)
     if family == "swalign":
         from ..ops.swalign import _sw_jit_cache_size

@@ -296,8 +296,7 @@ def test_cohortdepth_sigkill_crash_resume_subprocess(tmp_path):
     with open(bed, "w") as fh:
         for lo in range(0, 6000, 1000):
             fh.write(f"chr1\t{lo}\t{lo + 1000}\n")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GOLEFT_TPU_PROBE="0",
-               PYTHONPATH=REPO)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     env.pop("GOLEFT_TPU_FAULTS", None)
     base = [sys.executable, "-m", "goleft_tpu", "cohortdepth",
             "-r", fa, "-w", "200", "-b", bed, "-p", "2"]

@@ -9,6 +9,7 @@ import numpy as np
 from goleft_tpu.commands.indexcov import run_indexcov
 from goleft_tpu.io.bai import read_bai
 from helpers import write_bam_and_bai, random_reads
+from oracle_indexcov import oracle_cn, oracle_counters, oracle_normalized
 
 REFS = ("chr1", "X")
 LENS = (800_000, 300_000)
@@ -17,26 +18,6 @@ LENS = (800_000, 300_000)
 def _header(s):
     sq = "".join(f"@SQ\tSN:{n}\tLN:{l}\n" for n, l in zip(REFS, LENS))
     return f"@HD\tVN:1.6\tSO:coordinate\n{sq}@RG\tID:r\tSM:{s}\n"
-
-
-def oracle_median(all_sizes):
-    flat = np.sort(np.concatenate(all_sizes).astype(np.int64))
-    n98 = flat[int(0.98 * len(flat))]
-    cum = np.cumsum(np.minimum(flat, n98))
-    idx = int(np.searchsorted(cum, int(cum[-1]) // 2, side="right"))
-    return float(flat[min(idx, len(flat) - 1)])
-
-
-def oracle_cn(depths, ploidy=2):
-    tmp = sorted(float(x) for x in depths if x != 0)
-    lows = sum(1 for x in depths if x != 0 and x < 0.02)
-    if not tmp:
-        return -0.1
-    if lows / len(depths) > 0.3:
-        tmp = tmp[lows:]
-    if not tmp:
-        return 0.0
-    return float(np.float32(ploidy) * np.float32(tmp[int(len(tmp) * 0.4)]))
 
 
 def test_indexcov_pipeline_matches_sequential_oracle(tmp_path):
@@ -56,18 +37,8 @@ def test_indexcov_pipeline_matches_sequential_oracle(tmp_path):
                        write_html=False, write_png=False)
 
     # independent recomputation from the raw indexes
-    per_sample = []
-    for p in paths:
-        idx = read_bai(p + ".bai")
-        sizes = idx.sizes()
-        med = oracle_median([s for s in sizes if len(s)])
-        norm = [
-            np.minimum(
-                (s.astype(np.float64) / med).astype(np.float32), 50000
-            )
-            for s in sizes
-        ]
-        per_sample.append(norm)
+    per_sample = [oracle_normalized(read_bai(p + ".bai").sizes())
+                  for p in paths]
 
     # bed.gz values must equal the %.3g-formatted oracle normalization
     with gzip.open(res["bed"], "rt") as fh:
@@ -94,17 +65,9 @@ def test_indexcov_pipeline_matches_sequential_oracle(tmp_path):
         assert float(prows[k][cnx_col]) == float("%.2f" % want), k
 
     # counters recomputed: in/out/hi/low over autosome (chr1) bins
-    for name, col in (("in", "bins.in"), ("out", "bins.out"),
-                      ("hi", "bins.hi"), ("lo", "bins.lo")):
-        ci = hdr.index(col)
-        longest = max(len(ps[0]) for ps in per_sample)
+    longest = max(len(ps[0]) for ps in per_sample)
+    for name in ("in", "out", "hi", "lo"):
+        ci = hdr.index("bins." + name)
         for k in range(4):
-            d = per_sample[k][0]
-            inside = int(np.sum((d >= 0.85) & (d <= 1.15)))
-            out_n = int(np.sum((d < 0.85) | (d > 1.15)))
-            hi = int(np.sum(d > 1.15))
-            lo = int(np.sum(d < 0.15))
-            tail = longest - len(d)
-            expect = {"in": inside, "out": out_n + tail, "hi": hi,
-                      "lo": lo + tail}[name]
-            assert int(prows[k][ci]) == expect, (name, k)
+            want = oracle_counters(per_sample[k][0], longest)[name]
+            assert int(prows[k][ci]) == want, (name, k)

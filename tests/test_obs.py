@@ -449,7 +449,6 @@ def test_depth_cli_writes_trace_and_manifest(tmp_path, monkeypatch):
     from goleft_tpu.cli import main as cli_main
     from goleft_tpu.obs.smoke import validate_trace
 
-    monkeypatch.setenv("GOLEFT_TPU_PROBE", "0")
     rng = np.random.default_rng(5)
     ref_len = 20_000
     bam = str(tmp_path / "t.bam")

@@ -454,26 +454,3 @@ def test_injected_permanent_fault_quarantines_window(tmp_path):
     doc = json.load(open(qpath))
     assert doc["quarantined"] and \
         doc["quarantined"][0]["phase"] == "pairhmm"
-
-
-# ---------------------------------------------------------------------------
-# Pallas variant (interpret mode; jax-version drift tolerated)
-
-def test_pallas_forward_matches_xla_path():
-    rng = np.random.default_rng(7)
-    reads, quals, haps = _random_pairs(5, rng, max_r=24, max_h=40)
-    enc_r = [ph.encode_seq(r) for r in reads]
-    errs = [ph.phred_to_err(q) for q in quals]
-    enc_h = [ph.encode_seq(h) for h in haps]
-    packed = ph._pack_bucket(list(range(5)), enc_r, errs, enc_h,
-                             24, 40, np.float32)
-    trans = ph.transition_probs().astype(np.float32)
-    try:
-        c, s = ph.pallas_forward_bucket(*packed, trans,
-                                        interpret=True)
-    except (TypeError, AttributeError, NotImplementedError) as e:
-        pytest.skip(f"pallas interpret unavailable on this jax: {e!r}")
-    got = ph._fold_contribs(c, s)
-    want = np.array([oracle_log10(r, q, h)
-                     for r, q, h in zip(reads, quals, haps)])
-    np.testing.assert_allclose(got, want, rtol=0, atol=1e-4)

@@ -188,7 +188,11 @@ def test_overhead_at_100hz_is_within_two_percent():
     """The ISSUE's bound: 100 Hz sampling costs ≤ 2% of wall on the
     depth pipeline. 2% at 100 Hz means one sample may cost at most
     200µs; the memoized collapse makes a warm sample ~10µs, so this
-    pins with a 10x margin while real worker threads run."""
+    pins with a 10x margin while real worker threads run. The cost is
+    the sampling thread's own CPU time: on the wall clock the same loop
+    also counts every wait for the GIL and for a core, and on a loaded
+    machine (six test workers on eight cores) that wait, not the
+    sampler, decided the result."""
     stop = threading.Event()
 
     def busy():
@@ -204,10 +208,10 @@ def test_overhead_at_100hz_is_within_two_percent():
         for _ in range(50):
             p._sample_once()  # warm the key memo
         n = 200
-        t0 = time.perf_counter()
+        t0 = time.thread_time()
         for _ in range(n):
             p._sample_once()
-        per_sample = (time.perf_counter() - t0) / n
+        per_sample = (time.thread_time() - t0) / n
     finally:
         stop.set()
         for t in threads:

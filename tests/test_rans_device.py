@@ -96,24 +96,6 @@ def test_scan_parity_nosz():
         assert g == rx.decode(enc, len(data)) == data
 
 
-def test_pallas_parity_interpret():
-    # the experimental kernel, pinned in interpret mode (this
-    # container is CPU-only) against the same host oracle; the XLA
-    # expansion stages are shared so the rANS scan is what differs
-    rng = np.random.default_rng(2)
-    cases = []
-    for x32 in (False, True):
-        cases += _corpus(rng, [5, 201, 4097, 8000], x32=x32)
-        cases += _corpus(rng, [4097], x32=x32, rle=True, pack=True,
-                         alpha=9)
-    encs = [e for _, e in cases]
-    lens = [len(d) for d, _ in cases]
-    got = rd.decode_streams(encs, lens, backend="pallas",
-                            interpret=True)
-    for (data, enc), g in zip(cases, got):
-        assert g == rx.decode(enc, len(data)) == data
-
-
 def _order1_corpus(rng, n=20000):
     """Delta-correlated bytes — the shape ORDER1 wins on (quality/
     name-like streams)."""
