@@ -891,12 +891,12 @@ def _depth_jit_cache_total() -> int:
 @_contextlib.contextmanager
 def _count_compiles():
     """Delegates to the compile observatory's windowed view
-    (obs/compiles.py count_compiles): the SAME jax_log_compiles hook
+    (obs/compiles.py count_compiles): the SAME jax.monitoring hook
     serve and the CLI record through, so bench and serve can never
     disagree about compile counts. The handle's ``.names`` keeps this
     module's historical API; the :func:`_depth_jit_cache_total`
     cross-check below stays — a cold run that compiled anything MUST
-    grow a tracing cache, whatever jax does to its log format."""
+    grow a tracing cache, whatever jax does to its events."""
     from goleft_tpu.obs.compiles import count_compiles
 
     with count_compiles() as handle:
@@ -984,7 +984,7 @@ def bench_depth_wholegenome(quick: bool) -> dict:
                           "(overlapping threads can exceed wall)",
             "xla_compiles_cold": c_cold,
             "xla_compiles_warm_repeat": c_warm,
-            # independent cross-check on the log-based counter: new
+            # independent cross-check on the event counter: new
             # tracing-cache entries in the depth pipeline jits
             "jit_cache_entries_cold_delta": cache_cold,
             "jit_cache_entries_warm_delta": cache_warm,
@@ -996,16 +996,16 @@ def bench_depth_wholegenome(quick: bool) -> dict:
                     f"{c_warm} — scale adds shards, not compiles",
         }
         if c_cold == 0:
-            # a real first run always compiles: the log-based counter
-            # is broken (jax changed its message/logger) — say so
+            # a real first run always compiles: the event counter
+            # is broken (jax changed its monitoring events) — say so
             # loudly and make NO no-recompile claim this round
             entry["compile_counter_error"] = (
-                "cold run counted 0 compiles via jax_log_compiles — "
+                "cold run counted 0 compiles via jax.monitoring — "
                 "impossible for a first run; counter is broken "
                 f"(cross-check: jit cache grew {cache_cold} entries). "
                 "no_recompile_across_chroms claim withheld.")
         else:
-            # the claim must survive BOTH counters: zero compile logs
+            # the claim must survive BOTH counters: zero compile events
             # AND zero new cache entries on the warm repeat
             entry["no_recompile_across_chroms"] = (
                 c_warm == 0 and cache_warm == 0)

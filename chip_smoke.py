@@ -269,15 +269,13 @@ def indexcov_oracle(bais: list[str]) -> dict:
 
 
 def em_normalized(depths) -> np.ndarray:
-    """The emdepth command's own median normalization, in its own f32
-    (commands/emdepth_cmd._norm_chunk), so that the oracle and the
-    program see the same inputs."""
-    med = np.median(depths, axis=0)
+    """Median normalization by its definition, in f64 and apart from
+    the command's own f32 code: each sample's depths over that sample's
+    median (0 counts as 1), times the median of those medians."""
+    d = depths.astype(np.float64)
+    med = np.median(d, axis=0)
     med[med == 0] = 1.0
-    norm = depths.astype(np.float32)
-    np.divide(norm, med.astype(np.float32), out=norm)
-    np.multiply(norm, np.float32(np.median(med)), out=norm)
-    return norm
+    return d / med * np.median(med)
 
 
 def em_oracle_rows(rows):
@@ -312,6 +310,7 @@ class Smoke:
         self.phases: list[dict] = []
         self.devices: list[tuple] = []  # of the children that ran on a tpu
         self.off_chip: list[str] = []  # children that did not
+        self.cache_dirs: set = set()  # compile caches the children used
         self.env = dict(os.environ, PYTHONPATH=os.pathsep.join(
             [ROOT] + [p for p in os.environ.get(
                 "PYTHONPATH", "").split(os.pathsep) if p]))
@@ -325,7 +324,8 @@ class Smoke:
               env=None, want_platform: str = "tpu") -> dict:
         """One ``python -m goleft_tpu`` child to its end. Returns
         {child_seconds, compiles, compile_seconds, cache_hits,
-        cache_misses, platform, device_kind, device_count, gauges};
+        cache_misses, platform, device_kind, device_count,
+        compile_cache_dir, gauges};
         raises on a non-zero exit. A manifest that names another
         platform than wanted fails the phase after its comparisons
         (so that a CPU rehearsal still makes them)."""
@@ -359,10 +359,12 @@ class Smoke:
         elif want_platform == "tpu":
             self.devices.append(
                 (b["platform"], b["device_kind"], b["device_count"]))
+        self.cache_dirs.add(b["compile_cache_dir"])
         c = doc["metrics"]["counters"]
         return {
             "platform": b["platform"], "device_kind": b["device_kind"],
             "device_count": b["device_count"],
+            "compile_cache_dir": b["compile_cache_dir"],
             "compiles": c.get("xla.compiles_total", 0),
             "compile_seconds": round(
                 c.get("xla.compile_seconds_total", 0.0), 2),
@@ -617,7 +619,8 @@ def phase_emdepth(sm: Smoke, fx: dict) -> dict:
                 calls=len(calls),
                 compared="integer CN of every window and sample with the "
                          "sequential oracle (tests/oracle_emdepth.py, run "
-                         "here); every planted run called")
+                         "here on an f64 normalization of its own); every "
+                         "planted run called")
 
 
 def phase_serve(sm: Smoke, fx: dict) -> dict:
@@ -819,7 +822,7 @@ def main(argv=None) -> int:
             depths, fx["em_planted"] = fabricate_em_matrix(
                 fx["em_matrix"], cfg["em_samples"], cfg["em_windows"],
                 cfg["em_run"], rng_em)
-            rows = em_normalized(depths).astype(float).tolist()
+            rows = em_normalized(depths).tolist()
             fx["em_oracle"] = pool.map_async(
                 em_oracle_rows,
                 [rows[i:i + 16] for i in range(0, len(rows), 16)])
@@ -840,10 +843,15 @@ def main(argv=None) -> int:
     assert "jax" not in sys.modules, "the parent must stay off jax"
     emit({"summary": {p["phase"]: p["seconds"] for p in sm.phases},
           "native": variant,
+          "compile_cache_dirs": sorted(map(str, sm.cache_dirs)),
+          "JAX_COMPILATION_CACHE_DIR": os.environ.get(
+              "JAX_COMPILATION_CACHE_DIR"),
           "total_seconds": round(time.monotonic() - t_start, 2)})
     seen = set(sm.devices)
+    # one compile cache for every child, or the warm runs prove nothing
     ok = (all(p["ok"] for p in sm.phases) and len(seen) == 1
-          and next(iter(seen))[2] == want_devices)
+          and next(iter(seen))[2] == want_devices
+          and len(sm.cache_dirs) == 1)
     if not ok:
         emit({"ok": False,
               "failed": [p["phase"] for p in sm.phases if not p["ok"]],

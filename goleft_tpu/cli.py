@@ -278,7 +278,7 @@ def main(argv: list[str] | None = None) -> int:
     if PROGS[prog][2]:
         # multi-host world (no-op without GOLEFT_TPU_COORDINATOR): must
         # come before take_backend's jax.devices() brings the backend up
-        from .obs.compiles import ensure_log_hook
+        from .obs.compiles import ensure_compile_hook
         from .parallel.mesh import init_distributed
         from .utils.device_guard import take_backend
 
@@ -287,7 +287,7 @@ def main(argv: list[str] | None = None) -> int:
         # count every compile of the run from its first jit, seam or
         # no seam around it (xla.compiles_total, xla.compile_seconds_
         # total, xla.cache_hits_total in the --metrics-out manifest)
-        ensure_log_hook()
+        ensure_compile_hook()
 
     trace_id = None
     rc = 1

@@ -34,7 +34,6 @@ import hashlib
 import http.server
 import os
 import re
-import socket
 import sys
 import threading
 
@@ -113,18 +112,9 @@ class ObjectStore:
 class _Handler(http.server.BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
     store: ObjectStore = None  # bound per-server subclass
-    live: set = None  # open client sockets, bound per server
 
     def log_message(self, *a):  # quiet: tests read stdout
         pass
-
-    def setup(self):
-        super().setup()
-        self.live.add(self.connection)
-
-    def finish(self):
-        self.live.discard(self.connection)
-        super().finish()
 
     def _name(self) -> str:
         return self.path.lstrip("/").split("?", 1)[0]
@@ -181,12 +171,8 @@ class StubServer:
     def __init__(self, store: ObjectStore | None = None,
                  port: int = 0):
         self.store = store if store is not None else ObjectStore()
-        # keep-alive clients hold their handler thread in a read until
-        # they hang up: stop() hangs up for them, or every client that
-        # outlives the server leaves a thread behind
-        self._live: set = set()
         handler = type("_BoundHandler", (_Handler,),
-                       {"store": self.store, "live": self._live})
+                       {"store": self.store})
         self._httpd = http.server.ThreadingHTTPServer(
             ("127.0.0.1", port), handler)
         self._thread = threading.Thread(
@@ -207,11 +193,6 @@ class StubServer:
 
     def stop(self) -> None:
         self._httpd.shutdown()
-        for sock in list(self._live):
-            try:
-                sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass  # the client hung up first
         self._httpd.server_close()
         self._thread.join(timeout=10)
 

@@ -14,12 +14,12 @@ first-class, mergeable signal:
     dispatch;
   - a miss is detected two independent ways: a ``_cache_size()``
     delta on the wrapped jit (exact, when the seam holds the jit
-    object) and the ``jax_log_compiles`` WARNING records ("Compiling
-    <name> with global shapes..." from jax._src.interpreters.pxla)
-    attributed to the innermost active observation on the emitting
-    thread — jax compiles synchronously on the dispatching thread, so
-    thread-local attribution is sound. A compile seen by both
-    detectors is counted once (``max``, not sum);
+    object) and jax's own ``jax.monitoring`` event for every
+    executable built (its ``fun_name`` and seconds), attributed to the
+    innermost active observation on the emitting thread — jax
+    compiles synchronously on the dispatching thread, so thread-local
+    attribution is sound. A compile seen by both detectors is counted
+    once (``max``, not sum);
   - every miss becomes a structured :class:`CompileEvent` (program
     family, bucket signature, backend, wall duration, pid, trigger
     context), flows into the registry
@@ -37,20 +37,19 @@ first-class, mergeable signal:
     live at ``GET /debug/compiles``; exported/merged by ``goleft-tpu
     warmup export``.
 
-The log hook is installed lazily by the first ``observe()`` that runs
-with jax already imported (never imports jax itself — the jax-free
-router/fleet processes import this module); ``GOLEFT_TPU_NO_COMPILE_
-HOOK=1`` keeps jax logging untouched, degrading detection to the
-cache-delta path. A "Compiling" record with no active observation is
-still recorded (family ``unattributed``) — the observatory is
-process-wide, not seam-wide.
+The ``jax.monitoring`` listeners are registered lazily by the first
+``observe()`` that runs with jax already imported (this module never
+imports jax itself — the jax-free router/fleet processes import it);
+``GOLEFT_TPU_NO_COMPILE_HOOK=1`` registers nothing, degrading
+detection to the cache-delta path. A compile with no active
+observation is still recorded (family ``unattributed``) — the
+observatory is process-wide, not seam-wide.
 """
 
 from __future__ import annotations
 
 import contextlib
 import json
-import logging
 import os
 import sys
 import threading
@@ -124,7 +123,7 @@ class CompileEvent:
     pid: int
     trigger: str
     ts: float  # epoch seconds
-    names: tuple = ()  # jit names from the log detector, bounded
+    names: tuple = ()  # jit names from the monitoring feed, bounded
 
     def to_dict(self) -> dict:
         return {
@@ -140,13 +139,13 @@ class CompileEvent:
 class _Observation:
     """The thread-local record of one in-flight observe() window."""
 
-    __slots__ = ("family", "signature", "trigger", "log_names")
+    __slots__ = ("family", "signature", "trigger", "names")
 
     def __init__(self, family: str, signature: str, trigger: str):
         self.family = family
         self.signature = signature
         self.trigger = trigger
-        self.log_names: list[str] = []
+        self.names: list[str] = []
 
 
 class _ObsStack(threading.local):
@@ -171,7 +170,7 @@ class CompileTracker:
         self._registry = registry
         self._tracer = tracer
         self._backend: str | None = None
-        # count_compiles() windows: name lists the log hook feeds
+        # count_compiles() windows: name lists the compile hook feeds
         self._windows: list[list[str]] = []
 
     # the registry/tracer default to the process-wide singletons but
@@ -206,11 +205,11 @@ class CompileTracker:
                 trigger: str = ""):
         """Wrap ONE dispatch: always counts a hit for (family,
         signature); when a compile is detected (cache-size delta
-        and/or attributed log records), records the CompileEvent, the
-        registry counters and the nested ``xla.compile.<family>``
-        span. Exceptions pass through untouched — a failed dispatch
+        and/or attributed monitoring events), records the
+        CompileEvent, the registry counters and the nested
+        ``xla.compile.<family>`` span. Exceptions pass through untouched — a failed dispatch
         that compiled first still cost the compile."""
-        ensure_log_hook()
+        ensure_compile_hook()
         ob = _Observation(family, canonical_signature(signature),
                           trigger or family)
         size0 = None
@@ -229,7 +228,7 @@ class CompileTracker:
             if size0 is not None:
                 delta = max(0, int(cache_size_fn()) - size0)
             # one compile seen by both detectors is ONE compile
-            n = max(delta, len(ob.log_names))
+            n = max(delta, len(ob.names))
             self._record(ob, n, t0, t1)
 
     def _record(self, ob: _Observation, n: int, t0: float,
@@ -258,7 +257,7 @@ class CompileTracker:
                     family=ob.family, signature=ob.signature,
                     backend=key[2], duration_s=wall, compiles=n,
                     pid=os.getpid(), trigger=ob.trigger,
-                    ts=time.time(), names=tuple(ob.log_names[:8]))
+                    ts=time.time(), names=tuple(ob.names[:8]))
                 self._events.append(ev)
                 live = sum(1 for r in self._stats.values()
                            if r["compiles"] > 0)
@@ -278,33 +277,38 @@ class CompileTracker:
             family=ob.family, signature=ob.signature,
             compiles=n, backend=key[2], trigger=ob.trigger)
 
-    # ---- the log-hook feed ----
+    # ---- the jax.monitoring feed ----
 
-    def _on_compile_log(self, name: str) -> None:
-        """One ``jax_log_compiles`` WARNING record: attribute it to
-        the emitting thread's innermost observation, or record it
-        unattributed — the observatory misses nothing either way."""
-        self._reg().counter("xla.compiles_total").inc()
+    def _on_compile_log(self, name: str, seconds: float = 0.0) -> None:
+        """Log one executable built (compiled, or fetched from the
+        persistent cache): attribute it to the emitting thread's
+        innermost observation, or record it unattributed — the
+        observatory misses nothing either way."""
+        reg = self._reg()
+        reg.counter("xla.compiles_total").inc()
+        if seconds:
+            reg.counter("xla.compile_seconds_total").inc(
+                round(seconds, 6))
         with self._lock:
             for w in self._windows:
                 w.append(name)
         stack = self._ctx.stack
         if stack:
-            stack[-1].log_names.append(name)
+            stack[-1].names.append(name)
             return
         # no seam around this compile (warmup pass, a direct jit):
-        # synthesize a zero-length observation so it still lands in
-        # the stats/events/counters
+        # synthesize an observation as long as the compile so it
+        # still lands in the stats/events/counters
         ob = _Observation("unattributed", "", name)
-        ob.log_names.append(name)
+        ob.names.append(name)
         t = time.perf_counter()
-        self._record(ob, 1, t, t)
+        self._record(ob, 1, t - seconds, t)
 
     # ---- bench windows ----
 
     @contextlib.contextmanager
     def window(self):
-        """Collect every compile-log name recorded while the window
+        """Collect every compiled jit's name recorded while the window
         is open (the bench's ``_count_compiles`` contract: ``.names``
         on the yielded handle)."""
         names: list[str] = []
@@ -392,105 +396,68 @@ def observe(family: str, signature=None, cache_size_fn=None,
         yield ob
 
 
-# ------------------------------------------------- jax log-hook plumbing
+# ------------------------------------------------ jax.monitoring feed
 
-class _JaxCompileLogHandler(logging.Handler):
-    """The jax_log_compiles WARNING feed ("Compiling <name> with
-    global shapes..." from jax._src.interpreters.pxla). Fragile by
-    nature — a jax upgrade can rename logger or message — which is
-    why every seam that can also passes ``cache_size_fn`` and the
-    bench keeps its independent jit-cache cross-check."""
-
-    def __init__(self, tracker: CompileTracker):
-        super().__init__(level=logging.WARNING)
-        self._tracker = tracker
-
-    def emit(self, record):
-        msg = record.getMessage()
-        if msg.startswith("Compiling "):
-            name = msg.split(" with ")[0][len("Compiling "):]
-            self._tracker._on_compile_log(name)
-
+#: jax's own event for one executable built — compiled, or fetched
+#: from the persistent cache. It fires on the dispatching thread, with
+#: the jit's name as ``fun_name`` ("jit(name)") and the seconds spent
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "xla.cache_hits_total",
+    "/jax/compilation_cache/cache_misses": "xla.cache_misses_total",
+}
 
 _HOOK_LOCK = threading.Lock()
-_HOOK: _JaxCompileLogHandler | None = None
+_HOOK = False
 
 
-def ensure_log_hook() -> bool:
-    """Install the process-wide compile-log hook once jax is loaded.
+def _on_duration(event, duration, fun_name="", **_kw):
+    if event == _COMPILE_EVENT:
+        TRACKER._on_compile_log(fun_name, duration)
+
+
+def _on_event(event, **_kw):
+    counter = _CACHE_EVENTS.get(event)
+    if counter:
+        TRACKER._reg().counter(counter).inc()
+
+
+def ensure_compile_hook() -> bool:
+    """Register the process-wide ``jax.monitoring`` listeners once jax
+    is loaded: every compile's name and seconds, and whether the
+    persistent cache answered.
 
     Never imports jax itself (jax-free routers call observe()-guarded
-    paths too); a no-op until ``jax`` appears in sys.modules, then:
-    ``jax_log_compiles=True``, a WARNING handler on logger "jax" with
-    ``propagate=False`` (count quietly, don't spray stderr), and the
-    ``jax._src.dispatch`` logger disabled (jax_log_compiles also
-    elevates its per-op "Finished tracing/MLIR/XLA" chatter), and
-    ``jax.monitoring`` listeners feeding ``xla.compile_seconds_total``
-    / ``xla.cache_hits_total`` / ``xla.cache_misses_total``.
+    paths too): a no-op until ``jax`` appears in sys.modules. A jax
+    without these listeners raises here, it never goes quiet.
     ``GOLEFT_TPU_NO_COMPILE_HOOK=1`` opts out entirely."""
     global _HOOK
-    if _HOOK is not None:
+    if _HOOK:
         return True
     if os.environ.get("GOLEFT_TPU_NO_COMPILE_HOOK"):
         return False
     if "jax" not in sys.modules:
         return False
     with _HOOK_LOCK:
-        if _HOOK is not None:
-            return True
-        import jax
+        if not _HOOK:
+            import jax
 
-        jax.config.update("jax_log_compiles", True)
-        lg = logging.getLogger("jax")
-        if lg.level > logging.WARNING or lg.level == logging.NOTSET:
-            lg.setLevel(logging.WARNING)
-        lg.propagate = False
-        h = _JaxCompileLogHandler(TRACKER)
-        lg.addHandler(h)
-        # jax's logging_config pins its own stderr StreamHandler
-        # directly on logger "jax", so propagate=False alone still
-        # sprays "Compiling fn with global shapes..." per cache miss;
-        # drop exactly that handler (plain StreamHandler -> stderr),
-        # leaving any user-attached file/custom handlers alone
-        for other in list(lg.handlers):
-            if other is not h \
-                    and type(other) is logging.StreamHandler \
-                    and getattr(other, "stream", None) is sys.stderr:
-                lg.removeHandler(other)
-        logging.getLogger("jax._src.dispatch").disabled = True
-        # jax's own monitoring feed, for what the log text cannot say:
-        # seconds spent in the backend compiler (or in fetching the
-        # program from the persistent cache instead), and whether the
-        # persistent cache answered — seam or no seam around the jit
-        reg = get_registry()
-
-        def _on_event(event, **_kw):
-            if event == "/jax/compilation_cache/cache_hits":
-                reg.counter("xla.cache_hits_total").inc()
-            elif event == "/jax/compilation_cache/cache_misses":
-                reg.counter("xla.cache_misses_total").inc()
-
-        def _on_duration(event, duration, **_kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                reg.counter("xla.compile_seconds_total").inc(
-                    round(duration, 6))
-
-        jax.monitoring.register_event_listener(_on_event)
-        jax.monitoring.register_event_duration_secs_listener(
-            _on_duration)
-        _HOOK = h
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            _HOOK = True
     return True
 
 
 @contextlib.contextmanager
 def count_compiles():
     """The bench's compile window (bench.py ``_count_compiles``): a
-    handle whose ``.names`` lists every jit name the log hook saw
+    handle whose ``.names`` lists every jit name the compile hook saw
     while the window was open. Imports jax (the bench already has)
     so the hook is live before the window starts."""
     import jax  # noqa: F401 — force the module into sys.modules
 
-    ensure_log_hook()
+    ensure_compile_hook()
     with TRACKER.window() as h:
         yield h
 

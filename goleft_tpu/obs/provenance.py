@@ -14,9 +14,10 @@ _cached: dict | None = None
 
 
 def backend_provenance(refresh: bool = False) -> dict:
-    """{platform, device, device_kind, device_count, jax} for the live
-    backend — or an ``{"error": ...}`` record when no backend comes up
-    (provenance must never crash the run it describes).
+    """{platform, device, device_kind, device_count, jax,
+    compile_cache_dir} for the live backend — or an ``{"error": ...}``
+    record when no backend comes up (provenance must never crash the
+    run it describes).
 
     Cached after the first successful look: the answer cannot change
     within a process, and the hot device-span path reads it per
@@ -37,6 +38,10 @@ def backend_provenance(refresh: bool = False) -> dict:
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
             "jax": jax.__version__,
+            # where this process's persistent compile cache is (None:
+            # it has none) — a run's compile seconds mean nothing
+            # without it
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
         }
     except Exception as e:  # noqa: BLE001 — degrade, don't crash
         return {"error": repr(e)}

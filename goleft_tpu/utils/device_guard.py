@@ -9,8 +9,8 @@ once, in this process, before any backend use.
   child and nothing is remembered between runs: a run that cannot have
   the chip fails, it never computes somewhere else in silence.
 - The persistent compile cache lives where ``JAX_COMPILATION_CACHE_DIR``
-  says; without it, at the fixed ``<checkout>/.jax_cache`` (the path is
-  part of the cache key, so it must not move between runs).
+  says; without it, at the fixed ``<checkout>/.jax_cache``, so that
+  every run of one checkout finds what the last one compiled.
 
 ``cli.main`` (device commands), ``bench.py`` and ``__graft_entry__.py``
 all come through :func:`take_backend`.
@@ -29,8 +29,12 @@ NO_ACCELERATOR = (
     "GOLEFT_TPU_CPU=1 or JAX_PLATFORMS=cpu to run on the CPU")
 
 
+def _cpu_knob() -> bool:
+    return os.environ.get("GOLEFT_TPU_CPU", "").strip() == "1"
+
+
 def cpu_requested() -> bool:
-    return bool(os.environ.get("GOLEFT_TPU_CPU")) or os.environ.get(
+    return _cpu_knob() or os.environ.get(
         "JAX_PLATFORMS", "").strip().lower() == "cpu"
 
 
@@ -43,7 +47,7 @@ def take_backend() -> list:
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
-    if os.environ.get("GOLEFT_TPU_CPU"):
+    if _cpu_knob():
         jax.config.update("jax_platforms", "cpu")
     devs = jax.devices()
     if devs[0].platform == "cpu" and not cpu_requested():

@@ -49,6 +49,7 @@ def config_updates(monkeypatch):
     ({"JAX_PLATFORMS": "tpu,cpu"}, False),  # the chip machine's own
     ({"JAX_PLATFORMS": "tpu"}, False),
     ({"GOLEFT_TPU_CPU": ""}, False),
+    ({"GOLEFT_TPU_CPU": "0"}, False),  # only "1" asks, as the docs say
 ])
 def test_cpu_only_when_asked(no_env, monkeypatch, env, asked):
     for k, v in env.items():
@@ -56,8 +57,11 @@ def test_cpu_only_when_asked(no_env, monkeypatch, env, asked):
     assert device_guard.cpu_requested() is asked
 
 
+@pytest.mark.parametrize("env", [{}, {"GOLEFT_TPU_CPU": "0"}])
 def test_unasked_cpu_exits_with_the_one_line(no_env, monkeypatch,
-                                             config_updates):
+                                             config_updates, env):
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
     monkeypatch.setattr(jax, "devices", lambda: [_Dev("cpu")])
     with pytest.raises(SystemExit) as e:
         device_guard.take_backend()
@@ -120,8 +124,9 @@ def test_cache_dir_defaults_to_the_checkout(no_env, monkeypatch,
 
 
 def test_cache_path_is_fixed():
-    """The path is part of the cache key: nothing in it may change
-    from run to run — no temp directory, pid or clock."""
+    """A run finds what the last one compiled only at the same path:
+    nothing in it may change from run to run — no temp directory, pid
+    or clock."""
     import tempfile
 
     assert device_guard.CACHE_DIR == os.path.join(REPO, ".jax_cache")
@@ -202,6 +207,10 @@ def test_device_command_counts_its_compiles_in_the_manifest(tmp_path):
     assert out.returncode == 0, out.stderr[-800:]
     doc = json.load(open(tmp_path / "run.json"))
     assert doc["backend"]["platform"] == "cpu"
+    # the manifest names the compile cache the run used
+    assert doc["backend"]["compile_cache_dir"] == (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or os.path.join(REPO, ".jax_cache"))
     c = doc["metrics"]["counters"]
     assert c["xla.compiles_total"] >= 1
     assert c["xla.compile_seconds_total"] > 0
@@ -221,12 +230,12 @@ def test_private_jit_cache_size_works_on_the_installed_jax():
     assert f._cache_size() == 1
 
 
-def test_compile_log_hook_sees_an_unseamed_compile():
-    """The log-text detector on the installed jax: a jit compiled
+def test_compile_hook_sees_an_unseamed_compile():
+    """The jax.monitoring detector on the installed jax: a jit compiled
     outside any observe() seam still lands in the tracker."""
     from goleft_tpu.obs import compiles
 
-    assert compiles.ensure_log_hook()
+    assert compiles.ensure_compile_hook()
     n0 = compiles.TRACKER.compiles_total
 
     def unseamed_program(x):

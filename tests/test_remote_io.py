@@ -30,6 +30,10 @@ def _fresh_identity_cache():
     remote.invalidate_identity()
     yield
     remote.invalidate_identity()
+    # every test's server has a port of its own, so the idle keep-alive
+    # connections to it are never reused: hang them up, or each holds a
+    # handler thread of the stopped server for the rest of the process
+    remote._POOL.clear()
 
 
 DATA = bytes(range(256)) * 2048  # 512 KiB
