@@ -1090,8 +1090,9 @@ def bench_cohort_device(n_samples: int = 20, ref_len: int = 4_000_000,
             "seconds": round(t_p, 3),
             "identical_output": True,  # divergence raises above
             "stage_spans": tm.as_dict(),
-            "overlap_efficiency": overlap_efficiency(tm, wall=t_p),
-            "note": "per-stage span totals for decode/stage/transfer/"
+            "overlap_efficiency": overlap_efficiency(
+                tm, wall=t_p, compute_stage="device-compute"),
+            "note": "per-stage span totals for host-decode/device-"
                     "compute; overlap_efficiency = hidden non-compute "
                     "seconds / hideable non-compute seconds (1.0 = "
                     "wall equals compute; None = nothing recorded)",

@@ -35,7 +35,7 @@ ID = "obs-span-leak"
 #: resolved-origin suffixes that ARE span factories (the obs facade
 #: functions and the Tracer methods through the module-level TRACER)
 SPAN_ORIGIN_SUFFIXES = (
-    "obs.span", "obs.trace", "obs.device_span", "obs.maybe_span",
+    "obs.span", "obs.trace", "obs.device_span",
     "obs.tracing.TRACER.span", "obs.tracing.TRACER.trace",
 )
 

@@ -8,8 +8,8 @@ Global observability flags — valid before OR after the subcommand name
 (they are stripped here, so individual commands never re-declare them):
 
   --trace-out FILE    write the run's span timeline as Chrome
-                      trace-event JSON (loads in Perfetto); also turns
-                      on per-dispatch device-event fencing
+                      trace-event JSON (loads in Perfetto); the run
+                      is the one an untraced user gets, nothing fenced
   --metrics-out FILE  write the run manifest (env + backend provenance
                       + span summary + metrics-registry snapshot)
   --log-level LEVEL   debug/info/warning/error on the goleft-tpu.*
@@ -270,11 +270,6 @@ def main(argv: list[str] | None = None) -> int:
         from .resilience import faults
 
         faults.install(gopts["inject_faults"])
-    if gopts["trace_out"]:
-        # a trace artifact without honest per-dispatch device time is
-        # half an artifact: --trace-out implies device-event fencing
-        obs.set_device_events(True)
-
     if PROGS[prog][2]:
         # multi-host world (no-op without GOLEFT_TPU_COORDINATOR): must
         # come before take_backend's jax.devices() brings the backend up

@@ -27,7 +27,7 @@ from ..io.bai import read_bai, query_voffset
 from ..io.bam import open_bam_file
 from .depth import _decode_shard_segments
 from ..io.fai import read_fai, write_fai
-from ..obs import get_registry
+from .. import obs
 from ..ops.coverage import bucket_size, window_bounds
 from ..utils.decode_scaling import auto_processes, effective_cores
 from ..ops.depth_pipeline import shard_depth_pipeline
@@ -120,9 +120,12 @@ def cohort_matrix_blocks(
     ``prefetch_depth`` >= 1 routes the shard loop through the async
     staging pipeline (parallel/prefetch.py): up to that many shards are
     decoded, packed and (device engine) transferred ahead of the shard
-    being computed, with per-stage decode/stage/transfer/compute spans
-    recorded into ``stage_timer`` (a utils.profiling.StageTimer).
-    ``0`` is today's serial path; both produce identical matrices.
+    being computed. ``0`` is today's serial path; both produce
+    identical matrices, and the device engine records the same spans on
+    either: ``host-decode`` (one a sample-shard) and ``device-compute``
+    stages into ``stage_timer`` (a utils.profiling.StageTimer), the
+    consumer's ``decode-wait``, and ``pack``/``h2d``/``device-wait``/
+    ``d2h``/``unpack`` transfer spans (docs/observability.md).
 
     Resilience (goleft_tpu/resilience/, all optional):
       - ``checkpoint`` (CheckpointStore): each region's per-sample
@@ -284,9 +287,11 @@ def cohort_matrix_blocks(
     # Executor, so retry/quarantine/checkpoint compose here exactly as
     # they do for the scheduler and serve paths
     from ..plan import Executor as PlanExecutor, Step
+    from ..utils.profiling import StageTimer
 
     pex = PlanExecutor(policy=policy, quarantine=quarantine,
                        checkpoint=checkpoint)
+    timer = stage_timer if stage_timer is not None else StageTimer()
 
     def _guard_sample(i, key, thunk, fallback):
         """Per-sample resilience boundary: retry under the policy,
@@ -305,14 +310,22 @@ def cohort_matrix_blocks(
         columns + the shared filter/clip)."""
         i, h, bai, tid, s, e = args
         empty = np.zeros(0, np.int32)
-        return _guard_sample(
-            i, (names[i], s, e),
-            lambda: _decode_shard_segments(h, bai, tid, s, e, mapq),
-            lambda: (empty, empty))
+        with timer.stage("host-decode"):
+            return _guard_sample(
+                i, (names[i], s, e),
+                lambda: _decode_shard_segments(h, bai, tid, s, e, mapq),
+                lambda: (empty, empty))
 
     def submit_decodes(ex, c, s, e):
+        # a bare pool does not carry the submitting thread's trace
+        ctx = obs.capture()
+
+        def task(args):
+            with obs.attach(ctx):
+                return decode(args)
+
         return [
-            ex.submit(decode, (i, h, b, tm.get(c, -1), s, e))
+            ex.submit(task, (i, h, b, tm.get(c, -1), s, e))
             for i, (h, b, tm) in enumerate(zip(handles, bais,
                                                tid_maps))
         ]
@@ -426,27 +439,30 @@ def cohort_matrix_blocks(
             keep[i, :n] = True  # pre-filtered in decode()
         return seg_s, seg_e, keep
 
-    def put_sharded(args):
-        """Place one packed batch across the mesh's devices, and say
-        in the metrics where it landed: how many devices hold a shard
-        and how many sample rows each holds (S_pad rows on every one
-        would be copies, not a split)."""
-        args = tuple(jax.device_put(a, sharding) for a in args)
-        shards = args[0].addressable_shards
-        reg = get_registry()
-        reg.gauge("cohortdepth.batch_devices").set(
-            len({sh.device for sh in shards}))
-        reg.gauge("cohortdepth.batch_shard_rows").set(
-            shards[0].data.shape[0])
+    def transfer_device(args, region=None):
+        """Place one packed batch on the device (across the mesh's
+        devices when there are several, and then say in the metrics
+        where it landed: how many devices hold a shard and how many
+        sample rows each holds; S_pad rows on every one would be
+        copies, not a split)."""
+        args = obs.h2d(args, sharding)
+        if sharding is not None:
+            shards = args[0].addressable_shards
+            reg = obs.get_registry()
+            reg.gauge("cohortdepth.batch_devices").set(
+                len({sh.device for sh in shards}))
+            reg.gauge("cohortdepth.batch_shard_rows").set(
+                shards[0].data.shape[0])
         return args
 
     def run_pipeline(args, c, s, e):
         w0 = s // window * window
-        sums = np.asarray(_batched_pipeline(
+        (sums,) = obs.fetch(_batched_pipeline(
             *args, np.int32(w0), np.int32(s),
             np.int32(e), cap, length, window,
-        ))[:S]
-        return emit_block(c, s, e, sums)
+        ))
+        with obs.span("unpack", category="transfer"):
+            return emit_block(c, s, e, sums[:S])
 
     def blocks():
         with cf.ThreadPoolExecutor(max_workers=processes) as ex:
@@ -454,49 +470,46 @@ def cohort_matrix_blocks(
             # decode shard k+1 (native decode releases the GIL)
             pending = submit_decodes(ex, *compute_regions[0])
             for ri, (c, s, e) in enumerate(compute_regions):
-                segs = [f.result() for f in pending]
+                with obs.span("decode-wait", category="wait"):
+                    segs = [f.result() for f in pending]
                 if ri + 1 < len(compute_regions):
                     pending = submit_decodes(ex, *compute_regions[ri + 1])
-                args = pack_segblock(segs)
-                if sharding is not None:
-                    args = put_sharded(args)
-                yield run_pipeline(args, c, s, e)
+                with timer.stage("device-compute"):
+                    with obs.span("pack", category="transfer"):
+                        args = pack_segblock(segs)
+                    blk = run_pipeline(transfer_device(args), c, s, e)
+                yield blk
 
     # ---- prefetched variants: the async staging pipeline ----
     # (parallel/prefetch.py). The producer unit is a whole shard (all
     # samples, decoded serially on one worker); parallelism comes from
     # prefetch_depth shards in flight across the decode pool — vs the
     # serial paths' one-region lookahead. Identical matrices either way.
-    from ..utils.profiling import StageTimer
-
-    timer = stage_timer if stage_timer is not None else StageTimer()
 
     def produce_device(region):
         c, s, e = region
-        with timer.stage("decode"):
-            segs = [decode((i, h, b2, tm.get(c, -1), s, e))
-                    for i, (h, b2, tm) in enumerate(zip(handles, bais,
-                                                        tid_maps))]
-        with timer.stage("stage"):
+        segs = [decode((i, h, b2, tm.get(c, -1), s, e))
+                for i, (h, b2, tm) in enumerate(zip(handles, bais,
+                                                    tid_maps))]
+        with obs.span("pack", category="transfer"):
             return pack_segblock(segs)
-
-    def transfer_device(args, region):
-        with timer.stage("transfer"):
-            # asynchronous dispatch on the producer thread: the H2D
-            # copy of shard k+1 overlaps shard k's compute
-            if sharding is not None:
-                return put_sharded(args)
-            return tuple(jax.device_put(a) for a in args)
 
     def blocks_prefetched():
         from ..parallel.prefetch import ChunkPrefetcher
 
+        # transfer_device runs on the producer thread: the H2D copy of
+        # shard k+1 (and the wait for it) overlaps shard k's compute
         with ChunkPrefetcher(compute_regions, produce_device,
                              depth=prefetch_depth,
                              transfer=transfer_device,
                              processes=processes) as pf:
-            for ch in pf:
-                with timer.stage("compute"):
+            chunks = iter(pf)
+            while True:
+                with obs.span("decode-wait", category="wait"):
+                    ch = next(chunks, None)
+                if ch is None:
+                    return
+                with timer.stage("device-compute"):
                     blk = run_pipeline(ch.value, *ch.meta)
                 yield blk
 
@@ -659,16 +672,19 @@ def run_cohortdepth(
         out.write("#chrom\tstart\tend\t" + "\t".join(names) + "\n")
         use_native_fmt = native.get_lib() is not None
         for c, starts, ends, vals in blocks:
-            if use_native_fmt:
-                buf = native.format_matrix_rows(c, starts, ends, vals)
-                out.write(buf.decode("ascii"))
-            else:
-                lines = [
-                    f"{c}\t{starts[i]}\t{ends[i]}\t"
-                    + "\t".join(str(v) for v in vals[:, i]) + "\n"
-                    for i in range(len(starts))
-                ]
-                out.write("".join(lines))
+            # the block's format-and-write, not the generator's next()
+            with obs.span("write-output", category="stage"):
+                if use_native_fmt:
+                    buf = native.format_matrix_rows(c, starts, ends,
+                                                    vals)
+                    out.write(buf.decode("ascii"))
+                else:
+                    lines = [
+                        f"{c}\t{starts[i]}\t{ends[i]}\t"
+                        + "\t".join(str(v) for v in vals[:, i]) + "\n"
+                        for i in range(len(starts))
+                    ]
+                    out.write("".join(lines))
     finally:
         if checkpoint is not None:
             checkpoint.close()

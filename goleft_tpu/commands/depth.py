@@ -36,6 +36,7 @@ import numpy as np
 from ..io.bai import read_bai, query_voffset
 from ..io.bam import ReadColumns, open_bam_file
 from ..io.fai import Faidx, read_fai
+from .. import obs
 from ..ops.coverage import (
     bucket_size, pack_segments_u16, run_length_encode, window_bounds,
     CLASS_NAMES,
@@ -202,39 +203,41 @@ class DepthEngine:
                    np.int32(self.cap), np.int32(self.min_cov),
                    np.int32(self.max_mean))
         sel = slice(None) if kp is None else kp
-        packed = pack_segments_u16(seg_start, seg_end, sel) \
-            if self.packed else None
-        if packed is not None:
-            d, l, base, n_ent = packed
-            b = bucket_size(max(n_ent, 1))
-            dd = np.zeros(b, np.uint16)
-            ll = np.zeros(b, np.uint16)
-            dd[:n_ent] = d
-            ll[:n_ent] = l
-            sums, cls_p = shard_depth_pipeline_packed_cls_packed(
-                dd, ll, base, *scalars,
-                length=self.length, window=self.w_eff,
-            )
-        else:
-            b = bucket_size(n)
-            seg_s = np.full(b, 0, dtype=np.int32)
-            seg_e = np.full(b, 0, dtype=np.int32)
-            keep = np.zeros(b, dtype=bool)
-            if n:
-                seg_s[:n] = seg_start
-                seg_e[:n] = seg_end
-                keep[:n] = True if kp is None else kp
-            sums, cls_p = shard_depth_pipeline_cls_packed(
-                seg_s, seg_e, keep, *scalars,
-                length=self.length, window=self.w_eff,
-            )
-        starts, ends, _, _ = window_bounds(start, end, self.window)
-        n_win = len(starts)
-        sums = np.asarray(sums)[:n_win]
-        # classes come back 2-bit packed (1/4 the D2H bytes) and unpack
-        # on host with vectorized shifts
-        cls = unpack_cls_2bit(np.asarray(cls_p), self.length)
-        cls = cls[start - w0 : end - w0]
+        with obs.span("pack", category="transfer"):
+            packed = pack_segments_u16(seg_start, seg_end, sel) \
+                if self.packed else None
+            if packed is not None:
+                d, l, base, n_ent = packed
+                b = bucket_size(max(n_ent, 1))
+                dd = np.zeros(b, np.uint16)
+                ll = np.zeros(b, np.uint16)
+                dd[:n_ent] = d
+                ll[:n_ent] = l
+                pipeline = shard_depth_pipeline_packed_cls_packed
+                wire, rest = (dd, ll), (base,)
+            else:
+                b = bucket_size(n)
+                seg_s = np.full(b, 0, dtype=np.int32)
+                seg_e = np.full(b, 0, dtype=np.int32)
+                keep = np.zeros(b, dtype=bool)
+                if n:
+                    seg_s[:n] = seg_start
+                    seg_e[:n] = seg_end
+                    keep[:n] = True if kp is None else kp
+                pipeline = shard_depth_pipeline_cls_packed
+                wire, rest = (seg_s, seg_e, keep), ()
+        wire = obs.h2d(wire)
+        sums, cls_p = obs.fetch(*pipeline(
+            *wire, *rest, *scalars,
+            length=self.length, window=self.w_eff,
+        ))
+        with obs.span("unpack", category="transfer"):
+            starts, ends, _, _ = window_bounds(start, end, self.window)
+            sums = sums[:len(starts)]
+            # classes come back 2-bit packed (1/4 the D2H bytes) and
+            # unpack on host with vectorized shifts
+            cls = unpack_cls_2bit(cls_p, self.length)
+            cls = cls[start - w0 : end - w0]
         return starts, ends, sums, cls
 
     def run_segments_batch(self, segs, start: int, end: int):
@@ -250,30 +253,31 @@ class DepthEngine:
         B = len(segs)
         b = bucket_size(max(max((len(ss) for ss, _ in segs), default=0),
                             1))
-        seg_s = np.zeros((B, b), np.int32)
-        seg_e = np.zeros((B, b), np.int32)
-        keep = np.zeros((B, b), bool)
-        for i, (ss, ee) in enumerate(segs):
-            n = len(ss)
-            if n:
-                seg_s[i, :n] = ss
-                seg_e[i, :n] = ee
-                keep[i, :n] = True
+        with obs.span("pack", category="transfer"):
+            seg_s = np.zeros((B, b), np.int32)
+            seg_e = np.zeros((B, b), np.int32)
+            keep = np.zeros((B, b), bool)
+            for i, (ss, ee) in enumerate(segs):
+                n = len(ss)
+                if n:
+                    seg_s[i, :n] = ss
+                    seg_e[i, :n] = ee
+                    keep[i, :n] = True
         scalars = (np.int32(w0), np.int32(start), np.int32(end),
                    np.int32(self.cap), np.int32(self.min_cov),
                    np.int32(self.max_mean))
-        sums, cls_p = _batched_cls_packed()(
-            seg_s, seg_e, keep, *scalars,
+        wire = obs.h2d((seg_s, seg_e, keep))
+        sums, cls_p = obs.fetch(*_batched_cls_packed()(
+            *wire, *scalars,
             length=self.length, window=self.w_eff,
-        )
-        starts, ends, _, _ = window_bounds(start, end, self.window)
-        n_win = len(starts)
-        sums = np.asarray(sums)[:, :n_win]
-        cls_p = np.asarray(cls_p)
-        cls = np.stack([
-            unpack_cls_2bit(cls_p[i], self.length)[start - w0:end - w0]
-            for i in range(B)
-        ])
+        ))
+        with obs.span("unpack", category="transfer"):
+            starts, ends, _, _ = window_bounds(start, end, self.window)
+            sums = sums[:, :len(starts)]
+            cls = np.stack([
+                unpack_cls_2bit(row, self.length)[start - w0:end - w0]
+                for row in cls_p
+            ])
         return starts, ends, sums, cls
 
 
@@ -378,14 +382,13 @@ def run_depth(
     call_path = f"{prefix}{suffix}.callable.bed"
     tid_of = {n: i for i, n in enumerate(hdr.ref_names)}
 
-    from ..obs import get_registry
     from ..parallel.scheduler import ResultCache, file_key, run_sharded
     from ..utils.profiling import StageTimer, trace
 
     rc = ResultCache(cache_dir) if cache_dir else None
     fkey = file_key(bam) if cache_dir else bam
     timer = StageTimer()
-    reg = get_registry()
+    reg = obs.get_registry()
 
     def shard_fn(c, s, e, _fk):
         with timer.stage("host-decode"):

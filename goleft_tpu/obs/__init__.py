@@ -22,8 +22,6 @@ bring-up); anything needing jax resolves it lazily per call.
 
 from __future__ import annotations
 
-import contextlib
-
 from .logging import configure as configure_logging, get_logger
 from .metrics import (  # noqa: F401 — public API
     Counter, Gauge, Histogram, MetricsRegistry, REGISTRY, get_registry,
@@ -40,7 +38,8 @@ __all__ = [
     "Span", "SpanContext", "TRACER", "Tracer",
     "backend_provenance", "configure_logging", "capture", "attach",
     "device_span", "device_span_attrs", "dispatch", "env_provenance",
-    "get_logger", "get_registry", "get_tracer", "span", "trace",
+    "fetch", "get_logger", "get_registry", "get_tracer", "h2d", "span",
+    "trace",
 ]
 
 
@@ -70,13 +69,9 @@ def attach(ctx: "SpanContext | None"):
 
 # ---- device-event instrumentation ----
 
-def device_events_enabled() -> bool:
-    return TRACER.device_events
-
-
 def set_device_events(enabled: bool) -> None:
-    """Turn per-dispatch fencing on/off (the CLI's ``--trace-out``
-    sets it; GOLEFT_TPU_DEVICE_EVENTS=1 preseeds it)."""
+    """Turn per-dispatch fencing on/off (GOLEFT_TPU_DEVICE_EVENTS=1
+    preseeds it; no command switches it on)."""
     TRACER.device_events = bool(enabled)
 
 
@@ -104,8 +99,8 @@ def dispatch(name: str, fn, *args, **kwargs):
     """Run ``fn(*args, **kwargs)`` as an honest device event.
 
     When device events are off (the default) this is a plain call —
-    async dispatch keeps its pipelining. When on (``--trace-out`` /
-    GOLEFT_TPU_DEVICE_EVENTS=1), the call is wrapped in a span with
+    async dispatch keeps its pipelining. When on
+    (GOLEFT_TPU_DEVICE_EVENTS=1), the call is wrapped in a span with
     backend/platform/device-kind attributes and fenced with
     ``block_until_ready`` so the span's duration is the dispatch's
     device time, not the microseconds of enqueueing it.
@@ -157,12 +152,37 @@ class InstrumentedDispatch:
         return f"InstrumentedDispatch({self.__wrapped__!r})"
 
 
-@contextlib.contextmanager
-def maybe_span(enabled: bool, name: str, **attrs):
-    """span() when ``enabled``, else a no-op — for call sites whose
-    instrumentation is conditional on a flag they already hold."""
-    if not enabled:
-        yield None
-        return
-    with span(name, **attrs) as sp:
-        yield sp
+# ---- the dispatch hop: host <-> device transfers, unfenced ----
+# A dispatch site wraps its own host staging in span("pack"|"unpack",
+# category="transfer"); these two cover the hops in between. Category
+# "transfer" keeps all five out of the stage totals they are children of.
+
+def h2d(arrays, sharding=None) -> tuple:
+    """Place host ``arrays`` on the device (``sharding``, or the default
+    device) inside an ``h2d`` span that waits for the copies, and add
+    their bytes to ``xla.h2d_bytes_total``."""
+    import jax
+
+    with span("h2d", category="transfer"):
+        out = tuple(jax.device_put(a, sharding) for a in arrays)
+        jax.block_until_ready(out)
+    REGISTRY.counter("xla.h2d_bytes_total").inc(
+        sum(a.nbytes for a in arrays))
+    return out
+
+
+def fetch(*results) -> tuple:
+    """Device ``results`` as NumPy arrays: a ``device-wait`` span until
+    they are ready, at the place that is about to fetch them (the fetch
+    would wait there anyway, so no overlap is lost), then a ``d2h`` span
+    for the copies, whose bytes go to ``xla.d2h_bytes_total``."""
+    import jax
+    import numpy as np
+
+    with span("device-wait", category="transfer"):
+        jax.block_until_ready(results)
+    with span("d2h", category="transfer"):
+        out = tuple(np.asarray(r) for r in results)
+    REGISTRY.counter("xla.d2h_bytes_total").inc(
+        sum(a.nbytes for a in out))
+    return out

@@ -182,7 +182,7 @@ def shard_depth_pipeline_packed(
 
 
 # Device-event instrumentation: the module's dispatch boundaries are
-# proxies that (only when device events are on — --trace-out /
+# proxies that (only when device events are on —
 # GOLEFT_TPU_DEVICE_EVENTS=1) wrap each call in a span carrying
 # backend/platform/device-kind attributes and fence it with
 # block_until_ready, so per-dispatch device time is honest instead of

@@ -133,8 +133,9 @@ def distributed_cohort_matrix(
 
     ``prefetch_depth`` >= 1 routes each process's LOCAL shard loop
     through the async staging pipeline (parallel/prefetch.py) — the
-    decode/stage/transfer spans land in this process's ``stage_timer``;
-    the DCN gather is unaffected (it moves the already-reduced matrix).
+    host-decode/device-compute stages land in this process's
+    ``stage_timer``; the DCN gather is unaffected (it moves the
+    already-reduced matrix).
     """
     import jax
 
