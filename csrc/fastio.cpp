@@ -11,6 +11,7 @@
 // (see goleft_tpu/io/native.py, which builds lazily and falls back to the
 // pure-Python codecs on any failure).
 
+#include <climits>
 #include <cmath>
 #include <cstdint>
 #include <cstdio>
@@ -635,24 +636,51 @@ static long bwa_walk(void* stv, const uint8_t* buf, long have,
 // Segment collector: append each clipped, filter-passing M/=/X segment
 // instead of reducing — the device segment path's host stage. Using
 // the SAME walk template as the reduce paths means the shipped segment
-// set is the reduce engines' segment set by construction. Past cap the
-// walk keeps counting (no writes) so the caller can size one retry.
+// set is the reduce engines' segment set by construction. The collector
+// owns its output: a list of blocks, each as large as everything before
+// it (capacity doubles), so a region's stream is walked exactly once
+// whatever its coverage and no endpoint is written twice — a full
+// block is never copied or moved, only joined by the next. Block k
+// holds its start endpoints in p[0, len) and its ends in p[len, 2·len).
+// A failed allocation sets `oom` and drops the segment; bsg_walk turns
+// that into -4.
 struct BsgState : WalkCommon {
-    int32_t* seg_s;
-    int32_t* seg_e;
-    long cap, n;
-    inline void segment(long s, long e) {
-        if (n < cap) {
-            seg_s[n] = (int32_t)s;
-            seg_e[n] = (int32_t)e;
+    static const int MAX_BLOCKS = 64;  // doubling: more than a long counts
+    struct Block { int32_t* p; long len; } blocks[MAX_BLOCKS];
+    int n_blocks;
+    int32_t* cur;          // == blocks[n_blocks - 1].p
+    long cur_len, cur_n;   // its capacity and fill
+    long n;                // endpoints in all blocks
+    bool oom;
+    __attribute__((noinline, cold)) bool add_block(long len) {
+        if (oom || n_blocks == MAX_BLOCKS || len > (LONG_MAX >> 4)
+            || !(cur = (int32_t*)malloc(2 * len * sizeof(int32_t)))) {
+            oom = true;
+            return false;
         }
+        blocks[n_blocks].p = cur;
+        blocks[n_blocks++].len = cur_len = len;
+        cur_n = 0;
+        return true;
+    }
+    inline void segment(long s, long e) {
+        if (cur_n == cur_len && !add_block(n)) return;
+        cur[cur_n] = (int32_t)s;
+        cur[cur_len + cur_n] = (int32_t)e;
+        cur_n++;
         n++;
+    }
+    void release() {
+        for (int k = 0; k < n_blocks; k++) free(blocks[k].p);
+        n_blocks = 0;
     }
 };
 
 static long bsg_walk(void* stv, const uint8_t* buf, long have,
                      long* rpos_io) {
-    return bam_walk_records((BsgState*)stv, buf, have, rpos_io);
+    BsgState* st = (BsgState*)stv;
+    long status = bam_walk_records(st, buf, have, rpos_io);
+    return st->oom ? -4 : status;
 }
 
 extern "C" {
@@ -926,26 +954,59 @@ long bam_window_acc_stream(const uint8_t* comp, long comp_len,
 }
 
 // Streaming segment extraction for the device segment path: walk the
-// region once and emit absolute [s, e) endpoints of every clipped,
-// mapq/flag-passing aligned segment (w0 = 0, clip ceiling = end).
-// Returns kept-read count; *n_out = segments emitted (when > cap the
-// buffers were too small and the caller re-calls with cap >= *n_out —
-// nothing was written past cap). Explicit end required.
+// region ONCE and collect absolute [s, e) endpoints of every clipped,
+// mapq/flag-passing aligned segment (w0 = 0, clip ceiling = end) in the
+// collector's own blocks (see BsgState): cap_hint is only the first
+// block's size and changes neither the result nor the number of walks.
+// Returns kept-read count; on success *collector_out holds *n_out
+// endpoint pairs in 1 + *grows_out blocks and the caller hands it to
+// bam_segments_take, which copies them out and frees it. Every error
+// return has freed the blocks and leaves *collector_out NULL. Explicit
+// end required.
 long bam_segments_stream(const uint8_t* comp, long comp_len,
                          long c_begin, long in_block,
                          int target_tid, int start, int end,
                          int min_mapq, int flag_mask, int check_crc,
-                         int32_t* seg_s, int32_t* seg_e, long cap,
-                         long* n_out) {
+                         long cap_hint, void** collector_out,
+                         long* n_out, long* grows_out) {
+    *collector_out = NULL;
+    *n_out = *grows_out = 0;
     if (end < 0) return -8;
-    BsgState st = {{target_tid, start, end, /*w0=*/0, /*length=*/end,
-                    min_mapq, flag_mask, 0},
-                   seg_s, seg_e, cap, 0};
-    long status = bgzf_stream_walk(comp, comp_len, c_begin, in_block,
-                                   check_crc, bsg_walk, &st);
-    if (status < 0) return status;
-    *n_out = st.n;
-    return st.nk;
+    BsgState* st = (BsgState*)calloc(1, sizeof(BsgState));
+    if (!st) return -4;
+    *(WalkCommon*)st = {target_tid, start, end, /*w0=*/0, /*length=*/end,
+                        min_mapq, flag_mask, 0};
+    long status = st->add_block(cap_hint < 1 ? 1 : cap_hint)
+        ? bgzf_stream_walk(comp, comp_len, c_begin, in_block, check_crc,
+                           bsg_walk, st)
+        : -4;
+    if (status < 0) {
+        st->release();
+        free(st);
+        return status;
+    }
+    *collector_out = st;
+    *n_out = st->n;
+    *grows_out = st->n_blocks - 1;
+    return st->nk;
+}
+
+// Copy a collector's endpoints, in walk order, into seg_s / seg_e (room
+// for the *n_out that bam_segments_stream reported; both NULL: copy
+// nothing) and free it.
+void bam_segments_take(void* collector, int32_t* seg_s, int32_t* seg_e) {
+    BsgState* st = (BsgState*)collector;
+    if (!st) return;
+    for (int k = 0; seg_s && seg_e && k < st->n_blocks; k++) {
+        const BsgState::Block& b = st->blocks[k];
+        long fill = k + 1 < st->n_blocks ? b.len : st->cur_n;
+        memcpy(seg_s, b.p, fill * sizeof(int32_t));
+        memcpy(seg_e, b.p + b.len, fill * sizeof(int32_t));
+        seg_s += fill;
+        seg_e += fill;
+    }
+    st->release();
+    free(st);
 }
 
 // Inflate-only variant of the streaming walk (the walk consumes every

@@ -621,9 +621,8 @@ class BamFile:
             else:
                 c_begin = 0
                 in_block = self._body_start
-            # cap heuristic: ~5x coverage of 100bp reads over the span
-            # (span/16 segments) — an undersized cap costs a full
-            # re-walk of the stream, far worse than a few spare MB
+            # cap_hint is only the collector's first capacity: the C
+            # walk grows it as it fills and never re-walks
             return native.bam_segments_stream(
                 self._comp, c_begin, in_block, tid, start, end,
                 min_mapq, flag_mask,

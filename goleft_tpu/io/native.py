@@ -15,6 +15,7 @@ import threading
 import numpy as np
 
 from ..obs.logging import get_logger
+from ..obs.metrics import get_registry
 
 log = get_logger("native")
 
@@ -95,6 +96,7 @@ def _register_restypes(lib) -> None:
         lib.bam_window_reduce_stream.restype = ctypes.c_long
         lib.bam_window_acc_stream.restype = ctypes.c_long
         lib.bam_segments_stream.restype = ctypes.c_long
+        lib.bam_segments_take.restype = None
         lib.bgzf_stream_inflate_only.restype = ctypes.c_long
         lib.bgzf_deflate_block.restype = ctypes.c_long
         lib.rans4x8_decode.restype = ctypes.c_long
@@ -735,7 +737,9 @@ def bam_segments_stream(comp, c_begin: int, in_block: int,
     """Streaming extraction of the region's FILTERED clipped segment
     endpoints — the device segment path's host stage, sharing the
     reduce paths' walk/filters so the shipped set is identical by
-    construction (csrc/fastio.cpp::bam_segments_stream). Returns
+    construction (csrc/fastio.cpp::bam_segments_stream). The C side
+    walks the stream once, whatever the coverage, into blocks it adds
+    as they fill; ``cap_hint`` is only the first block's size. Returns
     (seg_start, seg_end) int32 arrays (absolute, clipped to
     [start, end)), or None when native is unavailable."""
     lib = get_lib()
@@ -746,29 +750,35 @@ def bam_segments_stream(comp, c_begin: int, in_block: int,
     if check_crc is None:
         check_crc = not os.environ.get("GOLEFT_TPU_SKIP_CRC")
     buf = _as_u8(comp)
-    cap = int(cap_hint) if cap_hint else 65536
-    while True:
-        seg_s = np.empty(cap, np.int32)
-        seg_e = np.empty(cap, np.int32)
-        n = ctypes.c_long(0)
-        nk = lib.bam_segments_stream(
-            _ptr(buf), ctypes.c_long(len(buf)),
-            ctypes.c_long(c_begin), ctypes.c_long(in_block),
-            ctypes.c_int(target_tid), ctypes.c_int(start),
-            ctypes.c_int(end), ctypes.c_int(min_mapq),
-            ctypes.c_int(flag_mask),
-            ctypes.c_int(1 if check_crc else 0),
-            _ptr(seg_s, ctypes.c_int32), _ptr(seg_e, ctypes.c_int32),
-            ctypes.c_long(cap), ctypes.byref(n),
-        )
-        if nk < 0:
-            raise ValueError(f"bam_segments_stream: {_stream_err(nk)}")
-        if n.value <= cap:
-            # copy: a slice VIEW would pin the full cap-sized buffers
-            # (~5MB per 10Mb shard) across the cohort's per-sample
-            # result fan-out even when n is tiny
-            return (seg_s[:n.value].copy(), seg_e[:n.value].copy())
-        cap = int(n.value) + 16  # one exact-size retry
+    collector = ctypes.c_void_p()
+    n = ctypes.c_long(0)
+    grows = ctypes.c_long(0)
+    reg = get_registry()
+    reg.counter("decode.segment_walks_total").inc()
+    nk = lib.bam_segments_stream(
+        _ptr(buf), ctypes.c_long(len(buf)),
+        ctypes.c_long(c_begin), ctypes.c_long(in_block),
+        ctypes.c_int(target_tid), ctypes.c_int(start),
+        ctypes.c_int(end), ctypes.c_int(min_mapq),
+        ctypes.c_int(flag_mask),
+        ctypes.c_int(1 if check_crc else 0),
+        ctypes.c_long(int(cap_hint) if cap_hint else 65536),
+        ctypes.byref(collector), ctypes.byref(n), ctypes.byref(grows),
+    )
+    if nk < 0:  # the C side has freed its blocks
+        raise ValueError(f"bam_segments_stream: {_stream_err(nk)}")
+    reg.counter("decode.segment_buffer_grows_total").inc(grows.value)
+    # exact-size arrays of our own: nothing capacity-sized stays pinned
+    # across the cohort's per-sample result fan-out
+    try:
+        seg_s = np.empty(n.value, np.int32)
+        seg_e = np.empty(n.value, np.int32)
+    except MemoryError:
+        lib.bam_segments_take(collector, None, None)  # just frees
+        raise
+    lib.bam_segments_take(collector, _ptr(seg_s, ctypes.c_int32),
+                          _ptr(seg_e, ctypes.c_int32))
+    return seg_s, seg_e
 
 
 def bam_window_acc_stream(comp, c_begin: int, in_block: int,

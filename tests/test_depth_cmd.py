@@ -150,6 +150,37 @@ def test_depth_empty_bam(tmp_path):
     assert crows == [("chr1", 0, 5000, "NO_COVERAGE")]
 
 
+def test_depth_walks_each_nonempty_shard_once(tmp_path):
+    """The mechanism's counter: a depth run walks each shard's BGZF
+    stream once for its segments, so decode.segment_walks_total rises
+    by depth.shards_total less the shards with no reads (a contig the
+    index has nothing for is never walked)."""
+    from goleft_tpu.io import native
+    from goleft_tpu.obs import get_registry
+
+    if native.get_lib() is None:
+        pytest.skip("native toolchain unavailable")
+    rng = np.random.default_rng(8)
+    reads = random_reads(rng, 2000, 0, REF_LEN, mapq_lo=30) \
+        + random_reads(rng, 200, 1, REF2_LEN, mapq_lo=30)
+    p = str(tmp_path / "w.bam")
+    write_bam_and_bai(p, reads, ref_names=("chr1", "chr2", "chr3"),
+                      ref_lens=(REF_LEN, REF2_LEN, 4000))
+    fa = write_fasta(str(tmp_path / "w.fa"),
+                     {"chr1": "A" * REF_LEN, "chr2": "C" * REF2_LEN,
+                      "chr3": "G" * 4000})
+    reg = get_registry()
+    walks = reg.counter("decode.segment_walks_total")
+    shards = reg.counter("depth.shards_total")
+    w0, s0 = walks.value, shards.value
+    dpath, _ = run_depth(p, str(tmp_path / "w"), reference=fa,
+                         window=500, mapq=20)
+    assert shards.value - s0 == 3  # one region a contig
+    assert walks.value - w0 == 2   # chr3 has no reads: no walk
+    assert_tiles([r for r in read_bed(dpath) if r[0] == "chr3"],
+                 "chr3", 4000)
+
+
 def test_depth_bed_regions(tmp_path):
     bam, ref = make_bam(tmp_path, n=500, seed=5)
     bedfile = str(tmp_path / "regions.bed")

@@ -25,18 +25,24 @@ needs_native = pytest.mark.skipif(
 )
 
 
-@needs_native
-@pytest.mark.parametrize("rs,re_", [(0, 100_000), (13_777, 61_003)])
-def test_window_reduce_matches_device_pipeline(tmp_path, rs, re_):
-    rng = np.random.default_rng(21)
+def _mixed_reads(rng, n, ref_len):
+    """Coordinate-sorted reads on tid 0 with mixed CIGARs, every MAPQ
+    and flags incl. skipped dup/secondary/QC-fail records: kept
+    segments != reads != records."""
     reads = []
-    # mixed CIGARs, mapqs, flags incl. skipped dup/secondary records
-    for s in np.sort(rng.integers(0, 99_000, size=3000)):
+    for s in np.sort(rng.integers(0, ref_len - 1000, size=n)):
         cig = rng.choice(["100M", "40M20D40M", "30M10N60M", "10S80M",
                           "50M2I48M"])
         mq = int(rng.integers(0, 61))
         fl = int(rng.choice([0, 0, 0, 0x400, 0x100, 0x200]))
         reads.append((0, int(s), cig, mq, fl))
+    return reads
+
+
+@needs_native
+@pytest.mark.parametrize("rs,re_", [(0, 100_000), (13_777, 61_003)])
+def test_window_reduce_matches_device_pipeline(tmp_path, rs, re_):
+    reads = _mixed_reads(np.random.default_rng(21), 3000, 100_000)
     p = str(tmp_path / "t.bam")
     write_bam_and_bai(p, reads, ref_names=("chr1",), ref_lens=(100_000,))
     bf = BamFile.from_file(p, lazy=True)
@@ -432,14 +438,7 @@ def test_read_segments_matches_filtered_columns(tmp_path, rs, re_):
     emit exactly the filtered/clipped segment set that columns decode +
     host filter produces — on the C streaming path, the eager fallback,
     and through a BAI voffset."""
-    rng = np.random.default_rng(21)
-    reads = []
-    for s in np.sort(rng.integers(0, 99_000, size=3000)):
-        cig = rng.choice(["100M", "40M20D40M", "30M10N60M", "10S80M",
-                          "50M2I48M"])
-        mq = int(rng.integers(0, 61))
-        fl = int(rng.choice([0, 0, 0, 0x400, 0x100, 0x200]))
-        reads.append((0, int(s), cig, mq, fl))
+    reads = _mixed_reads(np.random.default_rng(21), 3000, 100_000)
     p = str(tmp_path / "t.bam")
     write_bam_and_bai(p, reads, ref_names=("chr1",),
                       ref_lens=(100_000,))
@@ -474,9 +473,10 @@ def test_read_segments_matches_filtered_columns(tmp_path, rs, re_):
 
 @needs_native
 @pytest.mark.native_io
-def test_read_segments_buffer_retry(tmp_path):
-    """A cap_hint smaller than the segment count must transparently
-    retry with an exact-size buffer (nothing written past cap)."""
+def test_read_segments_buffer_grows(tmp_path):
+    """A cap_hint smaller than the segment count is only a first
+    capacity: the collector grows inside the one walk and still
+    returns the full arrays."""
     from goleft_tpu.io import native
 
     rng = np.random.default_rng(3)
@@ -491,6 +491,100 @@ def test_read_segments_buffer_retry(tmp_path):
     assert len(full_s) == 500
     assert np.array_equal(full_s, tiny_s)
     assert np.array_equal(full_e, tiny_e)
+
+
+# (reads, first capacity as a function of the kept-segment count)
+_COLLECTOR_CASES = {
+    "far-below": (6000, lambda kept: 16),
+    "equal": (1500, lambda kept: kept),
+    "one-less": (1500, lambda kept: kept - 1),
+    "seven": (1500, lambda kept: 7),  # 7 against exactly 500: above
+    "three-growths": (3000, lambda kept: kept // 5),
+    "above": (1500, lambda kept: kept + 1000),
+    "default-hint": (1500, lambda kept: None),
+}
+
+
+@needs_native
+@pytest.mark.native_io
+@pytest.mark.parametrize("case", list(_COLLECTOR_CASES))
+def test_segment_collector_one_walk_any_capacity(tmp_path, case):
+    """Whatever the first capacity is against the kept count, one call
+    is ONE walk of the stream (decode.segment_walks_total up by exactly
+    1), the capacity doubles just as often as it must, and the arrays
+    equal the host reference (filter_clip_segments over read_columns)."""
+    from goleft_tpu.io.bam import filter_clip_segments
+    from goleft_tpu.obs import get_registry
+
+    n_reads, cap_of = _COLLECTOR_CASES[case]
+    ref_len, rs, re_ = 200_000, 1_234, 187_655
+    reads = _mixed_reads(np.random.default_rng(11), n_reads, ref_len)
+    p = str(tmp_path / "c.bam")
+    write_bam_and_bai(p, reads, ref_names=("chr1",), ref_lens=(ref_len,))
+    h = BamFile.from_file(p, lazy=True)
+    want_s, want_e = filter_clip_segments(
+        h.read_columns(tid=0, start=rs, end=re_), rs, re_, 20, 0x704)
+    kept = len(want_s)
+    assert kept >= 500
+    cap = cap_of(kept)
+    want_grows, c = 0, cap or 65536
+    while c < kept:
+        c, want_grows = 2 * c, want_grows + 1
+    if case == "three-growths":
+        assert want_grows == 3
+    if case == "far-below":
+        assert want_grows >= 8
+
+    reg = get_registry()
+    walks = reg.counter("decode.segment_walks_total")
+    grows = reg.counter("decode.segment_buffer_grows_total")
+    w0, g0 = walks.value, grows.value
+    got_s, got_e = native.bam_segments_stream(
+        h._comp, 0, h._body_start, 0, rs, re_, 20, 0x704, cap_hint=cap)
+    assert walks.value - w0 == 1
+    assert grows.value - g0 == want_grows
+    assert got_s.dtype == np.int32 and got_e.dtype == np.int32
+    assert np.array_equal(got_s, want_s)
+    assert np.array_equal(got_e, want_e)
+    # exact-size arrays that own their memory (the result pins
+    # nothing capacity-sized)
+    assert got_s.flags.owndata and got_e.flags.owndata
+    assert len(got_s) == kept
+
+
+@needs_native
+@pytest.mark.native_io
+def test_segment_collector_empty_region_and_read_segments_walks(tmp_path):
+    """An empty region returns two empty int32 arrays after one walk,
+    and BamFile.read_segments — the call every engine makes — is one
+    walk a call even where its hint (the 65,536 floor on a 1 Mb tile)
+    is under the kept count."""
+    from goleft_tpu.io.bam import filter_clip_segments
+    from goleft_tpu.obs import get_registry
+
+    rng = np.random.default_rng(5)
+    starts = np.sort(rng.integers(0, 29_000, size=70_000))
+    reads = [(0, int(s), "50M", 60, 0) for s in starts]
+    p = str(tmp_path / "deep.bam")
+    write_bam_and_bai(p, reads, ref_names=("chr1", "chr2"),
+                      ref_lens=(30_000, 30_000))
+    h = BamFile.from_file(p, lazy=True)
+    reg = get_registry()
+    walks = reg.counter("decode.segment_walks_total")
+    grows = reg.counter("decode.segment_buffer_grows_total")
+    w0, g0 = walks.value, grows.value
+    got_s, got_e = h.read_segments(0, 0, 30_000, 20, 0x704)
+    assert (walks.value - w0, grows.value - g0) == (1, 1)
+    want_s, want_e = filter_clip_segments(
+        h.read_columns(tid=0, start=0, end=30_000), 0, 30_000, 20, 0x704)
+    assert len(got_s) == 70_000 > 65_536
+    assert np.array_equal(got_s, want_s) and np.array_equal(got_e, want_e)
+
+    w0 = walks.value
+    es, ee = h.read_segments(1, 0, 30_000, 20, 0x704)
+    assert walks.value - w0 == 1
+    assert es.dtype == np.int32 and ee.dtype == np.int32
+    assert len(es) == 0 and len(ee) == 0
 
 
 @needs_native

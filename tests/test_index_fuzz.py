@@ -209,12 +209,19 @@ def test_crai_sparse_high_seqid_is_cheap():
 
 
 @pytest.mark.native_io
-def test_segments_stream_corruption_fuzz(tmp_path):
-    """The new streaming segment extractor shares bgzf_stream_walk with
+@pytest.mark.parametrize("cap_hint", [None, 3],
+                         ids=["default-cap", "grown-buffers"])
+def test_segments_stream_corruption_fuzz(tmp_path, cap_hint):
+    """The streaming segment extractor shares bgzf_stream_walk with
     the reduce paths, so every corruption class must surface as the
     module's typed ValueError — never a crash, hang, or silent wrong
-    answer (single-byte flips across the whole stream)."""
+    answer (single-byte flips across the whole stream). With a first
+    capacity of 3 the collector has grown (up to 9 blocks for 800
+    segments) before a flip late in the stream is met: the error exit
+    must free every block (ASan, LeakSanitizer) and a clean stream
+    must still decode in full."""
     from goleft_tpu.io.bam import BamFile
+    from goleft_tpu.obs import get_registry
 
     if native.get_lib() is None:
         pytest.skip("native toolchain unavailable")
@@ -225,17 +232,46 @@ def test_segments_stream_corruption_fuzz(tmp_path):
     clean = open(p, "rb").read()
     h = BamFile.from_file(p, lazy=True)
     want = h.read_segments(0, 0, 30_000, 0, 0)
+    assert len(want[0]) == 800
+    grows = get_registry().counter("decode.segment_buffer_grows_total")
     # deterministic sweep of positions incl. headers, payloads, trailers
+    n_rejected = n_rejected_after_growth = 0
     for off in range(0, len(clean), max(1, len(clean) // 150)):
         data = bytearray(clean)
         data[off] ^= 0xFF
         try:
             got = native.bam_segments_stream(
                 np.frombuffer(bytes(data), np.uint8), 0,
-                h._body_start, 0, 0, 30_000, 0, 0, check_crc=True)
+                h._body_start, 0, 0, 30_000, 0, 0, check_crc=True,
+                cap_hint=cap_hint)
         except ValueError:
+            n_rejected += 1
             continue  # typed rejection: the contract
         # accepted: with CRC on, the payload must have been untouched
         # by the flip (e.g. header/extra fields) — results must match
         assert np.array_equal(got[0], want[0]) \
             and np.array_equal(got[1], want[1]), f"flip at {off}"
+    assert n_rejected > 100
+    if cap_hint is None:
+        return
+    # the same flips with CRC off reach the record walk itself: one in
+    # a later BGZF block is met with the collector already grown, and
+    # the call either raises the typed error or returns arrays (maybe
+    # of other records: without the CRC a flipped base is not an error)
+    g0 = grows.value
+    for off in range(len(clean) // 2, len(clean),
+                     max(1, len(clean) // 150)):
+        data = bytearray(clean)
+        data[off] ^= 0xFF
+        try:
+            got = native.bam_segments_stream(
+                np.frombuffer(bytes(data), np.uint8), 0,
+                h._body_start, 0, 0, 30_000, 0, 0, check_crc=False,
+                cap_hint=cap_hint)
+        except ValueError:
+            n_rejected_after_growth += 1
+            continue
+        assert len(got[0]) == len(got[1])
+    assert n_rejected_after_growth > 0
+    # only successful walks report their growths; each started from 3
+    assert grows.value > g0
