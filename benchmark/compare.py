@@ -1,39 +1,34 @@
 """What decides ``correct``: every job's output files against the plain
-reference's texts, byte for byte. The numbers compared are counts with
-the limit 0 (an exact comparison), one per kind of output, plus the jobs
-whose exit code was not 0."""
+reference's, each through the comparator its entry of the configuration's
+``outputs`` names (``"compare"``): a module under ``benchmark/comparators``
+with ``differ(got_path, want_path) -> int``, the lines that differ. The
+numbers compared are those counts with the limit 0 (an exact comparison),
+one per kind of output, plus the jobs whose exit code was not 0."""
 
 from __future__ import annotations
 
-
-def lines_differ(got: str, want: str) -> int:
-    """How many lines of ``got`` differ from ``want``'s, a missing or
-    surplus line counting as one."""
-    if got == want:
-        return 0
-    g, w = got.splitlines(), want.splitlines()
-    return sum(a != b for a, b in zip(g, w)) + abs(len(g) - len(w))
+import importlib
 
 
-def read(path: str) -> str:
-    try:
-        with open(path) as fh:
-            return fh.read()
-    except OSError:
-        return ""
+def comparator(name: str):
+    return importlib.import_module(f"comparators.{name}")
 
 
-def compare_jobs(jobs: list[dict], expected: dict[str, str]) -> dict:
-    """``jobs``: [{"rc", "outputs": {kind: path}}]; ``expected``:
-    {kind: text}. Returns the numbers compared, {name: {"value",
-    "limit"}}, summed over the jobs, and marks each job's ``ok``."""
-    totals = {f"{kind}_lines_differ": 0 for kind in expected}
+def compare_jobs(jobs: list[dict], outputs: list[dict],
+                 fixture_dir: str) -> dict:
+    """``jobs``: [{"rc", "outputs": {kind: path}}]; ``outputs``: the
+    configuration's, [{"name": kind, "compare", "expected"}]. Returns the
+    numbers compared, {name: {"value", "limit"}}, summed over the jobs,
+    and marks each job's ``ok``."""
+    kinds = [(o["name"], comparator(o["compare"]).differ,
+              f"{fixture_dir}/{o['expected']}") for o in outputs]
+    totals = {f"{kind}_lines_differ": 0 for kind, _, _ in kinds}
     nonzero = 0
     for job in jobs:
         bad = job["rc"] != 0
         nonzero += bad
-        for kind, want in expected.items():
-            n = lines_differ(read(job["outputs"][kind]), want)
+        for kind, differ, want in kinds:
+            n = differ(job["outputs"][kind], want)
             totals[f"{kind}_lines_differ"] += n
             bad = bad or n > 0
         job["ok"] = not bad

@@ -107,7 +107,7 @@ def module_seconds(trace: dict, regex: str) -> float:
 
 
 def reduce(args: dict, run: dict) -> float | None:
-    from work import job_bytes, job_sample_shards, peak
+    from work import job_bytes, job_units, peak
 
     trace = run.get("trace")
     if not trace:
@@ -119,7 +119,7 @@ def reduce(args: dict, run: dict) -> float | None:
     if not kernel_s:
         return None
     if what == "module_ms_per_sample_shard":
-        return 1e3 * kernel_s / job_sample_shards(run["meta"])
+        return 1e3 * kernel_s / job_units(run["meta"])
     if what == "hbm_roofline_percent":
         least_s = job_bytes(run["meta"]) / peak(run["device"]["kind"],
                                                 "hbm_bytes_per_s")

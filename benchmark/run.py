@@ -7,9 +7,13 @@ One process, one cell of ``BENCHMARK.json``. The cell's configuration,
 traffic mix and per-layer metrics are data files found by name under
 ``benchmark/configs``, ``benchmark/traffic`` and ``benchmark/metrics``; a
 mix names its driver (``benchmark/drivers``), a metric its reducer
-(``benchmark/reducers``). Fixtures and the plain reference are made by a
-JAX-free child (``fixtures.py``); the system under test is driven
-in-process through ``goleft_tpu.cli.main``.
+(``benchmark/reducers``), a configuration its maker (``benchmark/makers``)
+and each output's comparator (``benchmark/comparators``), and the
+fixture's ``meta.json`` the module that counts its device work
+(``benchmark/works``). Fixtures and the plain reference are made by a
+JAX-free child (``fixtures.py``), and ``meta.json`` is all this process
+reads of them; the system under test is driven in-process through
+``goleft_tpu.cli.main``.
 
 The last line of stdout is the result object; earlier lines are JSON
 notes (fixture, machine, each job's seconds, compile counters). A run
@@ -62,7 +66,14 @@ def applies(metric: dict, cell: str) -> bool:
 class Ctx:
     """What a traffic driver gets: the cell's data, where its jobs write,
     and the two calls back into the harness (``setup_done``,
-    ``profiler``)."""
+    ``profiler``).
+
+    Placeholders of the configuration's ``argv`` and ``outputs[].file``:
+    ``{prefix}`` (one job's own path, nothing there yet), ``{base}`` (its
+    last component: ``-d {prefix}`` writes ``{prefix}/{base}-indexcov.*``),
+    ``{dir}`` (the fixture's directory) and ``{ref}``, ``{fai}``, ``{bed}``
+    (``{dir}/ref.fa``, ``ref.fa.fai``, ``region.bed``). The argv token
+    ``{inputs}`` becomes one argument for each of ``meta["inputs"]``."""
 
     def __init__(self, config, mix, meta, fixture_dir, out_dir, seconds,
                  trace):
@@ -74,21 +85,27 @@ class Ctx:
         self.rss_at_setup_done = None
         self.trace_dir = os.path.join(out_dir, "trace")
         self._places = {
+            "dir": fixture_dir,
             "ref": f"{fixture_dir}/ref.fa", "fai": f"{fixture_dir}/ref.fa.fai",
             "bed": f"{fixture_dir}/region.bed"}
+
+    def _fill(self, text: str, prefix: str) -> str:
+        return text.format(prefix=prefix, base=os.path.basename(prefix),
+                           **self._places)
 
     def job_argv(self, prefix: str) -> list[str]:
         argv = []
         for tok in self.config["argv"]:
-            if tok == "{bams}":
-                argv += [f"{self.fixture_dir}/{b}" for b in self.meta["bams"]]
+            if tok == "{inputs}":
+                argv += [f"{self.fixture_dir}/{f}"
+                         for f in self.meta["inputs"]]
             else:
-                argv.append(tok.format(prefix=prefix, **self._places))
+                argv.append(self._fill(tok, prefix))
         return argv
 
     def job_outputs(self, prefix: str) -> dict[str, str]:
         return {kind: (f"{prefix}.stdout" if f == "stdout"
-                       else f.format(prefix=prefix))
+                       else self._fill(f, prefix))
                 for kind, f in self.output_files.items()}
 
     def setup_done(self) -> None:
@@ -142,6 +159,8 @@ from goleft_tpu.io.bai import query_voffset, read_bai
 from goleft_tpu.io.bam import open_bam_file
 if native.get_lib() is None:
     sys.exit(2)
+if len(sys.argv) < 2:  # a fixture with nothing to decode: loading is all
+    sys.exit(0)
 bam = sys.argv[1]
 voff = query_voffset(read_bai(bam + ".bai"), 0, 0)
 starts, _ = open_bam_file(bam, lazy=True).read_segments(
@@ -153,12 +172,13 @@ PORTABLE_BUILD = ["g++", "-O3", "-march=x86-64-v3", "-shared", "-fPIC",
                   "-o", "build/libgoleftio.so"]
 
 
-def native_library(bam: str):
+def native_library(bam: str | None):
     """(the loaded library, who built it). The run fails where the
-    program has no native decoder."""
+    program has no native decoder. ``bam`` is the file the probe decodes
+    200 kb of; without one the probe only builds and loads."""
     def probe() -> int:
         return subprocess.run(
-            [sys.executable, "-c", PROBE, bam], cwd=ROOT,
+            [sys.executable, "-c", PROBE, *([bam] if bam else [])], cwd=ROOT,
             env=dict(os.environ, PYTHONPATH=ROOT)).returncode
 
     marker = os.path.join(BENCH, ".native_rebuilt")
@@ -206,8 +226,7 @@ def program_spans(t0: float, t1: float) -> list[dict]:
 def counters() -> dict:
     from goleft_tpu.obs import get_registry
 
-    return {k: v for k, v in get_registry().counters().items()
-            if k.startswith("xla.")}
+    return get_registry().counters()
 
 
 def label_gaps(trace: dict, job: dict, spans: list[dict]) -> list:
@@ -270,7 +289,8 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT) -> int:
     os.makedirs(os.path.dirname(fixture_dir), exist_ok=True)
     meta, fixture_s = make_fixture(config_path, a.seed, fixture_dir)
     t_fixture = time.perf_counter()
-    lib, built = native_library(f"{fixture_dir}/{meta['bams'][0]}")
+    probe = meta["native_probe"]
+    lib, built = native_library(probe and f"{fixture_dir}/{probe}")
     # where set-up goes, for the notes: imports and backend, the native
     # library's probe (and build), then the driver's warm-up
     phases = {"backend_s": t_backend - T_START,
@@ -348,14 +368,13 @@ def measure(a, bench, cell, ctx, driver, devs, fixture_s,
                 "rss_gb": {"backend_up": rss_backend * 1e-9,
                            "setup_done": ctx.rss_at_setup_done * 1e-9,
                            "window_closed": rss_peak * 1e-9},
-                "compile_counters_after_warmup": ctx.counters_at_setup_done})
+                "counters_after_warmup": ctx.counters_at_setup_done})
     every_job = got["warmup"] + got["jobs"]
     for j in every_job:
         note(job=j["index"], seconds=j["t1"] - j["t0"], rc=j["rc"])
 
-    expected = {o["name"]: compare.read(f"{ctx.fixture_dir}/{o['expected']}")
-                for o in ctx.config["outputs"]}
-    numbers = compare.compare_jobs(every_job, expected)
+    numbers = compare.compare_jobs(every_job, ctx.config["outputs"],
+                                   ctx.fixture_dir)
     correct = bool(got["jobs"]) and all(
         n["value"] <= n["limit"] for n in numbers.values())
 

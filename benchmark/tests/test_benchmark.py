@@ -15,12 +15,14 @@ import sys
 import numpy as np
 import pytest
 
-import compare
 import control
 import fixtures
 import reference
 import run
 import work
+from comparators import lines
+from makers import bam_reads
+from works import depth_shards
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
@@ -42,10 +44,15 @@ def tiny_config(name: str) -> dict:
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
     """A checkout's shape in a temporary directory: BENCHMARK.json whose
-    configurations are the tiny ones; fixtures and runs land beside it."""
+    configurations are the tiny ones (those of ``TINY``: one that a later
+    PR brings comes with tests of its own); fixtures and runs land beside
+    it."""
     root = tmp_path_factory.mktemp("root")
     with open(f"{ROOT}/BENCHMARK.json") as fh:
         bench = json.load(fh)
+    bench["configs"] = [c for c in bench["configs"] if c["name"] in TINY]
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["config"] in TINY]
     for c in bench["configs"]:
         c["file"] = f"{c['name']}.json"
         (root / c["file"]).write_text(json.dumps(tiny_config(c["name"])))
@@ -168,15 +175,16 @@ def test_a_fault_under_the_timed_path_reads_not_correct(
 
 
 @pytest.mark.parametrize("name", ["depth30x", "cohort4x"])
-def test_every_control_fails_the_comparison(name):
+def test_every_control_fails_the_comparison(name, tmp_path):
     """The reference with a guarantee broken (bf16 window sums, no MAPQ
     filter, one wrong window sum) may not pass for the reference."""
     cfg = tiny_config(name)
     # at the cell's own coverage: 1x window sums fit bf16's 8 bits exactly
     with open(f"{BENCH}/configs/{name}.json") as fh:
         cfg["fixture"]["coverage"] = json.load(fh)["fixture"]["coverage"]
-    readings = control.control_readings(cfg, 11)
-    assert set(readings) == set(reference.CONTROLS)
+    readings = control.control_readings(cfg, 11, str(tmp_path))
+    assert set(readings) == set(reference.CONTROLS) == set(
+        bam_reads.CONTROLS)
     for ctl, numbers in readings.items():
         assert max(numbers.values()) > 0, ctl
     kind = "depth_bed" if name == "depth30x" else "matrix"
@@ -206,7 +214,12 @@ def test_fixture_is_byte_stable_for_a_seed_and_differs_between_seeds(
     assert blob("a") == blob("b")
     assert blob("a") != blob("c")
     meta = json.loads((tmp_path / "a" / "meta.json").read_text())
-    assert len(meta["bams"]) == 4 and meta["job_reads"] == 4 * 68_000
+    assert len(meta["inputs"]) == 4 and meta["job_reads"] == 4 * 68_000
+    assert meta["native_probe"] == "c000.bam"
+    assert meta["job_bases"] == 4 * 68_000 * 150
+    assert meta["work_unit"] == cfg["work_unit"]
+    # the work module is found by the kind the maker wrote: 4 samples x 2
+    assert work.job_units(meta) == 8
     # hard links, not copies
     assert os.stat(tmp_path / "a" / "c000.bam").st_nlink == 3
 
@@ -224,13 +237,14 @@ def test_fixture_payload_has_entropy(tmp_path):
 def test_work_bytes_of_one_hand_worked_shard():
     # 1.3 M kept segments over a 10 Mb span, 500 bp windows, classes out:
     # 8 * 1.3e6 + 2 * 4 * 1e7 + 4 * 1e7 / 500 + 1e7 / 4
-    assert work.shard_bytes(1_300_000, 10_000_000, 500, True) == \
+    assert depth_shards.shard_bytes(1_300_000, 10_000_000, 500, True) == \
         10_400_000 + 80_000_000 + 80_000 + 2_500_000
-    assert work.shard_bytes(0, 1000, 500, False) == 8000 + 8
-    meta = {"window": 500, "classes_out": False, "shards": [
-        {"start": 0, "end": 1000, "kept_segments": [1, 2]},
-        {"start": 1000, "end": 1500, "kept_segments": [0, 0]}]}
-    assert work.job_sample_shards(meta) == 4
+    assert depth_shards.shard_bytes(0, 1000, 500, False) == 8000 + 8
+    meta = {"work": {
+        "kind": "depth_shards", "window": 500, "classes_out": False,
+        "shards": [{"start": 0, "end": 1000, "kept_segments": [1, 2]},
+                   {"start": 1000, "end": 1500, "kept_segments": [0, 0]}]}}
+    assert work.job_units(meta) == 4
     assert work.job_bytes(meta) == 2 * 8008 + 24 + 2 * 4004
     assert work.peak("TPU v5 lite", "hbm_bytes_per_s") == 819e9
     with pytest.raises(KeyError):
@@ -238,10 +252,23 @@ def test_work_bytes_of_one_hand_worked_shard():
 
 
 def test_lines_differ_counts_changed_missing_and_surplus_lines():
-    assert compare.lines_differ("a\nb\n", "a\nb\n") == 0
-    assert compare.lines_differ("a\nx\n", "a\nb\n") == 1
-    assert compare.lines_differ("a\n", "a\nb\nc\n") == 2
-    assert compare.lines_differ("", "a\n") == 1
+    assert lines.lines_differ("a\nb\n", "a\nb\n") == 0
+    assert lines.lines_differ("a\nx\n", "a\nb\n") == 1
+    assert lines.lines_differ("a\n", "a\nb\nc\n") == 2
+    assert lines.lines_differ("", "a\n") == 1
+
+
+def test_the_lines_comparator_reads_files_and_needs_the_expected_one(
+        tmp_path):
+    (tmp_path / "want").write_text("a\nb\n")
+    (tmp_path / "got").write_text("a\nx\n")
+    want, got = str(tmp_path / "want"), str(tmp_path / "got")
+    assert lines.differ(got, want) == 1
+    # a job that wrote nothing wrote no line
+    assert lines.differ(str(tmp_path / "none"), want) == 2
+    # an expected file that is missing can never read as "nothing differs"
+    with pytest.raises(OSError):
+        lines.differ(got, str(tmp_path / "none"))
 
 
 TRACE = f"{BENCH}/tests/data/depth30x_job.xplane.pb"
@@ -271,9 +298,11 @@ def test_device_trace_reducer_on_a_recorded_trace():
     g0, g1 = t["gaps"][0]  # the longest: the host decodes, the chip waits
     assert (g0, g1 - g0) == (a0, pytest.approx(1.975878210, rel=1e-6))
 
-    meta = {"window": 500, "classes_out": True, "shards": [
-        {"start": s, "end": s + 10_000_000, "kept_segments": [1_300_000]}
-        for s in (0, 10_000_000, 20_000_000)]}
+    meta = {"work": {
+        "kind": "depth_shards", "window": 500, "classes_out": True,
+        "shards": [{"start": s, "end": s + 10_000_000,
+                    "kept_segments": [1_300_000]}
+                   for s in (0, 10_000_000, 20_000_000)]}}
     run_ = {"trace": t, "meta": meta, "device": {"kind": "TPU v5 lite"}}
 
     def reduce(**args):

@@ -92,3 +92,29 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
         assert set(m["workloads"]) <= cells
     assert sorted(f[:-5] for f in os.listdir(f"{BENCH}/metrics")) == sorted(
         m["name"] for m in bench["per_layer"])
+
+
+def test_every_configuration_names_a_maker_comparators_and_work_that_import():
+    """What a configuration names is there under ``makers/`` and
+    ``comparators/`` with the calls the harness makes; every module under
+    ``works/`` gives both counts (which of them a fixture uses, its maker
+    says in ``meta.json``, and ``fixtures.build`` imports it there)."""
+    with open(f"{ROOT}/BENCHMARK.json") as fh:
+        bench = json.load(fh)
+    for c in bench["configs"]:
+        with open(f"{ROOT}/{c['file']}") as fh:
+            cfg = json.load(fh)
+        assert cfg["work_unit"], c["name"]
+        maker = importlib.import_module(f"makers.{cfg['fixture']['maker']}")
+        assert callable(maker.build) and callable(maker.expected), c["name"]
+        assert isinstance(maker.CONTROLS, tuple), c["name"]
+        for out in cfg["outputs"]:
+            comparator = importlib.import_module(
+                f"comparators.{out['compare']}")
+            assert callable(comparator.differ), (c["name"], out["name"])
+    kinds = sorted(f[:-3] for f in os.listdir(f"{BENCH}/works")
+                   if f.endswith(".py"))
+    assert "depth_shards" in kinds
+    for kind in kinds:
+        counted = importlib.import_module(f"works.{kind}")
+        assert callable(counted.job_bytes) and callable(counted.job_units)

@@ -2,18 +2,15 @@
 fixture, not from the implementation: it reads the same whatever kernel,
 wire format, bucket or batching the program uses.
 
-Per sample-shard, in bytes of HBM traffic:
-
-    8 * kept_segments   two int32 endpoints per kept segment, un-padded
-  + 2 * 4 * span        one int32 per-base accumulator, written once and
-                        read once: the least a difference array and its
-                        scan can do
-  + 4 * span / window   window sums out
-  + span / 4            2-bit classes out (``depth`` only)
+What the algorithm is, the fixture says: ``meta["work"]["kind"]`` names a
+module under ``benchmark/works`` that gives ``job_bytes(work)``, the least
+HBM bytes of one job, and ``job_units(work)``, the pieces a kernel time is
+quoted per (sample-shards, for the depth pipeline).
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 
@@ -21,22 +18,16 @@ PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "peaks.json")
 
 
-def shard_bytes(kept_segments: int, span: int, window: int,
-                classes_out: bool) -> float:
-    return (8 * kept_segments + 2 * 4 * span + 4 * span / window
-            + (span / 4 if classes_out else 0))
+def _of(meta: dict):
+    return importlib.import_module(f"works.{meta['work']['kind']}")
 
 
-def job_sample_shards(meta: dict) -> int:
-    return sum(len(s["kept_segments"]) for s in meta["shards"])
+def job_units(meta: dict) -> int:
+    return _of(meta).job_units(meta["work"])
 
 
 def job_bytes(meta: dict) -> float:
-    """Least HBM bytes of one job: every sample-shard of the fixture."""
-    return sum(
-        shard_bytes(k, s["end"] - s["start"], meta["window"],
-                    meta["classes_out"])
-        for s in meta["shards"] for k in s["kept_segments"])
+    return _of(meta).job_bytes(meta["work"])
 
 
 def peak(device_kind: str, key: str) -> float:
