@@ -23,6 +23,7 @@ import sys
 
 import numpy as np
 
+from .. import obs
 from ..io.bai import read_bai
 from ..io.bgzf import BgzfWriter
 from ..io.crai import read_crai
@@ -51,22 +52,25 @@ class SampleIndex:
             # reference behavior: .cram rides its companion .crai
             # (indexcov.go:471-525 readIndex on rdr path + ".crai")
             path = path + ".crai"
+        from ..io import remote
+
         if path.endswith(".crai"):
-            self.sizes = read_crai(path).sizes()
+            data = remote.fetch_bytes(path)
+            self.sizes = read_crai(data).sizes()
             self.mapped = 0
             self.unmapped = 0
         else:
-            from ..io import remote
-
             bai_path = path
             if not path.endswith(".bai"):
                 bai_path = path + ".bai"
                 if not remote.exists(bai_path):
                     bai_path = path[:-4] + ".bai"
-            idx = read_bai(bai_path)
+            data = remote.fetch_bytes(bai_path)
+            idx = read_bai(data)
             self.sizes = idx.sizes()
             self.mapped = idx.mapped_total
             self.unmapped = idx.unmapped_total
+        self.nbytes = len(data)  # of the index file as read
         self.median = ops.median_size_per_tile(self.sizes)
 
     def normalized_depth(self, ref_id: int) -> np.ndarray:
@@ -191,7 +195,7 @@ def _index_file(path: str) -> str:
 
 
 def write_bed_block(bed, ref_name: str, lo: int, hi: int,
-                    mat_cols: np.ndarray, valid_cols: np.ndarray) -> None:
+                    mat_cols: np.ndarray, valid_cols: np.ndarray) -> int:
     """Format + write bed rows for bins [lo, hi) of one chromosome.
 
     ``mat_cols``/``valid_cols`` are the (samples, hi-lo) column slice.
@@ -201,16 +205,17 @@ def write_bed_block(bed, ref_name: str, lo: int, hi: int,
     when the native lib is built (byte-identical to np.char.mod
     "%.3g"). The emitted bytes depend only on the slice values, never
     on how the caller blocked its writes (BgzfWriter re-chunks to its
-    fixed block size).
+    fixed block size). Returns the bytes of text written.
     """
     from ..io import native
 
     idx = np.arange(lo, hi, dtype=np.int64)
     if native.get_lib() is not None:
-        bed.write(native.format_float_matrix_rows(
+        text = native.format_float_matrix_rows(
             ref_name, idx * TILE, (idx + 1) * TILE, mat_cols, valid_cols,
-        ))
-        return
+        )
+        bed.write(text)
+        return len(text)
     block = np.char.mod("%.3g", mat_cols.T)
     block[~valid_cols.T] = "0"
     starts_col = np.char.mod("%d", idx * TILE)
@@ -220,7 +225,9 @@ def write_bed_block(bed, ref_name: str, lo: int, hi: int,
         + "\t" + "\t".join(block[i]) + "\n"
         for i in range(hi - lo)
     ]
-    bed.write("".join(rows_txt).encode())
+    text = "".join(rows_txt).encode()
+    bed.write(text)
+    return len(text)
 
 
 def write_roc_rows(roc_fh, ref_name: str, rocs: np.ndarray) -> None:
@@ -260,24 +267,32 @@ def run_indexcov(
     from ..utils.profiling import StageTimer
 
     # wall-clock per pipeline stage, returned under "stages" (and
-    # recorded by bench.py's indexcov e2e entry)
+    # recorded by bench.py's indexcov e2e entry): the span vocabulary of
+    # docs/observability.md, "pca" being this command's own
     timer = StageTimer()
+    reg = obs.get_registry()
     # 8-way parallel index load, mirroring indexcov.go:417-434
     import concurrent.futures as cf
 
+    ctx = obs.capture()  # a bare pool does not carry this thread's trace
+
     def _load(p):
+        # one span an index file, on the thread that reads and scans it.
         # corrupt/truncated index -> clean CLI error naming the file,
         # not a traceback (the codecs' contract is typed ValueError)
-        try:
-            return SampleIndex(p)
-        except ValueError as e:
-            raise SystemExit(f"indexcov: {p}: {e}")
+        with obs.attach(ctx), timer.stage("host-decode"):
+            try:
+                return SampleIndex(p)
+            except ValueError as e:
+                raise SystemExit(f"indexcov: {p}: {e}")
 
-    with timer.stage("index_load"):
-        with cf.ThreadPoolExecutor(max_workers=8) as ex:
-            idxs = list(ex.map(_load, bams))
-            names = list(ex.map(get_short_name, bams))
+    with cf.ThreadPoolExecutor(max_workers=8) as ex:
+        idxs = list(ex.map(_load, bams))
+        names = list(ex.map(get_short_name, bams))
     n_samples = len(idxs)
+    reg.counter("indexcov.indexes_total").inc(n_samples)
+    reg.counter("indexcov.index_bytes_total").inc(
+        sum(i.nbytes for i in idxs))
 
     # per-chromosome checkpointing: the shard unit is one chromosome's
     # launched QC state. Every sample contributes to every chromosome
@@ -305,7 +320,10 @@ def run_indexcov(
 
     bed_fh = open(base + ".bed.gz", "wb")
     bed = BgzfWriter(bed_fh, level=1)
-    bed.write(("#chrom\tstart\tend\t" + "\t".join(names) + "\n").encode())
+    bed_text = reg.counter("indexcov.bed_text_bytes_total")  # before BGZF
+    header = ("#chrom\tstart\tend\t" + "\t".join(names) + "\n").encode()
+    bed.write(header)
+    bed_text.inc(len(header))
     roc_fh = open(base + ".roc", "w")
     roc_fh.write("#chrom\tcov\t" + "\t".join(names) + "\n")
 
@@ -330,9 +348,10 @@ def run_indexcov(
         latency of each chromosome). Empty chromosomes contribute
         nothing.
         """
-        with timer.stage("qc_launch"):
-            rows = [idx.normalized_depth(ref_id) for idx in idxs]
-            mat, valid, lengths = _pad_rows(rows)
+        with timer.stage("device-compute"):
+            with obs.span("pack", category="transfer"):
+                rows = [idx.normalized_depth(ref_id) for idx in idxs]
+                mat, valid, lengths = _pad_rows(rows)
             longest = int(lengths.max())
             is_sex = _same_chrom(sex_chroms, ref_name)
             if extra_normalize and not is_sex and n_samples >= 5:
@@ -341,11 +360,12 @@ def run_indexcov(
                 mat = np.where(valid, mat, 0.0)
             packed_dev = None
             if longest > 0:
-                packed_dev = ops.chrom_qc(mat, valid, np.int32(longest))
-                try:
-                    packed_dev.copy_to_host_async()
-                except AttributeError:  # non-jax array (cpu fallback)
-                    pass
+                packed_dev = ops.chrom_qc(*obs.h2d((mat, valid)),
+                                          np.int32(longest))
+                packed_dev.copy_to_host_async()
+                reg.counter("indexcov.qc_dispatches_total").inc()
+                reg.counter("indexcov.tile_samples_total").inc(
+                    int(lengths.sum()))
         return (ref_name, ref_len, mat, valid, lengths, longest, is_sex,
                 packed_dev)
 
@@ -355,18 +375,21 @@ def run_indexcov(
          packed_dev) = state
         rocs = chrom_counters = chrom_cn = None
         if packed_dev is not None:
-            with timer.stage("qc_fetch"):
-                rocs, chrom_counters, chrom_cn = ops.unpack_chrom_qc(
-                    np.asarray(packed_dev), n_samples
-                )
+            with timer.stage("device-compute"):
+                # a resumed chromosome's state is on the host already
+                packed = (packed_dev if isinstance(packed_dev, np.ndarray)
+                          else obs.fetch(packed_dev)[0])
+                with obs.span("unpack", category="transfer"):
+                    rocs, chrom_counters, chrom_cn = ops.unpack_chrom_qc(
+                        packed, n_samples)
 
         # bed.gz rows: chunked so a big cohort's formatted block stays
         # bounded in RAM (write_bed_block is the shared formatter)
-        with timer.stage("bed_gz"):
-            for lo in range(0, longest, 2048):
-                hi = min(lo + 2048, longest)
-                write_bed_block(bed, ref_name, lo, hi,
-                                mat[:, lo:hi], valid[:, lo:hi])
+        for lo in range(0, longest, 2048):
+            hi = min(lo + 2048, longest)
+            with timer.stage("write-output"):
+                bed_text.inc(write_bed_block(
+                    bed, ref_name, lo, hi, mat[:, lo:hi], valid[:, lo:hi]))
 
         if is_sex:
             if longest > 0:
@@ -374,16 +397,17 @@ def run_indexcov(
         else:
             # cap at MaxCN before quantization (indexcov.go:694-698);
             # missing tail bins quantize to 0
-            capped = np.where(valid, np.minimum(mat, ops.MAX_CN), 0.0)
-            q = ops.quantize_depths(capped)
-            q[~valid] = 0
-            pca_blocks.append(q[:, :max(longest, 0)])
+            with timer.stage("pca"), obs.span("pack", category="transfer"):
+                capped = np.where(valid, np.minimum(mat, ops.MAX_CN), 0.0)
+                q = ops.quantize_depths(capped)
+                q[~valid] = 0
+                pca_blocks.append(q[:, :max(longest, 0)])
             if chrom_counters is not None:
                 for k in counters:
                     counters[k] += chrom_counters[k]
 
         if longest > 0:
-            with timer.stage("roc"):
+            with timer.stage("write-output"):
                 write_roc_rows(roc_fh, ref_name, rocs)
             if (include_gl or not ref_name.startswith("GL")) and longest > 2:
                 if not is_sex and longest > 100:
@@ -465,37 +489,41 @@ def run_indexcov(
     bed.close()
     bed_fh.close()
     roc_fh.close()
-    with timer.stage("pca_ped_html"):
-        if n_slopes > 0:
-            slopes = slopes / np.float32(n_slopes)
-        _check_sexes(sexes, sex_chroms)
+    if n_slopes > 0:
+        slopes = slopes / np.float32(n_slopes)
+    _check_sexes(sexes, sex_chroms)
 
-        # PCA over autosome bins (indexcov.go:773-807)
-        pcs = None
-        var_frac = None
-        if pca_blocks:
-            pca_mat = np.concatenate(pca_blocks, axis=1).astype(
-                np.float32)
-            if pca_mat.shape[1] >= 3 and n_samples >= 3:
-                # k clamps to the sample count: same projection values
-                # (the SVD only has min(n, bins) right vectors anyway),
-                # but inside pca_project's guarded domain
-                proj, frac = ops.pca_project(
-                    pca_mat, k=min(5, n_samples))
-                pcs, var_frac = np.asarray(proj), np.asarray(frac)
+    # PCA over autosome bins (indexcov.go:773-807)
+    pcs = None
+    var_frac = None
+    n_bins = sum(b.shape[1] for b in pca_blocks)
+    if n_bins >= 3 and n_samples >= 3:
+        with timer.stage("pca"):
+            with obs.span("pack", category="transfer"):
+                # uint16 as quantised: pca_project casts on the device,
+                # so the host makes no float32 copy (351 MB at 500
+                # indexes) and half the bytes cross
+                pca_mat = np.concatenate(pca_blocks, axis=1)
+            # k clamps to the sample count: same projection values
+            # (the SVD only has min(n, bins) right vectors anyway),
+            # but inside pca_project's guarded domain
+            pcs, var_frac = obs.fetch(*ops.pca_project(
+                *obs.h2d((pca_mat,)), k=min(5, n_samples)))
 
+    with timer.stage("write-output"):
         ped_path = _write_ped(
             base, directory, sexes, counters, names, slopes, pcs,
             [i.mapped for i in idxs], [i.unmapped for i in idxs],
         )
-        if write_html:
+    if write_html:
+        with timer.stage("plots"):
             _write_index_html(
                 directory, base, name, sexes, counters, names, pcs,
                 var_frac,
                 [i.mapped for i in idxs], [i.unmapped for i in idxs],
                 chrom_names, write_png=write_png,
             )
-            log.info("indexcov finished: see %s/index.html", directory)
+        log.info("indexcov finished: see %s/index.html", directory)
     return {
         "sexes": sexes,
         "counters": counters,

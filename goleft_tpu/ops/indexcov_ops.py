@@ -79,11 +79,21 @@ def counts_at_depth(depths: jax.Array, valid: jax.Array) -> jax.Array:
     return jax.vmap(hist)(idx, one)
 
 
+def counts_from_top(counts: jax.Array) -> jax.Array:
+    """Tiles at or above each slot: the reverse running sum of the
+    histogram (indexcov.go:181-193). counts: (..., SLOTS)."""
+    return jnp.cumsum(counts[..., ::-1], axis=-1)[..., ::-1]
+
+
 @jax.jit
 def counts_roc(counts: jax.Array) -> jax.Array:
-    """Reverse-cumulative proportion (indexcov.go:181-193). counts:
-    (..., SLOTS)."""
-    totals = jnp.cumsum(counts[..., ::-1], axis=-1)[..., ::-1]
+    """Reverse-cumulative proportion (indexcov.go:181-193), divided on
+    the device. The TPU's float32 quotient is not always the correctly
+    rounded one (PR 29, v5e: 35% of k/n for n under 15,196 are an ulp
+    off, and 7 ROC lines of a 500-index job printed another "%.2f"), so
+    ``chrom_qc`` hands the counts to the host instead
+    (:func:`unpack_chrom_qc`)."""
+    totals = counts_from_top(counts)
     return totals.astype(jnp.float32) / totals[..., :1].astype(jnp.float32)
 
 
@@ -208,7 +218,11 @@ def _pca_project_jit(mat: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     n = x.shape[0]
     vars_ = (s * s) / jnp.float32(max(n - 1, 1))
     frac = vars_ / vars_.sum()
-    proj = x @ vt[:k].T
+    # float32 all the way: at the TPU's default precision the product
+    # rounds its inputs to bfloat16 (PR 29, 500 x 175,467 on a v5e: PC5
+    # off by 2.2e-4 of its largest value against 1.9e-5 so, at the same
+    # 65 ms)
+    proj = jnp.matmul(x, vt[:k].T, precision=jax.lax.Precision.HIGHEST)
     return proj, frac[:k]
 
 
@@ -243,20 +257,22 @@ def pca_project(mat, k: int = 5) -> tuple[jax.Array, jax.Array]:
 def chrom_qc(depths: jax.Array, valid: jax.Array,
              longest: jax.Array) -> jax.Array:
     """One fused per-chromosome QC program returning ONE packed f32
-    vector: [rocs (S·SLOTS)] [in|out|hi|low (4·S)] [cn (S)].
+    vector: [tiles at or above each slot (S·SLOTS)] [in|out|hi|low
+    (4·S)] [cn (S)].
 
     The per-call device→host latency of a slow link dominates when ROC,
     counters, and CN fetch separately (~6 round trips per chromosome);
     this packs everything the host needs into a single transfer. All
     values are integers (or f32 already) well under 2**24, so the f32
-    packing is exact.
+    packing is exact. The ROC's quotients are the host's to take
+    (:func:`unpack_chrom_qc`): they are printed "%.2f" and have to
+    round as IEEE float32 division does.
     """
     counts = counts_at_depth(depths, valid)
-    rocs = counts_roc(counts)
     cnt = bin_counters(depths, valid, longest)
     cn = get_cn(depths, valid)
     return jnp.concatenate([
-        rocs.ravel(),
+        counts_from_top(counts).astype(jnp.float32).ravel(),
         cnt["in"].astype(jnp.float32),
         cnt["out"].astype(jnp.float32),
         cnt["hi"].astype(jnp.float32),
@@ -269,7 +285,9 @@ def unpack_chrom_qc(packed: np.ndarray, n_samples: int):
     """Host split of chrom_qc's packed vector →
     (rocs (S, SLOTS) f32, counters dict of int64 (S,), cn f32 (S,))."""
     S = n_samples
-    rocs = packed[: S * SLOTS].reshape(S, SLOTS)
+    from_top = packed[: S * SLOTS].reshape(S, SLOTS)
+    with np.errstate(invalid="ignore"):  # a sample with no tile: NaN
+        rocs = from_top / from_top[:, :1]
     off = S * SLOTS
     cnt = {}
     for k in ("in", "out", "hi", "low"):
