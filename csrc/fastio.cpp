@@ -170,8 +170,13 @@ long bgzf_deflate_block(const uint8_t* data, long len, int level,
                         uint8_t* out, long out_cap) {
     if (len < 0 || len > 65280) return -2;  // BGZF cap minus overhead
 #ifndef NO_LIBDEFLATE
-    static thread_local struct libdeflate_compressor* comp = nullptr;
+    // freed when its thread ends: the bed stream's pool is new every job
+    static thread_local struct Cached {
+        struct libdeflate_compressor* comp = nullptr;
+        ~Cached() { if (comp) libdeflate_free_compressor(comp); }
+    } cached;
     static thread_local int comp_level = -1;
+    struct libdeflate_compressor*& comp = cached.comp;
     if (comp == nullptr || comp_level != level) {
         if (comp) libdeflate_free_compressor(comp);
         comp = libdeflate_alloc_compressor(level);
@@ -216,6 +221,24 @@ long bgzf_deflate_block(const uint8_t* data, long len, int level,
     uint32_t isize = (uint32_t)len;
     memcpy(out + 18 + clen + 4, &isize, 4);
     return bsize;
+}
+
+// Compress a text of any length into whole BGZF members, one per 65280
+// bytes of it (the last may be shorter), back to back in out: what
+// BgzfWriter makes of the same bytes, in one GIL-free call. Returns the
+// bytes written, or bgzf_deflate_block's negative (-3: out_cap too
+// small for the next member's worst case).
+long bgzf_deflate_members(const uint8_t* data, long len, int level,
+                          uint8_t* out, long out_cap) {
+    long w = 0;
+    for (long off = 0; off < len; off += 65280) {
+        long n = len - off < 65280 ? len - off : 65280;
+        long b = bgzf_deflate_block(data + off, n, level, out + w,
+                                    out_cap - w);
+        if (b < 0) return b;
+        w += b;
+    }
+    return w;
 }
 
 // ---- rANS 4x8 decode (CRAM 3.0 block method 4) ---------------------
@@ -1169,46 +1192,76 @@ long format_depth_rows(const char* chrom, long chrom_len,
 
 // Float matrix rows "chrom\tstart\tend\t%.{prec}g...\n" with a validity
 // mask (invalid cells print "0" — shorter samples' missing tail bins,
-// indexcov.go:678-680). vals/valid are (n_cols, n_rows) col-major like
-// format_matrix_rows. Byte-identical to numpy's np.char.mod("%.3g").
-long format_float_matrix_rows(const char* chrom, long chrom_len,
-                              const int64_t* starts, const int64_t* ends,
-                              const double* vals, const uint8_t* valid,
-                              long n_rows, long n_cols, int prec,
-                              char* out, long out_cap) {
+// indexcov.go:678-680). vals/valid are a column slice of row-major
+// (n_cols, width) matrices, taken where it lies: cell (c, r) is
+// vals[c * val_stride + r], valid[c * valid_stride + r]. The float32
+// values widen to double exactly, so the text is byte-identical to
+// numpy's np.char.mod("%.3g"). Rows are formatted from row0 on for as
+// long as a worst-case row still fits out_cap; *next_row is the first
+// row left (n_rows when done), so a caller can work through a block
+// with a scratch far smaller than its text. Returns bytes written. out
+// must be 8-byte aligned (any allocator's is).
+long format_float32_rows(const char* chrom, long chrom_len,
+                         const int64_t* starts, const int64_t* ends,
+                         const float* vals, long val_stride,
+                         const uint8_t* valid, long valid_stride,
+                         long row0, long n_rows, long n_cols, int prec,
+                         char* out, long out_cap, long* next_row) {
     if (prec > 17) prec = 17;  // "%.17g" worst case fits the 33B budget
     static locale_t c_loc3 = newlocale(LC_NUMERIC_MASK, "C", (locale_t)0);
     locale_t old = c_loc3 != (locale_t)0 ? uselocale(c_loc3)
                                          : (locale_t)0;
+    // a sample's row of the matrix is a whole stride away from the next
+    // sample's: walking one output row across the samples would miss the
+    // cache at every cell. So ROWS_T output rows are gathered at a time,
+    // a cache line or two a sample, and formatted from the gathered tile.
+    // The tile is the tail of out (ROWS_T * 5 bytes a column: a float and
+    // its flag a cell), so the call allocates nothing.
+    enum { ROWS_T = 32 };
+    const long row_worst = chrom_len + 2 * 21 + n_cols * 34 + 2;
+    out_cap = (out_cap - ROWS_T * 5 * n_cols) & ~7L;
+    float* tile = (float*)(out + (out_cap > 0 ? out_cap : 0));
+    uint8_t* vtile = (uint8_t*)(tile + ROWS_T * n_cols);
     long w = 0;
-    for (long r = 0; r < n_rows; r++) {
-        if (w + chrom_len + 2 * 21 + n_cols * 34 + 2 > out_cap) {
-            w = -1;
-            break;
-        }
-        memcpy(out + w, chrom, chrom_len);
-        w += chrom_len;
-        out[w++] = '\t';
-        w += itoa_u(starts[r], out + w);
-        out[w++] = '\t';
-        w += itoa_u(ends[r], out + w);
+    long r = row0;
+    bool full = out_cap < row_worst;  // not one row fits
+    while (r < n_rows && !full) {
+        long nt = n_rows - r < ROWS_T ? n_rows - r : ROWS_T;
         for (long c = 0; c < n_cols; c++) {
-            out[w++] = '\t';
-            if (valid[c * n_rows + r]) {
-                double v = vals[c * n_rows + r];
-                long fw = fmt_g(v, out + w, prec);
-                if (fw >= 0)
-                    w += fw;
-                else
-                    w += snprintf(out + w, 33, "%.*g", prec, v);
-            } else {
-                out[w++] = '0';
-            }
+            memcpy(tile + c * ROWS_T, vals + c * val_stride + r,
+                   sizeof(float) * nt);
+            memcpy(vtile + c * ROWS_T, valid + c * valid_stride + r, nt);
         }
-        out[w++] = '\n';
+        for (long t = 0; t < nt; t++, r++) {
+            if (w + row_worst > out_cap) {
+                full = true;
+                break;
+            }
+            memcpy(out + w, chrom, chrom_len);
+            w += chrom_len;
+            out[w++] = '\t';
+            w += itoa_u(starts[r], out + w);
+            out[w++] = '\t';
+            w += itoa_u(ends[r], out + w);
+            for (long c = 0; c < n_cols; c++) {
+                out[w++] = '\t';
+                if (vtile[c * ROWS_T + t]) {
+                    double v = (double)tile[c * ROWS_T + t];
+                    long fw = fmt_g(v, out + w, prec);
+                    if (fw >= 0)
+                        w += fw;
+                    else
+                        w += snprintf(out + w, 33, "%.*g", prec, v);
+                } else {
+                    out[w++] = '0';
+                }
+            }
+            out[w++] = '\n';
+        }
     }
     if (old != (locale_t)0)
         uselocale(old);
+    *next_row = r;
     return w;
 }
 

@@ -45,7 +45,7 @@ import shutil
 import numpy as np
 
 from ..commands import indexcov as ic
-from ..io.bgzf import BgzfWriter
+from ..io.bedgz import BedGzStream
 from ..obs import get_registry
 from ..obs.logging import get_logger
 from ..ops import indexcov_ops as ops
@@ -287,9 +287,10 @@ def run_cohortscan(
 
     # ---- pass 2 + emission ----
     bed_fh = open(base + ".bed.gz", "wb")
-    bed = BgzfWriter(bed_fh, level=1)
-    bed.write(("#chrom\tstart\tend\t" + "\t".join(names) + "\n")
-              .encode())
+    bed = BedGzStream(
+        bed_fh,
+        ("#chrom\tstart\tend\t" + "\t".join(names) + "\n").encode(),
+        timer)
     roc_fh = open(base + ".roc", "w")
     roc_fh.write("#chrom\tcov\t" + "\t".join(names) + "\n")
 
@@ -362,111 +363,115 @@ def run_cohortscan(
                 .inc(len(missing))
         return [blocks[i] for i in range(span)]
 
-    for rid, rname, rlen in refs:
-        if exclude is not None and exclude.search(rname):
-            continue
-        lens = lengths_by_ref[rid]
-        longest = int(lens.max()) if n_samples else 0
-        is_sex = ic._same_chrom(sex_chroms, rname)
+    with bed:  # drains the stream and writes its EOF; stops it on an error
+        for rid, rname, rlen in refs:
+            if exclude is not None and exclude.search(rname):
+                continue
+            lens = lengths_by_ref[rid]
+            longest = int(lens.max()) if n_samples else 0
+            is_sex = ic._same_chrom(sex_chroms, rname)
 
-        # global scalars for this chromosome (None → no normalization)
-        norm = None
-        norm_sig = None
-        st = stats_by_ref.get(rid)
-        if st is not None and not is_sex:
-            with timer.stage("norm_scalars"):
-                width = max(
-                    (spill.get(rid, ci, "raw").shape[1]
-                     for ci in range(len(chunks))), default=0)
-                norm = st.finalize(width)
-                norm_sig = st.scalars_digest(width)
+            # global scalars for this chromosome (None → no normalization)
+            norm = None
+            norm_sig = None
+            st = stats_by_ref.get(rid)
+            if st is not None and not is_sex:
+                with timer.stage("norm_scalars"):
+                    width = max(
+                        (spill.get(rid, ci, "raw").shape[1]
+                         for ci in range(len(chunks))), default=0)
+                    norm = st.finalize(width)
+                    norm_sig = st.scalars_digest(width)
 
-        # per-chunk: normalize, QC, collect per-sample blocks
-        rocs_all = np.zeros((n_samples, ops.SLOTS), np.float32)
-        cnt_all = {k: np.zeros(n_samples, np.int64)
-                   for k in ("in", "out", "hi", "low")}
-        cn_all = np.zeros(n_samples, np.float32)
-        for ci, (lo, hi) in enumerate(chunks):
-            mat = np.asarray(spill.get(rid, ci, "raw"))
-            clens = lens[lo:hi]
-            if norm is not None:
-                with timer.stage("normalize"):
-                    m_all, skip_all = norm
-                    w = len(m_all)
-                    if mat.shape[1] < w:
-                        mat = np.pad(mat, ((0, 0),
-                                           (0, w - mat.shape[1])))
-                    rb = _row_bucket(mat.shape[0])
-                    padded = _pad_rows_to(mat, rb)
-                    out = np.asarray(apply_normalization(
-                        padded,
-                        _pad_rows_to(clens.reshape(-1, 1),
-                                     rb).ravel().astype(np.int32),
-                        m_all, skip_all))[: mat.shape[0]]
-                    valid = (np.arange(out.shape[1],
-                                       dtype=np.int32)[None, :]
-                             < clens[:, None])
-                    mat = np.where(valid, out, 0.0).astype(np.float32)
-                    spill.put(rid, ci, "norm", mat)
-            if longest > 0:
-                blocks = _qc_chunk(rid, rname, rlen, ci, lo, hi,
-                                   mat, clens, norm_sig)
-                for off, blk in enumerate(blocks):
-                    s = lo + off
-                    rocs_all[s] = blk[: ops.SLOTS]
-                    for ki, k in enumerate(("in", "out", "hi", "low")):
-                        cnt_all[k][s] = int(blk[ops.SLOTS + ki])
-                    cn_all[s] = blk[ops.SLOTS + 4]
-            del mat
-
-        # host tail correction: exactly the monolithic kernel's
-        # max(longest - n_valid, 0) additive term
-        if longest > 0:
-            delta = (longest - lens.astype(np.int64))
-            cnt_all["out"] += delta
-            cnt_all["low"] += delta
-
-        # ---- emission (byte-identical to run_indexcov._emit) ----
-        with timer.stage("bed_gz"):
-            for blo in range(0, longest, BED_BLOCK):
-                bhi = min(blo + BED_BLOCK, longest)
-                parts = []
-                vparts = []
-                for ci, (lo, hi) in enumerate(chunks):
-                    cmat = spill.get(
-                        rid, ci, "norm" if norm is not None else "raw")
-                    cw = cmat.shape[1]
-                    sl = np.asarray(cmat[:, blo:min(bhi, cw)],
-                                    np.float32)
-                    if sl.shape[1] < bhi - blo:
-                        sl = np.pad(sl, ((0, 0),
-                                         (0, bhi - blo - sl.shape[1])))
-                    parts.append(sl)
-                    vparts.append(
-                        (np.arange(blo, bhi, dtype=np.int32)[None, :]
-                         < lens[lo:hi, None]))
-                ic.write_bed_block(bed, rname, blo, bhi,
-                                   np.vstack(parts), np.vstack(vparts))
-
-        if is_sex:
-            if longest > 0:
-                sexes[rname] = cn_all
-        else:
-            for k in counters:
+            # per-chunk: normalize, QC, collect per-sample blocks
+            rocs_all = np.zeros((n_samples, ops.SLOTS), np.float32)
+            cnt_all = {k: np.zeros(n_samples, np.int64)
+                       for k in ("in", "out", "hi", "low")}
+            cn_all = np.zeros(n_samples, np.float32)
+            for ci, (lo, hi) in enumerate(chunks):
+                mat = np.asarray(spill.get(rid, ci, "raw"))
+                clens = lens[lo:hi]
+                if norm is not None:
+                    with timer.stage("normalize"):
+                        m_all, skip_all = norm
+                        w = len(m_all)
+                        if mat.shape[1] < w:
+                            mat = np.pad(mat, ((0, 0),
+                                               (0, w - mat.shape[1])))
+                        rb = _row_bucket(mat.shape[0])
+                        padded = _pad_rows_to(mat, rb)
+                        out = np.asarray(apply_normalization(
+                            padded,
+                            _pad_rows_to(clens.reshape(-1, 1),
+                                         rb).ravel().astype(np.int32),
+                            m_all, skip_all))[: mat.shape[0]]
+                        valid = (np.arange(out.shape[1],
+                                           dtype=np.int32)[None, :]
+                                 < clens[:, None])
+                        mat = np.where(valid, out, 0.0).astype(np.float32)
+                        spill.put(rid, ci, "norm", mat)
                 if longest > 0:
-                    counters[k] += cnt_all[k]
-            pca_refs.append((rid, longest))
+                    blocks = _qc_chunk(rid, rname, rlen, ci, lo, hi,
+                                       mat, clens, norm_sig)
+                    for off, blk in enumerate(blocks):
+                        s = lo + off
+                        rocs_all[s] = blk[: ops.SLOTS]
+                        for ki, k in enumerate(("in", "out", "hi", "low")):
+                            cnt_all[k][s] = int(blk[ops.SLOTS + ki])
+                        cn_all[s] = blk[ops.SLOTS + 4]
+                del mat
 
-        if longest > 0:
-            with timer.stage("roc"):
-                ic.write_roc_rows(roc_fh, rname, rocs_all)
-            if (include_gl or not rname.startswith("GL")) and longest > 2:
-                if not is_sex and longest > 100:
-                    slopes += ops.update_slopes(rocs_all, rlen / 1e6)
-                    n_slopes += 1
-                chrom_names.append(rname)
+            # host tail correction: exactly the monolithic kernel's
+            # max(longest - n_valid, 0) additive term
+            if longest > 0:
+                delta = (longest - lens.astype(np.int64))
+                cnt_all["out"] += delta
+                cnt_all["low"] += delta
 
-    bed.close()
+            # ---- emission (byte-identical to run_indexcov._emit) ----
+            with timer.stage("bed_gz"):
+                for blo in range(0, longest, BED_BLOCK):
+                    bhi = min(blo + BED_BLOCK, longest)
+                    parts = []
+                    vparts = []
+                    for ci, (lo, hi) in enumerate(chunks):
+                        cmat = spill.get(
+                            rid, ci, "norm" if norm is not None else "raw")
+                        cw = cmat.shape[1]
+                        sl = np.asarray(cmat[:, blo:min(bhi, cw)],
+                                        np.float32)
+                        if sl.shape[1] < bhi - blo:
+                            sl = np.pad(sl, ((0, 0),
+                                             (0, bhi - blo - sl.shape[1])))
+                        parts.append(sl)
+                        vparts.append(
+                            (np.arange(blo, bhi, dtype=np.int32)[None, :]
+                             < lens[lo:hi, None]))
+                    # one block is formatted on the stream's pool while
+                    # the next is gathered from the spills; a block holds
+                    # every sample, so no more of them are alive than that
+                    bed.wait_through(bed.submit(
+                        rname, blo, bhi,
+                        np.vstack(parts), np.vstack(vparts)) - 1)
+
+            if is_sex:
+                if longest > 0:
+                    sexes[rname] = cn_all
+            else:
+                for k in counters:
+                    if longest > 0:
+                        counters[k] += cnt_all[k]
+                pca_refs.append((rid, longest))
+
+            if longest > 0:
+                with timer.stage("roc"):
+                    ic.write_roc_rows(roc_fh, rname, rocs_all)
+                if (include_gl or not rname.startswith("GL")) and longest > 2:
+                    if not is_sex and longest > 100:
+                        slopes += ops.update_slopes(rocs_all, rlen / 1e6)
+                        n_slopes += 1
+                    chrom_names.append(rname)
+
     bed_fh.close()
     roc_fh.close()
 

@@ -25,7 +25,7 @@ import numpy as np
 
 from .. import obs
 from ..io.bai import read_bai
-from ..io.bgzf import BgzfWriter
+from ..io.bedgz import BedGzStream
 from ..io.crai import read_crai
 from ..io.fai import read_fai
 from ..ops import indexcov_ops as ops
@@ -194,42 +194,6 @@ def _index_file(path: str) -> str:
     return path[:-4] + ".bai"
 
 
-def write_bed_block(bed, ref_name: str, lo: int, hi: int,
-                    mat_cols: np.ndarray, valid_cols: np.ndarray) -> int:
-    """Format + write bed rows for bins [lo, hi) of one chromosome.
-
-    ``mat_cols``/``valid_cols`` are the (samples, hi-lo) column slice.
-    The ONE formatting path for both the monolithic ``indexcov`` loop
-    and the chunked ``cohortscan`` engine — shorter samples print 0
-    (indexcov.go:678-680, depthsFor :1038-1048); C++ formats the block
-    when the native lib is built (byte-identical to np.char.mod
-    "%.3g"). The emitted bytes depend only on the slice values, never
-    on how the caller blocked its writes (BgzfWriter re-chunks to its
-    fixed block size). Returns the bytes of text written.
-    """
-    from ..io import native
-
-    idx = np.arange(lo, hi, dtype=np.int64)
-    if native.get_lib() is not None:
-        text = native.format_float_matrix_rows(
-            ref_name, idx * TILE, (idx + 1) * TILE, mat_cols, valid_cols,
-        )
-        bed.write(text)
-        return len(text)
-    block = np.char.mod("%.3g", mat_cols.T)
-    block[~valid_cols.T] = "0"
-    starts_col = np.char.mod("%d", idx * TILE)
-    ends_col = np.char.mod("%d", (idx + 1) * TILE)
-    rows_txt = [
-        ref_name + "\t" + starts_col[i] + "\t" + ends_col[i]
-        + "\t" + "\t".join(block[i]) + "\n"
-        for i in range(hi - lo)
-    ]
-    text = "".join(rows_txt).encode()
-    bed.write(text)
-    return len(text)
-
-
 def write_roc_rows(roc_fh, ref_name: str, rocs: np.ndarray) -> None:
     """One chromosome's ROC block (SLOTS rows), one vectorized format
     pass — shared by indexcov and cohortscan for byte-parity."""
@@ -319,11 +283,10 @@ def run_indexcov(
     base = os.path.join(directory, name + "-indexcov")
 
     bed_fh = open(base + ".bed.gz", "wb")
-    bed = BgzfWriter(bed_fh, level=1)
-    bed_text = reg.counter("indexcov.bed_text_bytes_total")  # before BGZF
-    header = ("#chrom\tstart\tend\t" + "\t".join(names) + "\n").encode()
-    bed.write(header)
-    bed_text.inc(len(header))
+    # counts its text before BGZF into indexcov.bed_text_bytes_total
+    bed = BedGzStream(
+        bed_fh, ("#chrom\tstart\tend\t" + "\t".join(names) + "\n").encode(),
+        timer)
     roc_fh = open(base + ".roc", "w")
     roc_fh.write("#chrom\tcov\t" + "\t".join(names) + "\n")
 
@@ -369,6 +332,19 @@ def run_indexcov(
         return (ref_name, ref_len, mat, valid, lengths, longest, is_sex,
                 packed_dev)
 
+    def _bed_blocks(state) -> int:
+        """Hand one chromosome's bed rows to the stream, 2,048 tiles a
+        block so that a big cohort's text in flight stays bounded. They
+        need the host's matrix only, not the device's answer, so they
+        go as soon as the chromosome is launched (or resumed) and are
+        formatted under its QC, the fetch of the one before, the ROC
+        rows and the quantisation. Returns the last block's ticket."""
+        ref_name, _, mat, valid, _, longest, _, _ = state
+        for lo in range(0, longest, 2048):
+            hi = min(lo + 2048, longest)
+            bed.submit(ref_name, lo, hi, mat[:, lo:hi], valid[:, lo:hi])
+        return bed.last_ticket
+
     def _emit(state):
         nonlocal slopes, n_slopes
         (ref_name, ref_len, mat, valid, lengths, longest, is_sex,
@@ -382,14 +358,6 @@ def run_indexcov(
                 with obs.span("unpack", category="transfer"):
                     rocs, chrom_counters, chrom_cn = ops.unpack_chrom_qc(
                         packed, n_samples)
-
-        # bed.gz rows: chunked so a big cohort's formatted block stays
-        # bounded in RAM (write_bed_block is the shared formatter)
-        for lo in range(0, longest, 2048):
-            hi = min(lo + 2048, longest)
-            with timer.stage("write-output"):
-                bed_text.inc(write_bed_block(
-                    bed, ref_name, lo, hi, mat[:, lo:hi], valid[:, lo:hi]))
 
         if is_sex:
             if longest > 0:
@@ -468,27 +436,33 @@ def run_indexcov(
     plot_ex = cf.ThreadPoolExecutor(max_workers=4)
     plot_futs: list = []
     try:
-        pending = None
-        for ref_id, ref_name, ref_len in refs:
-            if exclude is not None and exclude.search(ref_name):
-                continue
-            cur = _launch_or_resume(ref_id, ref_name, ref_len)
+        with bed:  # a clean exit drains the stream and writes its EOF
+            pending = None  # the chromosome launched and not yet emitted
+            settled = -1  # last bed ticket of the chromosome before it
+            for ref_id, ref_name, ref_len in refs:
+                if exclude is not None and exclude.search(ref_name):
+                    continue
+                # two chromosomes' matrices are alive, never a third:
+                # the blocks that still read the one before `pending`
+                # reach the file before the next is made
+                bed.wait_through(settled)
+                cur = _launch_or_resume(ref_id, ref_name, ref_len)
+                cur_last = _bed_blocks(cur)
+                if pending is not None:
+                    _emit(pending[0])
+                    settled = pending[1]
+                pending = (cur, cur_last)
             if pending is not None:
-                _emit(pending)
-            pending = cur
-        if pending is not None:
-            _emit(pending)
-        with timer.stage("plots"):
-            for f in plot_futs:
-                f.result()  # surface the first page-render failure
+                _emit(pending[0])
+            with timer.stage("plots"):
+                for f in plot_futs:
+                    f.result()  # surface the first page-render failure
     finally:
         plot_ex.shutdown(wait=True, cancel_futures=True)
         if checkpoint is not None:
             checkpoint.close()
-
-    bed.close()
-    bed_fh.close()
-    roc_fh.close()
+        bed_fh.close()
+        roc_fh.close()
     if n_slopes > 0:
         slopes = slopes / np.float32(n_slopes)
     _check_sexes(sexes, sex_chroms)

@@ -173,8 +173,35 @@ class BgzfReader:
         return bytes(out)
 
 
+def bgzf_member(chunk: bytes, level: int) -> bytes:
+    """One complete BGZF member for ``chunk`` (at most ``WRITE_CHUNK``
+    bytes). Native libdeflate block compression is 2-4x zlib; the
+    decompressed content is identical either way, only the compressed
+    bytes differ."""
+    from . import native
+
+    blob = native.bgzf_deflate_block(chunk, level)
+    if blob is not None:
+        return blob
+    co = zlib.compressobj(level, zlib.DEFLATED, -15)
+    cdata = co.compress(chunk) + co.flush()
+    crc = zlib.crc32(chunk) & 0xFFFFFFFF
+    bsize = len(cdata) + 12 + 6 + 8  # header(12) + extra(6) + crc/isize(8)
+    header = struct.pack(
+        "<BBBBIBBHBBHH",
+        0x1F, 0x8B, 8, 4,  # magic, deflate, FEXTRA
+        0, 0, 0xFF,  # mtime, xfl, os
+        6,  # xlen
+        0x42, 0x43, 2,  # BC subfield
+        bsize - 1,
+    )
+    return header + cdata + struct.pack("<II", crc, len(chunk))
+
+
 class BgzfWriter:
-    """Streaming BGZF writer (used for .bam fixtures and bed.gz outputs).
+    """Streaming BGZF writer, serial (used for .bam fixtures and the VCF
+    writer; the .bed.gz of indexcov and cohortscan goes through
+    io/bedgz.py's pool and shares ``bgzf_member`` with it).
 
     ``block_size`` caps uncompressed bytes per block — small blocks give
     test fixtures realistic multi-block-per-tile BAI linear indexes.
@@ -195,28 +222,7 @@ class BgzfWriter:
     def _flush_block(self, n: int) -> None:
         chunk = bytes(self._buf[:n])
         del self._buf[:n]
-        # native libdeflate block compression is 2-4x zlib — the bed.gz
-        # writer was ~1.1s of indexcov's whole-genome wall. Decompressed
-        # content is identical either way; only compressed bytes differ.
-        from . import native
-
-        blob = native.bgzf_deflate_block(chunk, self._level)
-        if blob is not None:
-            self._fh.write(blob)
-            return
-        co = zlib.compressobj(self._level, zlib.DEFLATED, -15)
-        cdata = co.compress(chunk) + co.flush()
-        crc = zlib.crc32(chunk) & 0xFFFFFFFF
-        bsize = len(cdata) + 12 + 6 + 8  # header(12) + extra(6) + crc/isize(8)
-        header = struct.pack(
-            "<BBBBIBBHBBHH",
-            0x1F, 0x8B, 8, 4,  # magic, deflate, FEXTRA
-            0, 0, 0xFF,  # mtime, xfl, os
-            6,  # xlen
-            0x42, 0x43, 2,  # BC subfield
-            bsize - 1,
-        )
-        self._fh.write(header + cdata + struct.pack("<II", crc, len(chunk)))
+        self._fh.write(bgzf_member(chunk, self._level))
 
     def close(self) -> None:
         while self._buf:

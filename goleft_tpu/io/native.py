@@ -99,6 +99,7 @@ def _register_restypes(lib) -> None:
         lib.bam_segments_take.restype = None
         lib.bgzf_stream_inflate_only.restype = ctypes.c_long
         lib.bgzf_deflate_block.restype = ctypes.c_long
+        lib.bgzf_deflate_members.restype = ctypes.c_long
         lib.rans4x8_decode.restype = ctypes.c_long
         lib.ransnx16_decode0.restype = ctypes.c_long
         lib.ransnx16_decode1.restype = ctypes.c_long
@@ -110,7 +111,7 @@ def _register_restypes(lib) -> None:
         lib.format_class_rows.restype = ctypes.c_long
         lib.bai_scan.restype = ctypes.c_long
         lib.format_xy_json.restype = ctypes.c_long
-        lib.format_float_matrix_rows.restype = ctypes.c_long
+        lib.format_float32_rows.restype = ctypes.c_long
 
 
 def _as_u8(data) -> np.ndarray:
@@ -446,6 +447,31 @@ def bgzf_deflate_block(chunk: bytes, level: int) -> bytes | None:
     return out[:n].tobytes()
 
 
+def bgzf_deflate_members(out: np.ndarray, text: np.ndarray,
+                         level: int) -> int | None:
+    """``text`` (uint8) as whole BGZF members of 65280 bytes (the last
+    may be shorter) into the uint8 scratch ``out``, which needs
+    ``bgzf_members_bound(len(text))`` bytes; the bytes written. None
+    when native is unavailable."""
+    lib = get_lib()
+    if lib is None:
+        return None
+    n = lib.bgzf_deflate_members(
+        _ptr(text), ctypes.c_long(len(text)), ctypes.c_int(level),
+        _ptr(out), ctypes.c_long(len(out)),
+    )
+    if n < 0:
+        raise ValueError(f"bgzf_deflate_members: error {n}")
+    return n
+
+
+def bgzf_members_bound(text_bytes: int) -> int:
+    """Bytes the members of ``text_bytes`` of text can take: deflate
+    grows incompressible input by a few bytes in ten thousand, and a
+    member adds its 26."""
+    return text_bytes + (text_bytes // 65280 + 1) * 256
+
+
 def bgzf_stream_inflate_only(comp, check_crc: bool = True):
     """Total uncompressed bytes after streaming the whole BGZF file
     through the product ring driver with a no-op walk — isolates the
@@ -504,34 +530,54 @@ def bai_scan(data):
     return {k: v[:n] for k, v in arrs.items()}
 
 
-def format_float_matrix_rows(chrom: str, starts: np.ndarray,
-                             ends: np.ndarray, vals: np.ndarray,
-                             valid: np.ndarray,
-                             prec: int = 3) -> bytes | None:
-    """Float matrix bed rows (%.{prec}g; invalid cells → "0"); None
-    without native. vals/valid are (n_cols, n_rows)."""
+def float_rows_scratch_bytes(chrom: str, n_cols: int,
+                             text_bytes: int) -> int:
+    """Size of a scratch in which ``format_float32_rows`` writes at
+    least ``text_bytes`` of text a call (or all that is left): that
+    much, one worst-case row (34 bytes a cell: "%.17g") and the
+    gathered tile it keeps at the scratch's tail."""
+    tile = 32 * 5 * n_cols  # ROWS_T cells a column, a float and a flag each
+    return (text_bytes + len(chrom.encode()) + 2 * 21 + n_cols * 34 + 2
+            + tile + 8)
+
+
+def format_float32_rows(out: np.ndarray, chrom: str, starts: np.ndarray,
+                        ends: np.ndarray, vals: np.ndarray,
+                        valid: np.ndarray, row0: int = 0,
+                        prec: int = 3) -> tuple[int, int] | None:
+    """Float matrix bed rows (%.{prec}g; invalid cells → "0") from
+    ``row0`` on into the uint8 scratch ``out``, for as long as a
+    worst-case row fits: (bytes written, first row left); None without
+    native. vals (float32) / valid (bool) are (n_cols, n_rows) and are
+    read where they lie: a column slice of a wider matrix is not
+    copied."""
     lib = get_lib()
     if lib is None:
         return None
     n_cols, n_rows = vals.shape
+    if vals.dtype != np.float32 or (n_rows > 1
+                                    and vals.strides[1] != 4):
+        vals = np.ascontiguousarray(vals, dtype=np.float32)
+    if valid.dtype.itemsize != 1 or (n_rows > 1
+                                     and valid.strides[1] != 1):
+        valid = np.ascontiguousarray(valid, dtype=np.uint8)
     starts = np.ascontiguousarray(starts, dtype=np.int64)
     ends = np.ascontiguousarray(ends, dtype=np.int64)
-    vals = np.ascontiguousarray(vals, dtype=np.float64)
-    valid = np.ascontiguousarray(valid, dtype=np.uint8)
     cb = chrom.encode()
-    cap = n_rows * (len(cb) + 2 * 21 + n_cols * 34 + 4) + 16
-    out = np.empty(cap, dtype=np.uint8)
-    w = lib.format_float_matrix_rows(
+    next_row = ctypes.c_long(row0)
+    w = lib.format_float32_rows(
         ctypes.c_char_p(cb), ctypes.c_long(len(cb)),
         _ptr(starts, ctypes.c_int64), _ptr(ends, ctypes.c_int64),
-        _ptr(vals, ctypes.c_double), _ptr(valid, ctypes.c_uint8),
-        ctypes.c_long(n_rows), ctypes.c_long(n_cols),
-        ctypes.c_int(prec), _ptr(out, ctypes.c_char),
-        ctypes.c_long(cap),
+        _ptr(vals, ctypes.c_float), ctypes.c_long(vals.strides[0] // 4),
+        _ptr(valid, ctypes.c_uint8), ctypes.c_long(valid.strides[0]),
+        ctypes.c_long(row0), ctypes.c_long(n_rows),
+        ctypes.c_long(n_cols), ctypes.c_int(prec),
+        _ptr(out, ctypes.c_char), ctypes.c_long(len(out)),
+        ctypes.byref(next_row),
     )
-    if w < 0:
-        raise ValueError("format_float_matrix_rows: capacity exceeded")
-    return out[:w].tobytes()
+    if next_row.value == row0 < n_rows:
+        raise ValueError("format_float32_rows: scratch holds no row")
+    return w, next_row.value
 
 
 def format_xy_json(xs: np.ndarray, ys: np.ndarray, xprec: int = 10,

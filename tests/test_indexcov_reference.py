@@ -38,7 +38,8 @@ SPANS = [("host-decode", "stage"), ("device-compute", "stage"),
          ("pack", "transfer"), ("h2d", "transfer"),
          ("device-wait", "transfer"), ("d2h", "transfer"),
          ("unpack", "transfer"), ("pca", "stage"),
-         ("write-output", "stage")]
+         ("write-output", "stage"), ("write-wait", "wait"),
+         ("format", "output"), ("deflate", "output")]
 
 
 def small_config() -> dict:
@@ -218,6 +219,15 @@ def test_the_job_records_the_span_vocabulary(job, name, category):
     if name == "host-decode":  # one an index file, on the pool's threads
         assert len(mine) == job["config"]["fixture"]["samples"]
         assert parents == {"run.indexcov"}
+    if category == "output":  # a bed block's two halves, on the bed pool
+        assert parents == {"write-output"}
+        assert all(s.thread_name.startswith("bedgz") for s in mine)
+    if name == "write-output":
+        # the bed blocks on their pool (chr1's two, one a contig else);
+        # the ROC blocks and the .ped on the main thread
+        pooled = [s for s in mine if s.thread_name.startswith("bedgz")]
+        assert len(pooled) == 5 and len(mine) == 5 + 4 + 1
+        assert parents == {"run.indexcov"}
     assert not {s.name for s in job["spans"]} & {
         "index_load", "qc_launch", "qc_fetch", "bed_gz", "roc",
         "pca_ped_html"}
@@ -244,6 +254,7 @@ def sent_bytes(job) -> int:
      lambda j: j["meta"]["work"]["contigs"]),
     ("indexcov.bed_text_bytes_total",
      lambda j: os.path.getsize(j["want"]["bed_gz"])),
+    ("indexcov.bed_blocks_pooled_total", lambda j: 2 + 1 + 1 + 1),
     ("xla.h2d_bytes_total", sent_bytes),
     ("xla.d2h_bytes_total",
      lambda j: 4 * (12 * 75 * 4 + 12 * 5 + 5)),
@@ -383,8 +394,8 @@ def test_a_metric_of_the_cell_has_its_file_and_reducer(metric):
     assert metric["moves"] in [m["name"] for m in MANIFEST["end_to_end"]]
 
 
-def test_thirteen_metrics_and_nothing_of_theirs_imports_jax():
-    assert len(IX_METRICS) == 13
+def test_fourteen_metrics_and_nothing_of_theirs_imports_jax():
+    assert len(IX_METRICS) == 14  # PR 29's thirteen and PR 30's write wait
     readers = sorted({load(f"{BENCH}/metrics/{m['name']}.json")["reducer"]
                       for m in IX_METRICS})
     code = (
