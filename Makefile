@@ -52,8 +52,7 @@ obs-smoke:
 # --checkpoint-dir/--resume to byte-identical output (journal replay
 # proven through the run manifest's checkpoint counters), a
 # permanently-corrupt sample is quarantined (exit 3, partial cohort
-# byte-identical to a run without it), and the happy-path
-# checkpointing overhead is held to the <=5% budget — then the serve
+# byte-identical to a run without it) — then the serve
 # legs against real daemons: poison isolation (one 400, seven
 # byte-identical 200s), circuit-breaker trip/recover, watchdog
 # re-queue of a hung pass, and a checkpoint:true request resuming

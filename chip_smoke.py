@@ -141,9 +141,9 @@ def fabricate_bam(path: str, sample: str, contig_len: int, coverage: int,
 
 
 def fabricate_bai_cohort(d: str, n: int, scale: float, rng) -> tuple:
-    """n whole-genome .bai files + ref.fa.fai, after bench.py's
-    _fabricate_bai_cohort (25 chromosomes of 2.5e8 bp falling by 3%
-    each, one 16 kb tile per linear-index entry), with what a real
+    """n whole-genome .bai files + ref.fa.fai (25 chromosomes of
+    2.5e8 bp falling by 3% each, one 16 kb tile per linear-index
+    entry), with what a real
     cohort has and i.i.d. tiles lack: chr23/chr24 at sex-dependent copy
     number, and five batch effects of well-separated strength, so the
     top principal components are defined and not a rotation of noise."""

@@ -1034,7 +1034,7 @@ void bam_segments_take(void* collector, int32_t* seg_s, int32_t* seg_e) {
 
 // Inflate-only variant of the streaming walk (the walk consumes every
 // byte and reduces nothing): isolates the BGZF inflate(+CRC) floor of
-// the decode stage so bench.py can record what fraction of
+// the decode stage, to tell what fraction of
 // decode_window_reduce is libdeflate running at hardware rates vs the
 // record walk. Returns total uncompressed bytes or a negative bgzf
 // error.

@@ -73,10 +73,6 @@ PROGS = {
     "map": ("map FASTQ reads: minimizer seeding + banded "
             "Smith-Waterman on device",
             _lazy(".commands.map_cmd"), True),
-    # bench.py's main takes the backend itself (--suite-host asks for
-    # the CPU there)
-    "bench": ("run the TPU benchmark suite",
-              _lazy(".commands.bench_cmd"), False),
     "anonymize": ("make shareable header-only bam+bai fixtures",
                   _lazy(".commands.anonymize"), False),
     "lint": ("AST invariant analyzer: determinism, tracer hygiene, "

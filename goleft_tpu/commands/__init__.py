@@ -7,9 +7,9 @@ import os
 
 def add_no_crc_flag(parser) -> None:
     """Register ``--no-crc`` on a decode-heavy subcommand. BGZF payload
-    CRC verification is the single largest share of per-sample decode
-    cost (BENCH_details.json ``cohort_e2e.decode_floor``); skipping it
-    on trusted local files is worth ~+24% end-to-end. What remains
+    CRC verification is a large share of per-sample decode cost (how
+    large is not measured on the chip host: no cell runs ``--no-crc``);
+    skipping it is for trusted local files. What remains
     caught without it — truncation (EOF check), broken deflate streams
     (inflate failure), length mismatches (isize check) — and what does
     not — a bit flip that leaves a valid stream, i.e. silent data
@@ -18,8 +18,8 @@ def add_no_crc_flag(parser) -> None:
     htslib path always verifies."""
     parser.add_argument(
         "--no-crc", action="store_true",
-        help="skip BGZF payload CRC verification (~+24%% decode "
-             "throughput). Truncation, broken streams and length "
+        help="skip BGZF payload CRC verification (faster decode). "
+             "Truncation, broken streams and length "
              "mismatches are still caught; a bit flip that leaves a "
              "valid stream is NOT — only use on trusted local files")
 

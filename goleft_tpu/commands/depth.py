@@ -337,11 +337,7 @@ def run_depth(
     processes: int = 4,
     cache_dir: str | None = None,
     profile_dir: str | None = None,
-    stage_totals: dict | None = None,
 ) -> tuple[str, str]:
-    """``stage_totals``, when given, receives the StageTimer's
-    accumulated host-decode / device-compute / write-output seconds —
-    the bench reads the same numbers ``--profile`` logs."""
     handle = open_bam_file(bam, lazy=True)
     hdr = handle.header
     from ..io import remote
@@ -427,8 +423,6 @@ def run_depth(
                                    dout, cout, fa)
     if profile_dir:
         timer.log_report()
-    if stage_totals is not None:
-        stage_totals.update(timer.totals)
     if n_failed:
         raise SystemExit(1)
     return depth_path, call_path

@@ -230,9 +230,8 @@ def run_indexcov(
     log.info("running on %d indexes", len(bams))
     from ..utils.profiling import StageTimer
 
-    # wall-clock per pipeline stage, returned under "stages" (and
-    # recorded by bench.py's indexcov e2e entry): the span vocabulary of
-    # docs/observability.md, "pca" being this command's own
+    # wall-clock per pipeline stage, returned under "stages": the span
+    # vocabulary of docs/observability.md, "pca" being this command's own
     timer = StageTimer()
     reg = obs.get_registry()
     # 8-way parallel index load, mirroring indexcov.go:417-434

@@ -514,8 +514,8 @@ class WorkerPool:
 
 
 class RouterApp:
-    """Routing + admission logic, independent of any socket (tests and
-    the bench drive it in-process, commands/fleet.py serves it)."""
+    """Routing + admission logic, independent of any socket (tests
+    drive it in-process, commands/fleet.py serves it)."""
 
     def __init__(self, worker_urls: list[str],
                  quotas: list[str] | None = None,
@@ -1407,7 +1407,7 @@ def make_router_server(app: RouterApp, host: str = "127.0.0.1",
 
 
 class RouterThread:
-    """In-process router harness (tests, the bench):
+    """In-process router harness (tests):
     ``with RouterThread(app) as url: ...``"""
 
     def __init__(self, app: RouterApp, host: str = "127.0.0.1",

@@ -475,8 +475,8 @@ def bgzf_members_bound(text_bytes: int) -> int:
 def bgzf_stream_inflate_only(comp, check_crc: bool = True):
     """Total uncompressed bytes after streaming the whole BGZF file
     through the product ring driver with a no-op walk — isolates the
-    inflate(+CRC) floor of the fused decode stage for the bench's
-    decode-floor evidence. None when native is unavailable."""
+    inflate(+CRC) floor of the fused decode stage (a probe: no command
+    calls it). None when native is unavailable."""
     lib = get_lib()
     if lib is None:
         return None

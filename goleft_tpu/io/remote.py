@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import collections
 import email.utils
-import hashlib
 import http.client
 import io as _io
 import os
@@ -719,15 +718,3 @@ def source_io(path: str):
     if is_remote(path):
         return _SourceIO(open_source(path))
     return open(path, "rb")
-
-
-def content_hash_key(path: str) -> str:
-    """A short stable digest of a path/URL's *identity* (not bytes) —
-    handy for log labels and bench record keys."""
-    if is_remote(path):
-        ident = repr(remote_file_key(path))
-    else:
-        st = os.stat(path)
-        ident = repr((os.path.abspath(path), st.st_size,
-                      st.st_mtime_ns))
-    return hashlib.sha256(ident.encode()).hexdigest()[:16]

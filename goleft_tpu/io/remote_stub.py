@@ -20,8 +20,8 @@ Just enough of an S3/htsget-shaped server to exercise every contract
     must still produce correct bytes)
 
 :class:`StubServer` is the harness: a context manager that binds a
-loopback port and yields URLs. Used by the unit tests, the
-``dataplane-smoke`` e2e and the ``remote_fetch`` bench entry; run
+loopback port and yields URLs. Used by the unit tests and the
+``dataplane-smoke`` e2e; run
 directly it serves a directory (the smoke's subprocess mode)::
 
     python -m goleft_tpu.io.remote_stub [--dir D] [--port P]

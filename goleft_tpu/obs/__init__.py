@@ -9,7 +9,7 @@ prefetched cohort, warm serve batch):
   - :mod:`~goleft_tpu.obs.metrics` — the process-wide registry of
     counters/gauges/histograms (``--metrics-out``, serve /metrics)
   - :mod:`~goleft_tpu.obs.provenance` — the one backend/platform
-    answer the manifest, the device spans and the bench all share
+    answer the manifest and the device spans share
   - :mod:`~goleft_tpu.obs.manifest` — the per-run evidence document
   - :mod:`~goleft_tpu.obs.logging` — ``goleft-tpu.*`` logger tree +
     the CLI's ``--log-level`` config

@@ -1,10 +1,8 @@
 """The compile observatory: every jit cache miss is a recorded event.
 
-Until now the only compile evidence in the tree was the bench's
-ad-hoc log handler — serve workers re-jitted their whole bucketed
-program portfolio on every restart and nobody could say what it cost
-or which signatures were hot. This module makes compilation a
-first-class, mergeable signal:
+Serve workers re-jit their whole bucketed program portfolio on every
+restart; this module says what that costs and which signatures are
+hot, by making compilation a first-class, mergeable signal:
 
   - :class:`CompileTracker` (one per process, :data:`TRACKER`) is fed
     by the existing dispatch seams — ``obs.InstrumentedDispatch``,
@@ -170,8 +168,6 @@ class CompileTracker:
         self._registry = registry
         self._tracer = tracer
         self._backend: str | None = None
-        # count_compiles() windows: name lists the compile hook feeds
-        self._windows: list[list[str]] = []
 
     # the registry/tracer default to the process-wide singletons but
     # resolve lazily so a test tracker can inject private ones
@@ -289,9 +285,6 @@ class CompileTracker:
         if seconds:
             reg.counter("xla.compile_seconds_total").inc(
                 round(seconds, 6))
-        with self._lock:
-            for w in self._windows:
-                w.append(name)
         stack = self._ctx.stack
         if stack:
             stack[-1].names.append(name)
@@ -303,28 +296,6 @@ class CompileTracker:
         ob.names.append(name)
         t = time.perf_counter()
         self._record(ob, 1, t - seconds, t)
-
-    # ---- bench windows ----
-
-    @contextlib.contextmanager
-    def window(self):
-        """Collect every compiled jit's name recorded while the window
-        is open (the bench's ``_count_compiles`` contract: ``.names``
-        on the yielded handle)."""
-        names: list[str] = []
-
-        class _Handle:
-            pass
-
-        h = _Handle()
-        h.names = names
-        with self._lock:
-            self._windows.append(names)
-        try:
-            yield h
-        finally:
-            with self._lock:
-                self._windows.remove(names)
 
     # ---- inspection / export ----
 
@@ -447,19 +418,6 @@ def ensure_compile_hook() -> bool:
             jax.monitoring.register_event_listener(_on_event)
             _HOOK = True
     return True
-
-
-@contextlib.contextmanager
-def count_compiles():
-    """The bench's compile window (bench.py ``_count_compiles``): a
-    handle whose ``.names`` lists every jit name the compile hook saw
-    while the window was open. Imports jax (the bench already has)
-    so the hook is live before the window starts."""
-    import jax  # noqa: F401 — force the module into sys.modules
-
-    ensure_compile_hook()
-    with TRACKER.window() as h:
-        yield h
 
 
 # ---------------------------------------------------- warmup manifest

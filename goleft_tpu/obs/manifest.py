@@ -2,12 +2,10 @@
 
 One JSON document per invocation tying together what three artifacts
 used to carry separately: the environment it ran in, the backend it
-actually dispatched to (obs/provenance.py — the same fields
-``bench.py`` pins into its device entries), the span totals of where
-the wall clock went, and the full metrics-registry snapshot. The
-bench ingests this file directly instead of re-deriving provenance;
-a run whose manifest says ``"platform": "cpu"`` can never be mistaken
-for device evidence.
+actually dispatched to (obs/provenance.py), the span totals of where
+the wall clock went, and the full metrics-registry snapshot. A run
+whose manifest says ``"platform": "cpu"`` can never be mistaken for
+device evidence.
 """
 
 from __future__ import annotations
@@ -20,9 +18,8 @@ from .metrics import MetricsRegistry, get_registry
 from .provenance import backend_provenance, env_provenance
 from .tracing import Tracer, get_tracer
 
-#: keys every manifest must carry — validated by the obs smoke and by
-#: bench-side ingestion (a manifest missing one of these is not run
-#: evidence)
+#: keys every manifest must carry — validated by :func:`load_manifest`
+#: (a manifest missing one of these is not run evidence)
 REQUIRED_KEYS = ("schema", "ts", "argv", "env", "backend", "spans",
                  "metrics", "trace_id")
 
@@ -109,10 +106,9 @@ def write_manifest(path: str, **kw) -> dict:
 
 
 def load_manifest(path: str) -> dict:
-    """Parse + validate a manifest (the bench's ingestion entry): the
-    REQUIRED_KEYS must be present and the
-    backend block must carry either provenance fields or an explicit
-    error.
+    """Parse + validate a manifest: the REQUIRED_KEYS must be present
+    and the backend block must carry either provenance fields or an
+    explicit error.
 
     Schema policy: any ``goleft-tpu.run-manifest/1.x`` revision loads
     (minor revisions only add fields — a reader must survive

@@ -410,7 +410,7 @@ class MemorySampler:
     def sample_once(self, pss: bool = False) -> dict:
         """Take one sample: host RSS/peak into the gauges, a device
         live-buffer scan, one pressure-band evaluation. Returns the
-        host dict (the overhead bench drives this directly). The
+        host dict (the pinned overhead test drives this directly). The
         periodic tick skips the expensive smaps_rollup Pss read —
         see :func:`read_host_memory`."""
         host = read_host_memory(pss=pss)

@@ -9,8 +9,8 @@ and the serve daemon's /metrics body) is the whole process's counter
 evidence. Serve tests construct private registries for isolation.
 
 Histograms share :func:`goleft_tpu.utils.profiling.percentiles` with
-the bench, so a latency summary means the same thing in /metrics, the
-run manifest and ``serve_throughput``.
+the serve daemon, so a latency summary means the same thing in /metrics
+and the run manifest.
 
 Snapshot determinism: ``snapshot()`` sorts every name and rounds
 consistently, so two snapshots of identical state serialize to

@@ -1,8 +1,6 @@
 """Backend/platform provenance: the ONE place that answers "what ran
-this" — shared by the run manifest, the device-event spans and the
-bench artifacts, so their platform/device fields can never drift apart
-(the ROADMAP's device-evidence gap was exactly three instruments
-answering that question separately).
+this" — shared by the run manifest and the device-event spans, so
+their platform/device fields can never drift apart.
 """
 
 from __future__ import annotations

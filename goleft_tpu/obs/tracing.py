@@ -332,8 +332,7 @@ class Tracer:
 
     def summary(self, trace_id: str | None = None) -> dict:
         """{name: {seconds, calls}} totals over the buffered spans —
-        the manifest's spans block (StageTimer.as_dict's shape, so the
-        bench can ingest either)."""
+        the manifest's spans block (StageTimer.as_dict's shape)."""
         out: dict[str, dict] = {}
         for sp in self.snapshot():
             if trace_id is not None and sp.trace_id != trace_id:

@@ -15,7 +15,7 @@ device-decodable since the ORDER1/STRIPE scan landed:
      byte counters and the ORDER1 table share
      (``decode.table_bytes_total``) visible (on tiny fixture blocks
      the per-block table floor dominates — the ratio only wins at
-     CRAM-typical block sizes, which the bench records);
+     CRAM-typical block sizes, not measured on the chip);
   3. an injected transient fault at the ``decode`` site is retried
      under the RetryPolicy to the same byte-identical output (the
      decode step is a real plan Step, not a bare device call).

@@ -188,10 +188,11 @@ def shard_depth_pipeline_packed(
 # block_until_ready, so per-dispatch device time is honest instead of
 # enqueue-microseconds. Off (the default), a call is a flag check away
 # from the raw jitted function, async dispatch intact. Jit attributes
-# (_cache_size, lower, …) forward through — bench.py's compile-cache
-# cross-check keeps working — and calls made INSIDE a jax trace (the
-# vmapped wrappers in commands/depth.py and commands/cohortdepth.py
-# close over these names) pass straight through untouched.
+# (_cache_size, lower, …) forward through — the compile observatory
+# and the AOT compile tests read them — and calls made INSIDE a jax
+# trace (the vmapped wrappers in commands/depth.py and
+# commands/cohortdepth.py close over these names) pass straight
+# through untouched.
 shard_depth_pipeline = _InstrumentedDispatch(
     shard_depth_pipeline, "shard_depth_pipeline")
 shard_depth_pipeline_cls_packed = _InstrumentedDispatch(

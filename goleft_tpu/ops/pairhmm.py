@@ -418,8 +418,3 @@ def forward_pairs_partial(reads, quals, haps, *,
         contribs, shifts = outcome.value
         out[np.asarray(idxs)] = _fold_contribs(contribs, shifts)
     return out, failed
-
-
-def total_cells(reads, haps) -> int:
-    """DP cell count Σ |read|·|hap| — the GCUPS denominator."""
-    return int(sum(len(r) * len(h) for r, h in zip(reads, haps)))

@@ -16,17 +16,14 @@ depth out, no 40MB delta array written and re-read.
 Windowed sums / callable classes stay in XLA (cheap fused elementwise on
 the kernel's output).
 
-STATUS: EXPERIMENTAL — parked, not a product path. Measured on TPU v5e
-(10Mb shard, 30×/150bp): 0.26 ms/shard (~39 Gbases/s) — correct but
-slower than the XLA scatter+cumsum pipeline (~0.06 ms device-resident;
-the recorded comparison lives in BENCH_details.json
-``pallas_vs_xla_depth``). The XLA path sits at the HBM roofline
-(bench.py kernel roofline block), so no amount of VMEM fusion of the
-window sums / class packing recovers the gap: this kernel's cost is
-O(endpoints/tile) vector compares per position — algorithmic, not
-traffic. Kept tested (tests/test_pallas_coverage.py) as the template
-for future VMEM-resident variants and as the only in-repo example of
-the sequential-grid carry pattern.
+STATUS: EXPERIMENTAL — parked, not a product path, and not measured
+on the chip: it compiles for the v5e (tests/test_tpu_compile.py) and
+has run in no cell. The XLA path it would replace reads 44.116 ms a
+10 Mb shard in ``depth30x.jobs``, 0.26% of the HBM roofline (ledger,
+PR 30), so there is room; this kernel's cost is O(endpoints/tile)
+vector compares per position. One run inside ``cohort4x.jobs`` decides
+whether it becomes the product path or goes (ROADMAP.md, Design 3).
+Kept tested (tests/test_pallas_coverage.py) until then.
 """
 
 from __future__ import annotations

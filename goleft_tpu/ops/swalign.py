@@ -342,7 +342,7 @@ def align_pairs(reads, wins, scores: Scores = DEFAULT_SCORES,
     wavefront dispatch. ``dispatch``, when given, wraps each bucket
     call — the mapping pipeline passes its plan-Step runner there so
     extension rides the ``map`` fault site with per-bucket
-    quarantine; ``None`` dispatches directly (tests, bench).
+    quarantine; ``None`` dispatches directly (tests).
     """
     out: list[Alignment | None] = [None] * len(reads)
     groups: dict[tuple[int, int], list[int]] = {}

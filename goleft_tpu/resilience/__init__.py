@@ -21,7 +21,7 @@ paths) and the depth-only ResultCache.
     executors' device dispatch
   - :mod:`~goleft_tpu.resilience.smoke` — the ``make chaos-smoke``
     body: SIGKILL a cohort run mid-flight, resume it, assert
-    byte-identity (+ quarantine and resume-overhead checks)
+    byte-identity (+ a quarantine check)
 
 Import is jax-free and cheap; the run-manifest "resilience" section is
 registered here so any command that engages the subsystem reports its

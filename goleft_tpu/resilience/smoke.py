@@ -14,22 +14,20 @@ must come back byte-identical:
   4. a permanently-corrupt sample → the run quarantines it and exits 3
      with the partial cohort, byte-identical to a cold run over the
      healthy samples, plus ``quarantine.json`` naming the culprit
-  5. happy-path overhead: the ``cohort_resume_overhead`` measurement
-     (the bench entry body) must show ≤5% checkpointing overhead
 
 then the serve legs — the same failure domains against a REAL
 ``goleft-tpu serve`` daemon (PR 7):
 
-  6. poison isolation: a coalesced batch of 8 depth requests with one
+  5. poison isolation: a coalesced batch of 8 depth requests with one
      corrupt BAM → seven 200s byte-identical to solo runs, one 400
      flagged ``poison``, ``serve.poison_total`` incremented
-  7. circuit breaker: injected permanent device faults trip the
+  6. circuit breaker: injected permanent device faults trip the
      endpoint (500,500,500 → 503 shed with retry_after) and a
      half-open probe recovers it to 200/closed
-  8. watchdog: an injected hung device pass is abandoned after the
+  7. watchdog: an injected hung device pass is abandoned after the
      budget and its request re-queued to a 200
      (``serve.watchdog_requeues_total``)
-  9. checkpointed serve requests: a ``checkpoint: true`` cohortdepth
+  8. checkpointed serve requests: a ``checkpoint: true`` cohortdepth
      request dies with a SIGKILLed daemon mid-run; re-issued against a
      restarted daemon it resumes from the journal byte-identically
      (``checkpoint.shards_resumed_total`` > 0 in the /metrics
@@ -37,9 +35,9 @@ then the serve legs — the same failure domains against a REAL
 
 and the fleet legs (PR 9, bodies shared with ``make fleet-smoke``):
 
-  10. a fleet worker is SIGKILLed mid-flight; the router retries the
+  9. a fleet worker is SIGKILLed mid-flight; the router retries the
       request on its sibling to a byte-identical 200
-  11. one worker's ``pairhmm`` breaker is tripped; the router imports
+  10. one worker's ``pairhmm`` breaker is tripped; the router imports
       the breaker state and re-routes ONLY pairhmm traffic — the
       worker's depth traffic keeps landing on it (plus the per-tenant
       quota 429/retry_after_s leg riding the same router)
@@ -56,8 +54,6 @@ import os
 import subprocess
 import sys
 import tempfile
-
-OVERHEAD_BUDGET = 0.05
 
 
 def _make_cohort(d: str, n_samples: int = 3, ref_len: int = 6000,
@@ -419,26 +415,7 @@ def run_smoke(timeout_s: float = 180.0, verbose: bool = True) -> int:
             print("chaos-smoke: corrupt sample quarantined (exit 3, "
                   "partial cohort byte-identical, manifest ok)")
 
-        # 5. happy-path overhead budget (the bench entry body): one
-        # retry at a larger fixture before declaring a regression —
-        # single-digit-percent timing on a shared host is noisy
-        from .overhead import measure_resume_overhead
-
-        entry = measure_resume_overhead(quick=True)
-        if entry["overhead_frac"] > OVERHEAD_BUDGET:
-            entry = measure_resume_overhead(quick=False)
-        if entry["overhead_frac"] > OVERHEAD_BUDGET:
-            raise RuntimeError(
-                "checkpointing overhead "
-                f"{entry['overhead_frac']:.1%} exceeds the "
-                f"{OVERHEAD_BUDGET:.0%} budget: {entry}")
-        if verbose:
-            print(f"chaos-smoke: checkpoint overhead "
-                  f"{entry['overhead_frac']:.1%} <= "
-                  f"{OVERHEAD_BUDGET:.0%} (resume replay "
-                  f"{entry['resume_speedup']}x faster)")
-
-        # 6-9. the serve legs: the same failure domains against a
+        # 5-8. the serve legs: the same failure domains against a
         # real daemon (poison isolation, breaker trip/recover,
         # watchdog re-queue, checkpointed requests across a SIGKILL)
         healthy_bam = bams[0]  # bams[1] was corrupted by step 4
@@ -448,7 +425,7 @@ def run_smoke(timeout_s: float = 180.0, verbose: bool = True) -> int:
         _serve_checkpoint_leg(d, [bams[0], bams[2]], fai, bed, env,
                               verbose)
 
-        # 10-11. the fleet failure domains (bodies shared with
+        # 9-10. the fleet failure domains (bodies shared with
         # `make fleet-smoke`): SIGKILLed worker → router retry, and
         # a tripped per-site breaker shedding only its own traffic.
         # bams[1] is corrupt by now — hand the legs healthy inputs.

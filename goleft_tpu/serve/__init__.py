@@ -24,7 +24,7 @@ Pieces (all stdlib — no new dependencies):
   server.py     ThreadingHTTPServer app: /v1/{depth,indexcov,
                 cohortdepth}, /healthz, /metrics, session result
                 cache (parallel/scheduler.ResultCache), SIGTERM drain
-  client.py     thin stdlib client (urllib) for scripts and the bench
+  client.py     thin stdlib client (urllib) for scripts and the smokes
   metrics.py    request/batch/cache counters + latency percentiles
   smoke.py      the `make serve-smoke` end-to-end check
 

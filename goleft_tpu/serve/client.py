@@ -1,6 +1,6 @@
 """Thin stdlib client for the serve daemon and the fleet router.
 
-urllib-only so scripts, the bench and `make serve-smoke` need nothing
+urllib-only so scripts and `make serve-smoke` need nothing
 beyond this repo. Methods mirror the routes; non-2xx responses raise
 :class:`ServeError` carrying the HTTP status, the server's error
 message and (when the server sent one) its ``retry_after_s`` hint —

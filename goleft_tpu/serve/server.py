@@ -71,8 +71,8 @@ log = get_logger("serve")
 
 class ServeApp:
     """Wiring between the HTTP surface, the batcher, the executors and
-    the session cache; independent of any socket so tests (and the
-    bench) can drive it in-process."""
+    the session cache; independent of any socket so tests can drive
+    it in-process."""
 
     def __init__(self, batch_window_s: float = 0.01,
                  max_batch: int = 16, max_queue: int = 64,
@@ -609,7 +609,7 @@ def make_server(app: ServeApp, host: str = "127.0.0.1",
 
 
 class ServerThread:
-    """In-process server harness: the tests' and bench's entry.
+    """In-process server harness: the tests' entry.
 
     with ServerThread(app) as base_url: ...  # "http://127.0.0.1:PORT"
     """

@@ -1,12 +1,12 @@
-"""Decode-thread scaling measurement shared by the bench and tests.
+"""Decode-thread scaling: the pool-width policy and its measurement.
 
 The cohort engine's native calls release the GIL, so per-sample window
 reductions scale across decode threads on multi-core hosts (the
 reference's equivalent is its process pool, depth/depth.go:392-394).
 ``measure_scaling`` runs that claim: N concurrent ``window_reduce``
 calls on distinct mmap-backed files vs the same calls serial.
-bench.py records the numbers in BENCH_details.json;
-tests/test_thread_scaling.py asserts them.
+tests/test_thread_scaling.py asserts what holds on any host (bounded
+threading overhead); no number of it has been taken on the chip host.
 """
 
 from __future__ import annotations
@@ -80,9 +80,8 @@ def measure_scaling(paths, ref_len: int, window: int = 500,
 def default_thread_counts(cores: int | None = None, n_tasks: int = 4):
     """Worker counts worth measuring on this host: 1, the core count,
     the midpoint, one oversubscribed point (capped by tasks — more
-    workers than tasks measures nothing) and the full task width (the
-    historical bench point, kept so threaded_over_serial stays
-    comparable across rounds)."""
+    workers than tasks measures nothing) and the full task width
+    (the point ``threaded_over_serial`` is read at)."""
     cores = effective_cores() if cores is None else cores
     cand = {1, min(2, n_tasks), min(cores, n_tasks),
             min(2 * cores, n_tasks), n_tasks}

@@ -12,8 +12,8 @@ once, in this process, before any backend use.
   says; without it, at the fixed ``<checkout>/.jax_cache``, so that
   every run of one checkout finds what the last one compiled.
 
-``cli.main`` (device commands), ``bench.py`` and ``__graft_entry__.py``
-all come through :func:`take_backend`.
+``cli.main`` (device commands) and ``__graft_entry__.py`` both come
+through :func:`take_backend`.
 """
 
 from __future__ import annotations

@@ -82,7 +82,8 @@ class StageTimer:
                     self.spans.append((name, t0, t1))
 
     def as_dict(self, ndigits: int = 4) -> dict:
-        """{stage: {"seconds", "calls"}} snapshot for bench artifacts."""
+        """{stage: {"seconds", "calls"}} snapshot (serve's /metrics
+        ``stage_seconds``)."""
         with self._lock:
             return {
                 name: {
@@ -118,8 +119,8 @@ class StageTimer:
 def percentiles(values, qs=(50, 95, 99), ndigits: int = 4) -> dict:
     """{"p50": ..., "p95": ..., "p99": ..., "max": ..., "count": n}
     nearest-rank percentiles over a sequence of seconds — the latency
-    summary the serve daemon's /metrics endpoint, the obs registry's
-    histograms and the bench's serve_throughput entry all share.
+    summary the serve daemon's /metrics endpoint and the obs registry's
+    histograms share.
     Empty input returns {"count": 0} (no fabricated zeros)."""
     vals = sorted(float(v) for v in values)
     out: dict = {"count": len(vals)}

@@ -1,6 +1,6 @@
 """Test environment: force JAX onto a virtual 8-device CPU platform.
 
-Real-TPU execution is exercised by bench.py and the driver's dryrun; tests
+Real-TPU execution is exercised by benchmark/run.py and chip_smoke.py; tests
 must be hermetic and validate sharding semantics on virtual devices
 (one real chip is all we have, and CI may have none).
 

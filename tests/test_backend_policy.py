@@ -1,6 +1,6 @@
 """The backend policy (utils/device_guard.take_backend), the compile
 cache it places, and the programs that must come through it: the CLI's
-device commands, bench.py, __graft_entry__.py — plus chip_smoke.py's
+device commands, __graft_entry__.py — plus chip_smoke.py's
 outer contract and the two fallbacks this PR turned into failures."""
 
 import os
@@ -143,10 +143,10 @@ def test_cache_path_is_fixed():
     assert ".jax_cache/" in open(os.path.join(REPO, ".gitignore")).read()
 
 
-@pytest.mark.parametrize("program", ["bench.py", "__graft_entry__.py",
+@pytest.mark.parametrize("program", ["__graft_entry__.py",
                                      "goleft_tpu/cli.py"])
 def test_every_entry_point_comes_through_the_policy(program):
-    """CLI, bench.py and __graft_entry__.py place the cache and take
+    """The CLI and __graft_entry__.py place the cache and take
     the backend through take_backend, and set neither on their own."""
     src = open(os.path.join(REPO, program)).read()
     assert "take_backend()" in src

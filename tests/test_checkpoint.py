@@ -15,6 +15,8 @@ import pytest
 import goleft_tpu
 from goleft_tpu.commands import cohortdepth as cd
 from goleft_tpu.commands import depth as depth_mod
+from goleft_tpu.io import native
+from goleft_tpu.io.bam import BamFile
 from goleft_tpu.io.fai import write_fai
 from goleft_tpu.obs import get_registry
 from goleft_tpu.resilience.checkpoint import (
@@ -200,22 +202,34 @@ def test_cohortdepth_stale_input_invalidates_only_its_shards(
     assert fresh != cold
 
 
+@pytest.mark.parametrize("engine", ["device", "hybrid"])
 def test_cohortdepth_midstream_failure_quarantines_and_zero_fills(
-        tmp_path, monkeypatch, capsys):
+        tmp_path, monkeypatch, capsys, engine):
+    """Each engine named (``auto`` picks by whether the native library
+    loaded), and each failing in its own decode entry."""
+    if engine == "hybrid" and native.get_lib() is None:
+        pytest.skip("native toolchain unavailable")
     monkeypatch.setattr(depth_mod, "STEP", 1000)
     fa, bams = _cohort(tmp_path, seed=4)
-    rc, cold = _run_cd(bams, fa)
+    rc, cold = _run_cd(bams, fa, engine=engine)
 
-    real = cd._decode_shard_segments
+    # the segment decode of the device engine takes the region's start
+    # fourth, the hybrid engine's fused decode+reduce second (after
+    # self, for both)
+    owner, name, s_at = {
+        "device": (cd, "_decode_shard_segments", 3),
+        "hybrid": (BamFile, "window_reduce", 2),
+    }[engine]
+    real = getattr(owner, name)
 
-    def failing(h, bai, tid, s, e, mapq):
-        if s >= 2000:  # regions 3+4: corruption past the midpoint
+    def failing(*a, **kw):
+        if a[s_at] >= 2000:  # regions 3+4: corruption past the midpoint
             raise ValueError("simulated mid-stream corruption")
-        return real(h, bai, tid, s, e, mapq)
+        return real(*a, **kw)
 
-    monkeypatch.setattr(cd, "_decode_shard_segments", failing)
+    monkeypatch.setattr(owner, name, failing)
     ck = str(tmp_path / "ck")
-    rc, out = _run_cd(bams, fa, checkpoint_dir=ck)
+    rc, out = _run_cd(bams, fa, checkpoint_dir=ck, engine=engine)
     assert rc == 3
     # the matrix still has every row and every column (zero-filled
     # tails — a streamed matrix cannot unwrite columns)
@@ -231,7 +245,8 @@ def test_cohortdepth_midstream_failure_quarantines_and_zero_fills(
     assert "quarantined" in capsys.readouterr().err
     # quarantined columns are NOT checkpointed: a resume recomputes
     # regions 3+4, fails again, and degrades identically
-    rc2, out2 = _run_cd(bams, fa, checkpoint_dir=ck, resume=True)
+    rc2, out2 = _run_cd(bams, fa, checkpoint_dir=ck, resume=True,
+                        engine=engine)
     assert rc2 == 3 and out2 == out
 
 

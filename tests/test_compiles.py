@@ -73,18 +73,8 @@ def test_unattributed_compile_log_still_lands():
     (key, rec), = t.stats().items()
     assert key[0] == "unattributed"
     assert rec["compiles"] == 1
-    # the process-lifetime counter the bench historically kept
     snap = t._reg().snapshot()
     assert snap["counters"]["xla.compiles_total"] == 1
-
-
-def test_observe_window_collects_names_like_bench():
-    t = _tracker()
-    with t.window() as h:
-        t._on_compile_log("jit(a)")
-        t._on_compile_log("jit(b)")
-    t._on_compile_log("jit(after)")  # outside the window
-    assert h.names == ["jit(a)", "jit(b)"]
 
 
 def test_observe_exception_still_records_the_compile():

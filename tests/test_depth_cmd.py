@@ -213,3 +213,43 @@ def test_depth_stats_columns(tmp_path):
     assert float(chr1[4]) == pytest.approx(0.5, abs=0.01)  # ACGT repeat
     chr2 = [r for r in rows if r[0] == "chr2"][0]
     assert float(chr2[4]) == pytest.approx(0.5, abs=0.01)  # AC repeat gc=.5
+
+
+def test_depth_scale_adds_shards_not_compiles(tmp_path):
+    """A whole genome of uneven contigs through ``cli.main``: the depth
+    program compiles once per segment bucket (one static length for the
+    genome), so the compile count is bucket geometry, far below one per
+    contig, and a warm repeat of every contig adds none."""
+    from goleft_tpu import obs
+    from goleft_tpu.cli import main as cli_main
+
+    rng = np.random.default_rng(2)
+    lens = [int(300_000 * (1 - 0.055 * i)) for i in range(6)]
+    names = [f"chr{i + 1}" for i in range(len(lens))]
+    reads = [r for tid, ln in enumerate(lens)
+             for r in random_reads(rng, ln * 2 // 100, tid, ln)]
+    bam = str(tmp_path / "wg.bam")
+    write_bam_and_bai(
+        bam, reads, ref_names=names, ref_lens=lens,
+        header_text="@HD\tVN:1.6\tSO:coordinate\n" + "".join(
+            f"@SQ\tSN:{n}\tLN:{ln}\n" for n, ln in zip(names, lens)))
+    with open(tmp_path / "ref.fa.fai", "w") as fh:
+        for n, ln in zip(names, lens):
+            fh.write(f"{n}\t{ln}\t6\t60\t61\n")
+    compiles = obs.get_registry().counter("xla.compiles_total")
+
+    def run(tag):
+        n0 = compiles.value
+        assert cli_main(["depth", "--prefix", str(tmp_path / tag),
+                         "-r", str(tmp_path / "ref.fa"), "-w", "250",
+                         "-Q", "20", bam]) == 0
+        return compiles.value - n0
+
+    cold, warm = run("cold"), run("warm")
+    assert cold <= len(lens) // 2
+    assert warm == 0
+    rows = read_bed(str(tmp_path / "warm.depth.bed"))
+    for n, ln in zip(names, lens):
+        assert_tiles(rows, n, ln)
+    assert open(tmp_path / "cold.depth.bed").read() == \
+        open(tmp_path / "warm.depth.bed").read()

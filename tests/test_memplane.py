@@ -152,8 +152,7 @@ def test_span_mem_attrs_ride_exactly_while_sampler_runs():
 
 def test_sample_tick_cost_within_one_percent_duty_cycle():
     """The leak sentinel's overhead pin: one periodic tick must cost
-    <= 1% of the 0.1s operational cadence (the memory_overhead bench
-    entry records the same duty cycle)."""
+    <= 1% of the 0.1s operational cadence."""
     reg = MetricsRegistry()
     s = MemorySampler(interval_s=0.1, registry=reg,
                       tracker=MemoryTracker(registry=reg))

@@ -1,7 +1,6 @@
 """--no-crc fast mode: what is still caught, and what is not.
 
-The BGZF payload CRC is the largest share of per-sample decode cost
-(BENCH_details.json cohort_e2e.decode_floor, ~+24% e2e when skipped).
+The BGZF payload CRC is a large share of per-sample decode cost.
 ``--no-crc`` trades it away for trusted local files. The contract these
 tests pin down, corruption class by corruption class:
 
