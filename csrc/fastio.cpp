@@ -11,6 +11,7 @@
 // (see goleft_tpu/io/native.py, which builds lazily and falls back to the
 // pure-Python codecs on any failure).
 
+#include <algorithm>
 #include <climits>
 #include <cmath>
 #include <cstdint>
@@ -1062,16 +1063,23 @@ long bgzf_stream_inflate_only(const uint8_t* comp, long comp_len,
     return 0;
 }
 
-// Scan a .bai: per reference, the bin-section byte range, linear-index
-// range, and stats-bin (0x924A) counts — without materializing per-bin
-// chunk lists (Python parses one reference's bins lazily if a region
-// query ever needs them; indexcov needs only intervals + stats, and the
-// pure-Python bin walk was ~0.7s per whole-genome index). Returns n_ref
-// or negative: -1 bad magic, -2 truncated, -3 over max_ref.
-long bai_scan(const uint8_t* data, long len, long max_ref,
-              int64_t* bins_start, int64_t* bins_end,
-              int64_t* n_intv_out, int64_t* intv_off,
-              int64_t* mapped, int64_t* unmapped) {
+}  // extern "C" — the .bai walker below is a template
+
+// One reference of a .bai as the walker below finds it: the byte range of
+// its bin section, its linear index, and the stats pseudo-bin's (0x924A)
+// counts, -1 where the reference has none.
+struct BaiRef {
+    int64_t bins_start, bins_end, n_intv, intv_off, mapped, unmapped;
+};
+
+// THE walk of a .bai's structure (SAM specification 5.2), without
+// materializing per-bin chunk lists: every bounds check of the format
+// lives here, for both entries below. Calls on_ref(r, ref) once a
+// reference, in order. Returns n_ref or negative: -1 bad magic,
+// -2 truncated, -3 over max_ref.
+template <class OnRef>
+static long bai_walk(const uint8_t* data, long len, long max_ref,
+                     OnRef on_ref) {
     if (len < 8 || memcmp(data, "BAI\x01", 4) != 0) return -1;
     long off = 4;
     int32_t n_ref;
@@ -1084,9 +1092,10 @@ long bai_scan(const uint8_t* data, long len, long max_ref,
         memcpy(&n_bin, data + off, 4);
         off += 4;
         if (n_bin < 0) return -2;
-        bins_start[r] = off;
-        mapped[r] = -1;
-        unmapped[r] = -1;
+        BaiRef ref;
+        ref.bins_start = off;
+        ref.mapped = -1;
+        ref.unmapped = -1;
         for (long b = 0; b < n_bin; b++) {
             if (off + 8 > len) return -2;
             uint32_t bno;
@@ -1099,22 +1108,158 @@ long bai_scan(const uint8_t* data, long len, long max_ref,
                 uint64_t m, u;
                 memcpy(&m, data + off + 16, 8);
                 memcpy(&u, data + off + 24, 8);
-                mapped[r] = (int64_t)m;
-                unmapped[r] = (int64_t)u;
+                ref.mapped = (int64_t)m;
+                ref.unmapped = (int64_t)u;
             }
             off += 16L * n_chunk;
         }
-        bins_end[r] = off;
+        ref.bins_end = off;
         if (off + 4 > len) return -2;
         int32_t n_intv;
         memcpy(&n_intv, data + off, 4);
         off += 4;
         if (n_intv < 0 || off + 8L * n_intv > len) return -2;
-        n_intv_out[r] = n_intv;
-        intv_off[r] = off;
+        ref.n_intv = n_intv;
+        ref.intv_off = off;
         off += 8L * n_intv;
+        on_ref(r, ref);
     }
     return n_ref;
+}
+
+extern "C" {
+
+// Scan a .bai for the region-query readers (io/bai.py read_bai): per
+// reference the walker's six numbers (Python parses one reference's bins
+// lazily if a region query ever needs them; the pure-Python bin walk was
+// ~0.7s per whole-genome index). Returns what bai_walk returns.
+long bai_scan(const uint8_t* data, long len, long max_ref,
+              int64_t* bins_start, int64_t* bins_end,
+              int64_t* n_intv_out, int64_t* intv_off,
+              int64_t* mapped, int64_t* unmapped) {
+    return bai_walk(data, len, max_ref, [&](long r, const BaiRef& ref) {
+        bins_start[r] = ref.bins_start;
+        bins_end[r] = ref.bins_end;
+        n_intv_out[r] = ref.n_intv;
+        intv_off[r] = ref.intv_off;
+        mapped[r] = ref.mapped;
+        unmapped[r] = ref.unmapped;
+    });
+}
+
+// indexcov's scaling median of n >= 1 non-negative tile sizes, to the
+// bit what ops/indexcov_ops.py median_size_per_tile computes
+// (indexcov/indexcov.go:96-124): in ascending order s, cap at
+// n98 = s[int(0.98 n)] and take the uncapped value at the first rank
+// whose running sum of capped values exceeds half their total (the
+// last rank where none does). int64 throughout, and exact, without
+// sorting the whole: the values are counted and summed into 2,048
+// buckets of equal width between the least and the largest, in which
+// the sorted order is known bucket by bucket, and only the bucket that
+// holds rank int(0.98 n) and the one in which the running sum crosses
+// are looked into (copied to tmp, n int64, and selected or sorted
+// there).
+static double tile_size_median(const int64_t* v, long n, int64_t* tmp) {
+    const int BUCKETS = 2048;
+    int64_t lo = v[0], hi = v[0];
+    for (long i = 1; i < n; i++) {
+        lo = v[i] < lo ? v[i] : lo;
+        hi = v[i] > hi ? v[i] : hi;
+    }
+    if (lo == hi) return (double)hi;
+    int shift = 0;
+    while (((hi - lo) >> shift) >= BUCKETS) shift++;
+    int64_t cnt[BUCKETS] = {0}, sum[BUCKETS] = {0};
+    for (long i = 0; i < n; i++) {
+        const long b = (v[i] - lo) >> shift;
+        cnt[b]++;
+        sum[b] += v[i];
+    }
+    auto gather = [&](long bucket) {
+        long m = 0;
+        for (long i = 0; i < n; i++)
+            if (((v[i] - lo) >> shift) == bucket) tmp[m++] = v[i];
+        return m;
+    };
+    // n98: rank k98 of the whole is rank k98 - before of its bucket
+    const long k98 = (long)(0.98 * (double)n);
+    long b98 = 0, before = 0;
+    while (before + cnt[b98] <= k98) before += cnt[b98++];
+    long m = gather(b98);
+    std::nth_element(tmp, tmp + (k98 - before), tmp + m);
+    const int64_t n98 = tmp[k98 - before];
+    int64_t capped98 = 0;  // of bucket b98, where the cap falls
+    for (long j = 0; j < m; j++) capped98 += tmp[j] < n98 ? tmp[j] : n98;
+    auto capped = [&](long b) {
+        return b < b98 ? sum[b] : b == b98 ? capped98 : cnt[b] * n98;
+    };
+    int64_t total = 0;
+    for (long b = 0; b < BUCKETS; b++) total += capped(b);
+    const int64_t half = total / 2;  // total >= 0: floor, as Python's //
+    int64_t run = 0;
+    long bx = 0;
+    while (bx < BUCKETS && run + capped(bx) <= half) run += capped(bx++);
+    if (bx == BUCKETS) return (double)hi;  // total 0: the last rank
+    m = gather(bx);
+    std::sort(tmp, tmp + m);
+    for (long j = 0; j < m; j++) {
+        run += tmp[j] < n98 ? tmp[j] : n98;
+        if (run > half) return (double)tmp[j];
+    }
+    return (double)hi;  // not reached: bucket bx crosses half
+}
+
+// What indexcov needs of a .bai, in one pass over its bytes
+// (commands/indexcov.py SampleIndex): the per-16KB-tile sizes of every
+// reference (the deltas of neighbouring linear-index offsets; a
+// reference with fewer than two intervals has none) one after another
+// at the head of scratch, offsets[r]..offsets[r+1] being reference r's,
+// each reference's pseudo-bin counts, and the scaling median of all the
+// sizes. scratch holds scratch_cap int64 and has to hold twice the tiles
+// (the sizes, then what the median looks into); a caller that gives it
+// 2 * (len / 8) can never be short. Returns the number of tiles (the
+// median is written only when there is one) or negative: bai_walk's
+// codes, -4 a negative delta (the offsets of a sorted file never fall),
+// -5 scratch too small. The structure's faults come before -4, as
+// read_bai comes before BaiIndex.sizes.
+long bai_tile_sizes(const uint8_t* data, long len, long max_ref,
+                    int64_t* scratch, long scratch_cap,
+                    int64_t* offsets, int64_t* mapped, int64_t* unmapped,
+                    double* median) {
+    long tiles = 0;
+    bool falls = false, short_scratch = false;
+    const long room = scratch_cap / 2;
+    long n_ref = bai_walk(data, len, max_ref, [&](long r, const BaiRef& ref) {
+        offsets[r] = tiles;
+        mapped[r] = ref.mapped;
+        unmapped[r] = ref.unmapped;
+        if (ref.n_intv < 2 || falls || short_scratch) return;
+        const long n = ref.n_intv - 1;
+        if (n > room - tiles) { short_scratch = true; return; }
+        const uint8_t* p = data + ref.intv_off;
+        int64_t* out = scratch + tiles;
+        uint64_t prev, next;
+        memcpy(&prev, p, 8);
+        int64_t low = 0;
+        for (long i = 0; i < n; i++) {
+            memcpy(&next, p + 8 * (i + 1), 8);
+            // the difference of the offsets read as int64, wrapping
+            // as NumPy's does
+            const int64_t d = (int64_t)(next - prev);
+            low |= d;
+            out[i] = d;
+            prev = next;
+        }
+        if (low < 0) { falls = true; return; }
+        tiles += n;
+    });
+    if (n_ref < 0) return n_ref;
+    if (falls) return -4;
+    if (short_scratch) return -5;
+    offsets[n_ref] = tiles;
+    if (tiles > 0)
+        *median = tile_size_median(scratch, tiles, scratch + room);
+    return tiles;
 }
 
 static long fmt_g(double v, char* p, int prec);

@@ -24,7 +24,7 @@ import sys
 import numpy as np
 
 from .. import obs
-from ..io.bai import read_bai
+from ..io.bai import TileSizes, read_bai, read_tile_sizes
 from ..io.bedgz import BedGzStream
 from ..io.crai import read_crai
 from ..io.fai import read_fai
@@ -56,22 +56,30 @@ class SampleIndex:
 
         if path.endswith(".crai"):
             data = remote.fetch_bytes(path)
-            self.sizes = read_crai(data).sizes()
-            self.mapped = 0
-            self.unmapped = 0
+            t = TileSizes(read_crai(data).sizes(), 0, 0, len(data), None)
         else:
             bai_path = path
             if not path.endswith(".bai"):
                 bai_path = path + ".bai"
                 if not remote.exists(bai_path):
                     bai_path = path[:-4] + ".bai"
-            data = remote.fetch_bytes(bai_path)
-            idx = read_bai(data)
-            self.sizes = idx.sizes()
-            self.mapped = idx.mapped_total
-            self.unmapped = idx.unmapped_total
-        self.nbytes = len(data)  # of the index file as read
-        self.median = ops.median_size_per_tile(self.sizes)
+            # a local .bai in one native pass on buffers the thread keeps;
+            # a URL, or a build without the library, through read_bai
+            t = (None if remote.is_remote(bai_path)
+                 else read_tile_sizes(bai_path))
+            if t is None:
+                data = remote.fetch_bytes(bai_path)
+                idx = read_bai(data)
+                t = TileSizes(idx.sizes(), idx.mapped_total,
+                              idx.unmapped_total, len(data), None)
+        self.sizes = t.sizes
+        self.mapped = t.mapped
+        self.unmapped = t.unmapped
+        self.nbytes = t.nbytes  # of the index file as read
+        # the native pass brings the median unless the index has no tile,
+        # which median_size_per_tile refuses in its own words
+        self.median = (ops.median_size_per_tile(t.sizes)
+                       if t.median is None else t.median)
 
     def normalized_depth(self, ref_id: int) -> np.ndarray:
         if ref_id >= len(self.sizes):

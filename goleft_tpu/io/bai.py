@@ -16,10 +16,14 @@ written with io.bam.BamWriter (no copying of reference test data).
 
 from __future__ import annotations
 
+import collections
+import os
 import struct
 from dataclasses import dataclass
 
 import numpy as np
+
+from ..obs.metrics import get_registry
 
 BAI_MAGIC = b"BAI\x01"
 TILE_WIDTH = 0x4000  # 16384, matches indexcov/types.go:15
@@ -186,6 +190,75 @@ def read_bai(path_or_bytes) -> BaiIndex:
         return BaiIndex(refs, n_no_coor)
     except struct.error as e:
         raise ValueError(f"bai: truncated index ({e})")
+
+
+@dataclass
+class TileSizes:
+    """What ``indexcov`` reads of one index (commands/indexcov.py
+    SampleIndex): per-reference int64 tile sizes (views of one block),
+    the pseudo-bins' totals, the file's length and the scaling median."""
+    sizes: list[np.ndarray]
+    mapped: int
+    unmapped: int
+    nbytes: int
+    median: float | None  # None: not computed, or the index has no tile
+
+
+# (read buffer uint8, scratch int64) pairs of read_tile_sizes that no
+# load is using. A load takes one and gives it back, so there are as
+# many as loads ever ran side by side (a pool's width); one is made
+# anew when an index is larger than it, never shrunk, and all stay for
+# the process's next cohort: a load allocates nothing but its result
+_kept: collections.deque = collections.deque()
+
+
+def read_tile_sizes(path: str) -> TileSizes | None:
+    """A local ``.bai``'s tile sizes and scaling median in one native
+    pass (native.bai_tile_sizes) on kept buffers: the file is read into
+    the buffer, walked and differenced there and the median selected in
+    the scratch with the GIL released, and the one allocation is the
+    int64 block of sizes the caller keeps. The values are
+    ``read_bai(path).sizes()``'s and ``median_size_per_tile``'s to the
+    bit (the median None where the index has no tile), and corruption
+    raises the same typed ValueError. None where there is no native
+    library: the caller then takes those."""
+    from . import native
+
+    if native.get_lib() is None:
+        return None
+    reg = get_registry()
+    try:
+        buf, scratch = _kept.pop()
+    except IndexError:  # none idle: a pair is made below
+        buf = scratch = np.empty(0, np.uint8)
+    try:
+        with open(path, "rb", buffering=0) as fh:
+            n = os.fstat(fh.fileno()).st_size
+            if len(buf) < n:
+                # a quarter of headroom, so that a cohort's indexes,
+                # which differ by a few percent, grow a pair once
+                cap = n + n // 4
+                buf = np.empty(cap, np.uint8)
+                scratch = np.empty(
+                    native.bai_tile_sizes_scratch(cap), np.int64)
+                reg.counter("indexcov.index_buffer_grows_total").inc(2)
+            view = memoryview(buf)
+            got = 0
+            while got < n:
+                k = fh.readinto(view[got:n])
+                if not k:
+                    break
+                got += k
+        sizes, offsets, mapped, unmapped, median = native.bai_tile_sizes(
+            buf[:got], scratch)
+    finally:
+        _kept.append((buf, scratch))
+    reg.counter("indexcov.index_native_loads_total").inc()
+    return TileSizes(
+        [sizes[lo:hi] for lo, hi in zip(offsets[:-1], offsets[1:])],
+        sum(int(m) for m in mapped if m >= 0),
+        sum(int(u) for u in unmapped if u >= 0),
+        got, median)
 
 
 def write_bai(idx: BaiIndex, path: str) -> None:

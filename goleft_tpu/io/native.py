@@ -55,36 +55,43 @@ def get_lib() -> ctypes.CDLL | None:
     if _lib is not None or _tried:
         return _lib
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        if os.environ.get("GOLEFT_TPU_NO_NATIVE"):
-            return None
-        src = os.path.join(_root(), "csrc", "fastio.cpp")
-        out = os.environ.get("GOLEFT_TPU_ASAN_LIB") or os.path.join(
-            _root(), "build", "libgoleftio.so"
-        )
-        if not os.path.exists(out) or (
-            os.path.exists(src)
-            and os.path.getmtime(src) > os.path.getmtime(out)
-        ):
-            if not os.path.exists(src) or not _build(src, out):
-                return None
-        try:
-            lib = ctypes.CDLL(out)
-        except OSError as e:
-            log.warning("native load failed: %s", e)
-            return None
-        try:
-            _register_restypes(lib)
-        except AttributeError as e:
-            # stale prebuilt library missing a newer symbol: honor the
-            # module contract (pure-Python fallback on ANY failure)
-            log.warning("native library is stale (%s) — rebuild "
-                        "build/libgoleftio.so; using Python codecs", e)
-            return None
-        _lib = lib
+        if _lib is None and not _tried:
+            try:
+                _lib = _load_lib()
+            finally:
+                # only now: a thread that arrives while another builds
+                # waits on the lock, and does not take the fallback
+                _tried = True
         return _lib
+
+
+def _load_lib() -> ctypes.CDLL | None:
+    if os.environ.get("GOLEFT_TPU_NO_NATIVE"):
+        return None
+    src = os.path.join(_root(), "csrc", "fastio.cpp")
+    out = os.environ.get("GOLEFT_TPU_ASAN_LIB") or os.path.join(
+        _root(), "build", "libgoleftio.so"
+    )
+    if not os.path.exists(out) or (
+        os.path.exists(src)
+        and os.path.getmtime(src) > os.path.getmtime(out)
+    ):
+        if not os.path.exists(src) or not _build(src, out):
+            return None
+    try:
+        lib = ctypes.CDLL(out)
+    except OSError as e:
+        log.warning("native load failed: %s", e)
+        return None
+    try:
+        _register_restypes(lib)
+    except AttributeError as e:
+        # stale prebuilt library missing a newer symbol: honor the
+        # module contract (pure-Python fallback on ANY failure)
+        log.warning("native library is stale (%s) — rebuild "
+                    "build/libgoleftio.so; using Python codecs", e)
+        return None
+    return lib
 
 
 def _register_restypes(lib) -> None:
@@ -110,6 +117,7 @@ def _register_restypes(lib) -> None:
         lib.format_depth_rows.restype = ctypes.c_long
         lib.format_class_rows.restype = ctypes.c_long
         lib.bai_scan.restype = ctypes.c_long
+        lib.bai_tile_sizes.restype = ctypes.c_long
         lib.format_xy_json.restype = ctypes.c_long
         lib.format_float32_rows.restype = ctypes.c_long
 
@@ -492,6 +500,33 @@ def bgzf_stream_inflate_only(comp, check_crc: bool = True):
     return int(total.value)
 
 
+def _bai_max_ref(buf: np.ndarray) -> int:
+    """The most references the C walker may accept for ``buf``: what the
+    header claims, bound by what the bytes could possibly hold (every
+    reference costs >= 8 bytes), so a corrupt header cannot demand a
+    multi-GB allocation — genuinely oversized counts then fail in C with
+    -3 (over max_ref)."""
+    if len(buf) < 8:
+        raise ValueError("bai: truncated or corrupt index (-2)")
+    n_ref = max(int(np.frombuffer(buf[4:8], "<i4")[0]), 0)
+    return min(n_ref, len(buf) // 8 + 1)
+
+
+def _bai_raise(code: int) -> None:
+    """The C walker's negative return, as the readers' typed error."""
+    if code == -1:
+        raise ValueError("not a BAI file (bad magic)")
+    if code == -3:
+        # same diagnostic as the pure-Python fallback's byte-derived
+        # n_ref bound: the header claims more references than the
+        # bytes could hold
+        raise ValueError("bai: implausible n_ref (over what the bytes "
+                         "can hold)")
+    if code == -4:
+        raise ValueError("bai: negative voffset delta in linear index")
+    raise ValueError(f"bai: truncated or corrupt index ({code})")
+
+
 def bai_scan(data):
     """Single-pass .bai structure scan → dict of per-ref arrays
     (bins_start, bins_end, n_intv, intv_off, mapped, unmapped), or None
@@ -500,14 +535,7 @@ def bai_scan(data):
     if lib is None:
         return None
     buf = _as_u8(data)
-    if len(buf) < 8:
-        raise ValueError("bai: truncated or corrupt index (-2)")
-    # exact allocation: the header carries n_ref up front. Bound by
-    # what the bytes could possibly hold (every reference costs >= 8
-    # bytes), so a corrupt header cannot demand a multi-GB allocation —
-    # genuinely oversized counts then fail in C with -3 (over max_ref)
-    max_ref = max(int(np.frombuffer(buf[4:8], "<i4")[0]), 0)
-    max_ref = min(max_ref, len(buf) // 8 + 1)
+    max_ref = _bai_max_ref(buf)  # exact: the header carries n_ref up front
     arrs = {k: np.empty(max_ref, np.int64)
             for k in ("bins_start", "bins_end", "n_intv", "intv_off",
                       "mapped", "unmapped")}
@@ -517,17 +545,51 @@ def bai_scan(data):
           for k in ("bins_start", "bins_end", "n_intv", "intv_off",
                     "mapped", "unmapped")),
     )
-    if n == -1:
-        raise ValueError("not a BAI file (bad magic)")
-    if n == -3:
-        # same diagnostic as the pure-Python fallback's byte-derived
-        # n_ref bound: the header claims more references than the
-        # bytes could hold
-        raise ValueError("bai: implausible n_ref (over what the bytes "
-                         "can hold)")
     if n < 0:
-        raise ValueError(f"bai: truncated or corrupt index ({n})")
+        _bai_raise(n)
     return {k: v[:n] for k, v in arrs.items()}
+
+
+def bai_tile_sizes_scratch(n_bytes: int) -> int:
+    """int64 elements of scratch with which ``bai_tile_sizes`` cannot
+    be short on a .bai of ``n_bytes``: a tile costs the file 8 bytes,
+    and the call keeps the sizes and what its median looks into."""
+    return 2 * (n_bytes // 8)
+
+
+def bai_tile_sizes(buf: np.ndarray, scratch: np.ndarray):
+    """What ``indexcov`` needs of the .bai whose bytes are ``buf``
+    (uint8), in one GIL-free pass with no allocation but the result:
+    (sizes, offsets, mapped, unmapped, median). ``sizes`` is one fresh
+    int64 array of every reference's tile sizes, reference r's being
+    ``sizes[offsets[r]:offsets[r + 1]]``; ``mapped`` / ``unmapped`` the
+    per-reference pseudo-bin counts (-1 without one); ``median`` the
+    scaling median, ``ops.indexcov_ops.median_size_per_tile``'s to the
+    bit, None when the index has no tile. ``scratch`` is int64, of at
+    least ``bai_tile_sizes_scratch(len(buf))``; the library has to be
+    there (``get_lib()``). Corruption raises the
+    readers' typed ValueError, as ``read_bai`` and ``BaiIndex.sizes``."""
+    lib = get_lib()
+    if bytes(buf[:4]) != b"BAI\x01":
+        raise ValueError("not a BAI file (bad magic)")
+    max_ref = _bai_max_ref(buf)
+    if len(scratch) < bai_tile_sizes_scratch(len(buf)):
+        raise ValueError("bai_tile_sizes: scratch too small")
+    per_ref = np.empty((3, max_ref + 1), np.int64)
+    offsets, mapped, unmapped = per_ref
+    median = ctypes.c_double(0.0)
+    n = lib.bai_tile_sizes(
+        _ptr(buf), ctypes.c_long(len(buf)), ctypes.c_long(max_ref),
+        _ptr(scratch, ctypes.c_int64), ctypes.c_long(len(scratch)),
+        _ptr(offsets, ctypes.c_int64), _ptr(mapped, ctypes.c_int64),
+        _ptr(unmapped, ctypes.c_int64), ctypes.byref(median))
+    if n < 0:
+        _bai_raise(n)
+    # the walk accepted the header's n_ref, so max_ref is it
+    sizes = np.empty(n, np.int64)  # the one block the caller keeps
+    ctypes.memmove(sizes.ctypes.data, scratch.ctypes.data, 8 * n)
+    return (sizes, offsets, mapped[:max_ref], unmapped[:max_ref],
+            median.value if n else None)
 
 
 def float_rows_scratch_bytes(chrom: str, n_cols: int,

@@ -74,6 +74,52 @@ def test_bai_scan_native_fuzz(bai_bytes):
             pass
 
 
+def _outcome(load):
+    """What a load of one index came to: its values, or the words of
+    its typed error."""
+    try:
+        s = load()
+    except OK_ERRORS as e:
+        return ("refused", str(e))
+    return ("read", [a.tolist() for a in s.sizes], s.mapped, s.unmapped,
+            s.nbytes, s.median)
+
+
+@pytest.mark.native_io
+@pytest.mark.parametrize("seed", [1, 2, 4])  # the seeds of the tests above
+def test_bai_fuzz_one_pass_load_agrees_with_read_bai(bai_bytes, tmp_path,
+                                                     seed):
+    """indexcov's one-pass load (native.bai_tile_sizes behind
+    SampleIndex) on every mutation: the values read_bai, sizes() and
+    median_size_per_tile give, or their ValueError word for word."""
+    import types
+
+    from goleft_tpu.commands.indexcov import SampleIndex
+    from goleft_tpu.ops.indexcov_ops import median_size_per_tile
+
+    if native.get_lib() is None:
+        pytest.skip("native lib unavailable")
+
+    def python_path(data):
+        idx = read_bai(data)
+        sizes = idx.sizes()
+        return types.SimpleNamespace(
+            sizes=sizes, mapped=idx.mapped_total,
+            unmapped=idx.unmapped_total, nbytes=len(data),
+            median=median_size_per_tile(sizes))
+
+    path = str(tmp_path / "mut.bai")
+    refused = 0
+    for mut in [bai_bytes] + list(
+            _mutations(bai_bytes, np.random.default_rng(seed), 300)):
+        with open(path, "wb") as fh:
+            fh.write(mut)
+        want = _outcome(lambda: python_path(mut))
+        assert _outcome(lambda: SampleIndex(path)) == want
+        refused += want[0] == "refused"
+    assert 0 < refused < 301
+
+
 def test_crai_fuzz(tmp_path):
     from goleft_tpu.io.crai import read_crai
 

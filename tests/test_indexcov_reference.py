@@ -233,6 +233,12 @@ def test_the_job_records_the_span_vocabulary(job, name, category):
         "pca_ped_html"}
 
 
+def native_library() -> bool:
+    from goleft_tpu.io import native
+
+    return native.get_lib() is not None
+
+
 def sent_bytes(job) -> int:
     """Bytes of the arrays a job places on the device: a float32 matrix
     and its mask a contig, at the padded width, and the PCA's uint16
@@ -248,6 +254,9 @@ def sent_bytes(job) -> int:
 @pytest.mark.parametrize("counter,want", [
     ("indexcov.indexes_total", lambda j: j["meta"]["work"]["samples"]),
     ("indexcov.index_bytes_total", lambda j: j["meta"]["index_bytes"]),
+    # every index of the cohort is a local .bai: the one-pass load's
+    ("indexcov.index_native_loads_total",
+     lambda j: j["meta"]["work"]["samples"] * native_library()),
     ("indexcov.tile_samples_total",
      lambda j: j["meta"]["work"]["tile_samples"]),
     ("indexcov.qc_dispatches_total",
@@ -261,6 +270,13 @@ def sent_bytes(job) -> int:
 ])
 def test_the_job_counts_what_it_moved(job, counter, want):
     assert job["grew"][counter] == want(job)
+
+
+def test_the_index_load_makes_at_most_a_pair_of_buffers_a_thread(job):
+    """One read buffer and one scratch for each of the pool's 8 threads
+    at most (none where an earlier job of the process left them)."""
+    assert 0 <= job["grew"].get(
+        "indexcov.index_buffer_grows_total", 0) <= 2 * 8
 
 
 def test_the_stage_totals_keep_the_new_names(tmp_path, job):

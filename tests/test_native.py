@@ -100,6 +100,34 @@ def test_native_region_decode(tmp_path):
 
 
 @pytest.mark.native_io
+def test_get_lib_waits_for_the_build_in_progress(monkeypatch):
+    """A thread that asks while another builds the library gets the
+    library, not the pure-Python fallback (the first job of a fresh
+    checkout loads its indexes 8 at a time)."""
+    import concurrent.futures as cf
+    import threading
+    import time
+
+    started = threading.Event()
+    built = object()
+
+    def slow_build():
+        started.set()
+        time.sleep(0.3)
+        return built
+
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_load_lib", slow_build)
+    with cf.ThreadPoolExecutor(max_workers=4) as ex:
+        first = ex.submit(native.get_lib)
+        assert started.wait(timeout=10)
+        late = [ex.submit(native.get_lib) for _ in range(3)]
+        assert first.result(timeout=10) is built
+        assert [f.result(timeout=10) for f in late] == [built] * 3
+
+
+@pytest.mark.native_io
 def test_open_bam_fallback(tmp_path, monkeypatch):
     rng = np.random.default_rng(2)
     p = str(tmp_path / "t.bam")
