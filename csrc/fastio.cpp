@@ -1410,6 +1410,91 @@ long format_float32_rows(const char* chrom, long chrom_len,
     return w;
 }
 
+// The widest "%.2f" of a float32: '-', the 39 digits of 2^128 - 2^104
+// and ".00".
+enum { FIXED2_CELL_MAX = 43 };
+
+// "%.2f" of a float32 as Python's '"%.2f" % float(x)' writes it (so as
+// np.char.mod does): the value widened to double, which is exact, and
+// rounded correctly, a tie decided on the exact binary value, to even.
+// No printf and so no locale: a float32 has 24 significant bits and
+// 100 = 25 * 4, so a * 100.0 has at most 29 and is exact in a double;
+// what is cut off below the hundredths is then compared with one half
+// exactly. The traps: a NaN prints "nan" whatever its sign bit (0/0 on
+// x86 has it set, and glibc's printf would write "-nan"); -0.0 and a
+// negative that rounds to zero keep their sign ("-0.00"); 0.995f is
+// under its tie and 0.999f over it. Returns chars written, at most
+// FIXED2_CELL_MAX.
+static inline long fmt_fixed2(float x, char* p) {
+    uint32_t bits;
+    memcpy(&bits, &x, 4);
+    long w = 0;
+    if ((bits & 0x7f800000u) == 0x7f800000u) {
+        if (bits & 0x007fffffu) {
+            memcpy(p, "nan", 3);
+            return 3;
+        }
+        if (bits >> 31) p[w++] = '-';
+        memcpy(p + w, "inf", 3);
+        return w + 3;
+    }
+    if (bits >> 31) p[w++] = '-';
+    double a = std::fabs((double)x);
+    if (a < 9.0e13) {  // a * 100 < 2^53
+        double s = a * 100.0;
+        uint64_t q = (uint64_t)s;
+        double cut = s - (double)q;
+        if (cut > 0.5 || (cut == 0.5 && (q & 1))) q++;
+        w += itoa_u((int64_t)(q / 100), p + w);
+        p[w++] = '.';
+        p[w++] = (char)('0' + q % 100 / 10);
+        p[w++] = (char)('0' + q % 10);
+        return w;
+    }
+    // 2^24 or more: a whole number, under 2^128
+    unsigned __int128 v = (unsigned __int128)a;
+    char tmp[40];
+    int n = 0;
+    while (v > 0) { tmp[n++] = (char)('0' + (int)(v % 10)); v /= 10; }
+    while (n > 0) p[w++] = tmp[--n];
+    memcpy(p + w, ".00", 3);
+    return w + 3;
+}
+
+// Fixed-point matrix rows "prefix\tlabel[r]\t%.2f...\n" (indexcov's ROC
+// block: the chromosome, the row's cov label as the caller printed it,
+// a cell a sample). vals is (n_cols, n_rows) and is read where it lies:
+// cell (c, r) is vals[c * col_stride + r * row_stride], strides in
+// floats, of either sign. Row r's label is labels[label_off[r] ..
+// label_off[r + 1]). Returns bytes written, or -1, with nothing
+// written, where out_cap is under the block's worst case (every cell
+// FIXED2_CELL_MAX wide): never a truncated line.
+long format_fixed2_rows(const char* prefix, long prefix_len,
+                        const char* labels, const int32_t* label_off,
+                        const float* vals, long col_stride,
+                        long row_stride, long n_rows, long n_cols,
+                        char* out, long out_cap) {
+    if (n_rows * (prefix_len + 2 + n_cols * (FIXED2_CELL_MAX + 1))
+            + label_off[n_rows] > out_cap)
+        return -1;
+    long w = 0;
+    for (long r = 0; r < n_rows; r++) {
+        memcpy(out + w, prefix, prefix_len);
+        w += prefix_len;
+        out[w++] = '\t';
+        long ll = label_off[r + 1] - label_off[r];
+        memcpy(out + w, labels + label_off[r], ll);
+        w += ll;
+        const float* v = vals + r * row_stride;
+        for (long c = 0; c < n_cols; c++) {
+            out[w++] = '\t';
+            w += fmt_fixed2(v[c * col_stride], out + w);
+        }
+        out[w++] = '\n';
+    }
+    return w;
+}
+
 // %.{prec}g-compatible fast formatter for the fixed-notation regime
 // (1e-4 <= v < 10^prec): round to prec significant decimal digits,
 // place the point, strip trailing fraction zeros. Returns chars

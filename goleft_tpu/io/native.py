@@ -120,6 +120,7 @@ def _register_restypes(lib) -> None:
         lib.bai_tile_sizes.restype = ctypes.c_long
         lib.format_xy_json.restype = ctypes.c_long
         lib.format_float32_rows.restype = ctypes.c_long
+        lib.format_fixed2_rows.restype = ctypes.c_long
 
 
 def _as_u8(data) -> np.ndarray:
@@ -640,6 +641,55 @@ def format_float32_rows(out: np.ndarray, chrom: str, starts: np.ndarray,
     if next_row.value == row0 < n_rows:
         raise ValueError("format_float32_rows: scratch holds no row")
     return w, next_row.value
+
+
+# the widest "%.2f" of a float32 (csrc FIXED2_CELL_MAX)
+FIXED2_CELL_MAX = 43
+
+
+def fixed2_rows_scratch_bytes(prefix: str, labels, n_cols: int) -> int:
+    """Size of the scratch ``format_fixed2_rows`` asks for: the block's
+    worst case, every cell ``FIXED2_CELL_MAX`` wide."""
+    return (len(labels) * (len(prefix.encode()) + 2
+                           + n_cols * (FIXED2_CELL_MAX + 1))
+            + sum(len(s.encode()) for s in labels))
+
+
+def format_fixed2_rows(out: np.ndarray, prefix: str, labels,
+                       vals: np.ndarray) -> int | None:
+    """Fixed-point rows 'prefix\tlabels[r]\t%.2f...\n' into the uint8
+    scratch ``out``: bytes written; None without native. vals is float32
+    (n_cols, len(labels)), read where it lies whatever its strides, and
+    the text is np.char.mod("%.2f", vals.T)'s byte for byte (a NaN of
+    either sign is "nan"). It is formatted without printf, so there is
+    no numeric locale to pin. A scratch under
+    ``fixed2_rows_scratch_bytes`` raises, with nothing written."""
+    lib = get_lib()
+    if lib is None:
+        return None
+    if vals.dtype != np.float32 or vals.ndim != 2:
+        # a float64 cast to float32 would round twice
+        raise TypeError("format_fixed2_rows: vals must be 2-d float32")
+    n_cols, n_rows = vals.shape
+    if n_rows != len(labels):
+        raise ValueError("format_fixed2_rows: a label a row")
+    if vals.strides[0] % 4 or vals.strides[1] % 4:
+        vals = np.ascontiguousarray(vals)
+    pb = prefix.encode()
+    lb = [s.encode() for s in labels]
+    label_off = np.zeros(n_rows + 1, dtype=np.int32)
+    np.cumsum([len(b) for b in lb], out=label_off[1:])
+    w = lib.format_fixed2_rows(
+        ctypes.c_char_p(pb), ctypes.c_long(len(pb)),
+        ctypes.c_char_p(b"".join(lb)), _ptr(label_off, ctypes.c_int32),
+        _ptr(vals, ctypes.c_float), ctypes.c_long(vals.strides[0] // 4),
+        ctypes.c_long(vals.strides[1] // 4), ctypes.c_long(n_rows),
+        ctypes.c_long(n_cols), _ptr(out, ctypes.c_char),
+        ctypes.c_long(len(out)),
+    )
+    if w < 0:
+        raise ValueError("format_fixed2_rows: scratch too small")
+    return w
 
 
 def format_xy_json(xs: np.ndarray, ys: np.ndarray, xprec: int = 10,

@@ -232,3 +232,185 @@ def test_bai_scan_matches_python_parse(tmp_path, monkeypatch):
         assert rf.mapped == rs.mapped
         assert rf.unmapped == rs.unmapped
         assert rf.bins == rs.bins  # triggers the lazy parse
+
+
+# ---- format_fixed2_rows: indexcov's ROC text, np.char.mod's byte for byte
+
+def fixed2_want(prefix, labels, vals) -> bytes:
+    cells = np.char.mod("%.2f", vals.T)
+    return "".join(
+        prefix + "\t" + labels[r] + "\t" + "\t".join(cells[r]) + "\n"
+        for r in range(len(labels))).encode()
+
+
+def fixed2_got(prefix, labels, vals, short_by: int = 0) -> bytes:
+    out = np.empty(native.fixed2_rows_scratch_bytes(
+        prefix, labels, vals.shape[0]) - short_by, dtype=np.uint8)
+    return out[:native.format_fixed2_rows(out, prefix, labels,
+                                          vals)].tobytes()
+
+
+def as_rows(values, n_rows: int = 7) -> np.ndarray:
+    """float32 values as an (n_cols, n_rows) matrix, zeros to fill."""
+    v = np.asarray(values, dtype=np.float32).ravel()
+    m = np.zeros(-(-len(v) // n_rows) * n_rows, dtype=np.float32)
+    m[:len(v)] = v
+    return m.reshape(-1, n_rows)
+
+
+def quotients(n: int) -> np.ndarray:
+    # what unpack_chrom_qc divides: float32 counts over a float32 total
+    return np.arange(n + 1, dtype=np.float32) / np.float32(n)
+
+
+def tie_neighbours() -> np.ndarray:
+    """The float32 next to every x.xx5 in [0, 2] and two steps either
+    side of it: 0.125, 0.375, ... are ties on the binary value itself
+    (to even: 0.12, 0.38), the others lie just over or under theirs."""
+    near = np.float32((np.arange(200) + 0.5) / 100)
+    out = [near]
+    lo = hi = near
+    for _ in range(2):
+        lo = np.nextafter(lo, np.float32(-1))
+        hi = np.nextafter(hi, np.float32(3))
+        out += [lo, hi]
+    return np.concatenate(out)
+
+
+def random_bit_patterns() -> np.ndarray:
+    bits = np.random.default_rng(35).integers(
+        0, 2**32, size=70_000, dtype=np.uint64).astype(np.uint32)
+    return bits.view(np.float32)
+
+
+NEG_NAN = np.array([0xFFC00000], dtype=np.uint32).view(np.float32)[0]
+FIXED2_CASES = {
+    "k-over-1": lambda: as_rows(quotients(1)),
+    "k-over-7": lambda: as_rows(quotients(7)),
+    "k-over-2048": lambda: as_rows(quotients(2048)),
+    "k-over-15195": lambda: as_rows(quotients(15195)),
+    "tie-neighbours": lambda: as_rows(tie_neighbours()),
+    # 0/0 on x86 is a NaN with the sign bit set: Python prints "nan",
+    # glibc's printf "-nan"
+    "nan-of-both-signs-and-inf": lambda: as_rows(
+        [np.nan, NEG_NAN, np.float32(0) / np.zeros(1, np.float32)[0],
+         np.inf, -np.inf]),
+    "signed-zero-and-round-up": lambda: as_rows(
+        [-0.0, 0.0, -0.001, -0.004999, 0.995, 0.999, 0.9949, 9.995,
+         9.999, 99.995, 1e-45, -1e-45]),
+    "ten-and-over-and-negative": lambda: as_rows(
+        [10.0, 12.345, -12.345, 1234567.0, 1e7, -1e7, 16777216.0,
+         16777217.0, 8.9e13, 9.1e13, -9.1e13, 1e20, 3.4028235e38,
+         -3.4028235e38, 2.0**100]),
+    "random-bit-patterns": lambda: as_rows(random_bit_patterns(), 70),
+    "transposed": lambda: as_rows(quotients(699), 70).T[:, :7],
+    "column-sliced": lambda: as_rows(quotients(699), 70)[::3, 5:66:2],
+    "reversed": lambda: as_rows(quotients(699), 70)[::-1, ::-1],
+    "one-cell": lambda: np.full((1, 1), 0.5, dtype=np.float32),
+}
+
+
+@needs_native
+@pytest.mark.native_io
+@pytest.mark.parametrize("case", sorted(FIXED2_CASES))
+def test_format_fixed2_rows_is_np_char_mod_byte_for_byte(case):
+    with np.errstate(invalid="ignore", over="ignore"):
+        vals = FIXED2_CASES[case]()
+    assert vals.dtype == np.float32
+    labels = ["%.2f" % (r / 46.7) for r in range(vals.shape[1])]
+    want = fixed2_want("chr17_alt", labels, vals)
+    assert fixed2_got("chr17_alt", labels, vals) == want
+    if case.startswith("nan"):
+        assert want.count(b"\tnan") == 3 and b"-nan" not in want
+
+
+@needs_native
+@pytest.mark.native_io
+def test_format_fixed2_rows_scratch_one_byte_short_is_an_error():
+    vals = as_rows(quotients(699), 70)
+    labels = [str(r) for r in range(70)]
+    assert fixed2_got("c", labels, vals) == fixed2_want("c", labels, vals)
+    out = np.zeros(native.fixed2_rows_scratch_bytes("c", labels, 10) - 1,
+                   dtype=np.uint8)
+    with pytest.raises(ValueError, match="scratch too small"):
+        native.format_fixed2_rows(out, "c", labels, vals)
+    assert not out.any()  # not a line of it, let alone a truncated one
+
+
+@needs_native
+@pytest.mark.native_io
+def test_format_fixed2_rows_takes_float32_only():
+    # a float64 cast to float32 and then printed would round twice
+    with pytest.raises(TypeError, match="float32"):
+        native.format_fixed2_rows(np.empty(1 << 16, np.uint8), "c", ["0"],
+                                  np.zeros((3, 1), np.float64))
+    with pytest.raises(ValueError, match="a label a row"):
+        native.format_fixed2_rows(np.empty(1 << 16, np.uint8), "c", ["0"],
+                                  np.zeros((3, 2), np.float32))
+
+
+def roc_block(samples: int = 9) -> np.ndarray:
+    """A chromosome's ROC as unpack_chrom_qc makes it, one sample with
+    no tile (0/0)."""
+    from goleft_tpu.ops import indexcov_ops as ops
+
+    rng = np.random.default_rng(3)
+    top = np.sort(rng.integers(0, 2048, (samples, ops.SLOTS)),
+                  axis=1)[:, ::-1].astype(np.float32)
+    top[4] = 0
+    with np.errstate(invalid="ignore"):
+        return top / top[:, :1]
+
+
+ROC_NATIVE = "indexcov.roc_native_blocks_total"
+
+
+@pytest.mark.native_io
+@pytest.mark.parametrize("path", ["native", "fallback", "float64"])
+def test_write_roc_rows_bytes_and_counter(monkeypatch, path):
+    """The same bytes with and without the library, and the counter
+    says which wrote them: +1 a block on the native path, +0 on
+    np.char.mod's."""
+    import io
+
+    from goleft_tpu import obs
+    from goleft_tpu.commands import indexcov as ic
+    from goleft_tpu.ops import indexcov_ops as ops
+
+    rocs = roc_block()
+    if path == "native" and native.get_lib() is None:
+        pytest.skip("no native library here")
+    if path == "fallback":
+        monkeypatch.setattr(native, "get_lib", lambda: None)
+    if path == "float64":  # exact in float64: the text does not change
+        rocs = rocs.astype(np.float64)
+    cov = ["%.2f" % (i / (ops.SLOTS * ops.SLOTS_MID))
+           for i in range(ops.SLOTS)]
+    want = fixed2_want("chrX", cov, rocs).decode()
+    assert "\tnan" in want and "-nan" not in want
+    counter = obs.get_registry().counter(ROC_NATIVE)
+    before = counter.value
+    fh = io.StringIO()
+    ic.write_roc_rows(fh, "chrX", rocs)
+    ic.write_roc_rows(fh, "chrX", rocs)
+    assert fh.getvalue() == want * 2
+    assert counter.value - before == (2 if path == "native" else 0)
+
+
+@needs_native
+@pytest.mark.native_io
+def test_write_roc_rows_keeps_its_scratch_from_block_to_block():
+    import io
+
+    from goleft_tpu.commands import indexcov as ic
+
+    rocs = roc_block()
+    ic.write_roc_rows(io.StringIO(), "chr1", rocs)
+    kept = ic._roc_scratch.out
+    for name in ("chr2", "chr10", "chrUn_KI270302v1"):
+        ic.write_roc_rows(io.StringIO(), name, rocs)
+    assert ic._roc_scratch.out is kept
+    wide = io.StringIO()  # a wider cohort outgrows it, once
+    ic.write_roc_rows(wide, "chr1", np.tile(rocs, (200, 1)))
+    assert ic._roc_scratch.out is not kept
+    assert len(wide.getvalue().splitlines()[0].split("\t")) == 2 + 1800
