@@ -7,13 +7,35 @@ Set-up runs ``warmup_jobs`` whole jobs (their outputs are compared like
 any other's); the window then starts jobs until ``--seconds`` have
 passed and ends with the job that is running then. With ``--trace 1`` a
 ``jax.profiler`` trace wraps job ``traced_job`` of the window.
+
+The rate, ``gbases_per_s``, is all completed jobs' bases over all the
+window's seconds. Each job carries host notes for ``run.py``'s job lines:
+what ``getrusage`` says the process used over it, the CPU seconds of the
+thread that ran it and the machine's load at its end.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import resource
 import time
+
+RUSAGE = ("ru_utime", "ru_stime", "ru_minflt", "ru_majflt", "ru_nvcsw",
+          "ru_nivcsw")
+
+
+def rusage() -> list:
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return [getattr(ru, f) for f in RUSAGE]
+
+
+def loadavg1() -> float | None:
+    try:
+        with open("/proc/loadavg") as fh:
+            return float(fh.read().split()[0])
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 def run_job(ctx, index: int) -> dict:
@@ -24,6 +46,7 @@ def run_job(ctx, index: int) -> dict:
     outputs = ctx.job_outputs(prefix)
     stdout = next((p for kind, p in outputs.items()
                    if ctx.output_files[kind] == "stdout"), None)
+    ru0, cpu0 = rusage(), time.thread_time()
     t0 = time.perf_counter()
     try:
         if stdout:
@@ -33,8 +56,16 @@ def run_job(ctx, index: int) -> dict:
             rc = cli.main(argv)
     except SystemExit as e:  # the commands exit this way on a failed shard
         rc = e.code if isinstance(e.code, int) else 1
-    return {"index": index, "t0": t0, "t1": time.perf_counter(),
-            "rc": int(rc or 0), "outputs": outputs}
+    t1 = time.perf_counter()
+    # host notes, read at the job's two ends and outside its seconds: what
+    # the process used over the job, and the machine's load at its end
+    host = {f: b - a for f, a, b in zip(RUSAGE, ru0, rusage())}
+    # this thread's own CPU seconds: the one reading that told the cell's
+    # two speeds apart where the kernel counts no faults (PERF.md, PR 36)
+    host["main_thread_cpu_s"] = time.thread_time() - cpu0
+    host["loadavg1"] = loadavg1()
+    return {"index": index, "t0": t0, "t1": t1, "rc": int(rc or 0),
+            "outputs": outputs, "host": host}
 
 
 def run(ctx) -> dict:

@@ -16,7 +16,8 @@ reads of them; the system under test is driven in-process through
 ``goleft_tpu.cli.main``.
 
 The last line of stdout is the result object; earlier lines are JSON
-notes (fixture, machine, each job's seconds, compile counters). A run
+notes (fixture, machine, compile counters, and for each job its seconds,
+what the process used over it and its seconds by span name). A run
 that finds no TPU, or fewer chips than the cell asks for, exits 3 with
 no result.
 """
@@ -133,8 +134,10 @@ class Ctx:
             jax.profiler.stop_trace()
 
 
-def make_fixture(config_path: str, seed: int, out: str) -> tuple[dict, float]:
-    """Run the JAX-free child; (meta, the child's wall seconds)."""
+def make_fixture(config_path: str, seed: int,
+                 out: str) -> tuple[dict, float, str]:
+    """Run the JAX-free child; (meta, the child's wall seconds, whether it
+    ``built`` the fixture or ``reused`` one)."""
     t0 = time.perf_counter()
     proc = subprocess.run(
         [sys.executable, os.path.join(BENCH, "fixtures.py"),
@@ -143,8 +146,22 @@ def make_fixture(config_path: str, seed: int, out: str) -> tuple[dict, float]:
     if proc.returncode:
         raise SystemExit(f"benchmark: fixtures.py exited {proc.returncode}")
     seconds = time.perf_counter() - t0
-    note(**json.loads(proc.stdout.splitlines()[-1]), child_seconds=seconds)
-    return load(f"{out}/meta.json"), seconds
+    said = json.loads(proc.stdout.splitlines()[-1])
+    note(**said, child_seconds=seconds)
+    return load(f"{out}/meta.json"), seconds, said["fixture"]
+
+
+def drop_other_seeds(fixture_dir: str, config_name: str) -> None:
+    """One seed's fixture a configuration: a run on a new seed removes
+    what the configuration's earlier seeds left (3.5 GB a seed of
+    ``indexcov500``), whole or half-made. Runs of one configuration in one
+    checkout are serial, as the check makes them: a second run beside
+    this one would lose the fixture it is making or reading."""
+    parent, keep = os.path.split(fixture_dir)
+    other = re.compile(rf"{re.escape(config_name)}-\d+(\.tmp\d+)?$")
+    for d in os.listdir(parent):
+        if d != keep and other.match(d):
+            shutil.rmtree(os.path.join(parent, d), ignore_errors=True)
 
 
 # The program builds its decoder with ``g++ -march=native`` on first use. On
@@ -201,6 +218,16 @@ def native_library(bam: str | None):
     return lib, built
 
 
+def machine_id() -> str:
+    """What tells one machine (one lease) from the next: the kernel's boot
+    id, else the host's name."""
+    try:
+        with open("/proc/sys/kernel/random/boot_id") as fh:
+            return fh.read().strip()
+    except OSError:
+        return os.uname().nodename
+
+
 def peak_rss_bytes() -> int:
     """This process's resident high-water mark: ``VmHWM`` where the kernel
     gives it, else ``ru_maxrss`` (the chip machine's gVisor kernel has no
@@ -221,6 +248,16 @@ def program_spans(t0: float, t1: float) -> list[dict]:
     return [{"name": s.name, "category": s.category, "t0": s.t0, "t1": s.t1}
             for s in get_tracer().snapshot()
             if s.t1 is not None and s.t0 >= t0 and s.t1 <= t1]
+
+
+def span_seconds(spans: list[dict], job: dict) -> dict:
+    """One job's seconds by span name (thread-seconds where threads share
+    a name), for the notes: which stage a slow job was slow in."""
+    sums: dict[str, float] = {}
+    for s in spans:
+        if s["t0"] >= job["t0"] and s["t1"] <= job["t1"]:
+            sums[s["name"]] = sums.get(s["name"], 0.0) + s["t1"] - s["t0"]
+    return {k: round(v, 4) for k, v in sorted(sums.items())}
 
 
 def counters() -> dict:
@@ -287,7 +324,9 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT) -> int:
     fixture_dir = os.path.join(work, ".fixtures",
                                f"{cell['config']}-{a.seed}")
     os.makedirs(os.path.dirname(fixture_dir), exist_ok=True)
-    meta, fixture_s = make_fixture(config_path, a.seed, fixture_dir)
+    drop_other_seeds(fixture_dir, cell["config"])
+    meta, fixture_s, fixture_was = make_fixture(config_path, a.seed,
+                                                fixture_dir)
     t_fixture = time.perf_counter()
     probe = meta["native_probe"]
     lib, built = native_library(probe and f"{fixture_dir}/{probe}")
@@ -295,7 +334,12 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT) -> int:
     # library's probe (and build), then the driver's warm-up
     phases = {"backend_s": t_backend - T_START,
               "native_probe_s": time.perf_counter() - t_fixture}
-    note(machine={"cpu_count": os.cpu_count(), "native_built_by": built,
+    note(machine={"cpu_count": os.cpu_count(),
+                  "affinity": len(os.sched_getaffinity(0)),
+                  "id": machine_id(), "fixture": fixture_was,
+                  "fixture_disk_free_bytes":
+                      shutil.disk_usage(fixture_dir).free,
+                  "native_built_by": built,
                   "native_inflate": "libdeflate" if hasattr(
                       lib, "libdeflate_alloc_decompressor") else "zlib",
                   "jax": jax.__version__})
@@ -370,8 +414,11 @@ def measure(a, bench, cell, ctx, driver, devs, fixture_s,
                            "window_closed": rss_peak * 1e-9},
                 "counters_after_warmup": ctx.counters_at_setup_done})
     every_job = got["warmup"] + got["jobs"]
+    spans = program_spans(min((j["t0"] for j in every_job), default=0.0),
+                          max((j["t1"] for j in every_job), default=0.0))
     for j in every_job:
-        note(job=j["index"], seconds=j["t1"] - j["t0"], rc=j["rc"])
+        note(job=j["index"], seconds=j["t1"] - j["t0"], rc=j["rc"],
+             **j.get("host", {}), span_s=span_seconds(spans, j))
 
     numbers = compare.compare_jobs(every_job, ctx.config["outputs"],
                                    ctx.fixture_dir)

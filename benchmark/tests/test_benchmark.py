@@ -109,6 +109,121 @@ def test_a_run_up_to_the_last_line(tiny_root, capfd, workload, kinds, trace):
         assert all(m["value"] > 0 for m in line["metrics"].values())
 
 
+JOB_NOTE = {"job", "seconds", "rc", "ru_utime", "ru_stime", "ru_minflt",
+            "ru_majflt", "ru_nvcsw", "ru_nivcsw", "main_thread_cpu_s",
+            "loadavg1", "span_s"}
+END_TO_END = ["gbases_per_s", "peak_rss_gb", "setup_s"]
+PER_LAYER = [
+    "host_decode_s_per_gbase", "dispatch_s_per_gbase", "write_s_per_gbase",
+    "window_compiles", "kernel_ms_per_shard", "kernel_hbm_roofline",
+    "device_idle", "device_peak_gb", "rss_growth_mb_per_job",
+    "decode_wait_s_per_gbase", "host_stage_s_per_gbase", "h2d_s_per_gbase",
+    "d2h_s_per_gbase", "h2d_mb_per_gbase", "d2h_mb_per_gbase",
+    "ix_index_load_s_per_gbase", "ix_dispatch_s_per_gbase",
+    "ix_pca_s_per_gbase", "ix_write_s_per_gbase", "ix_h2d_mb_per_gbase",
+    "ix_bed_text_mb_per_gbase", "ix_window_compiles",
+    "ix_qc_kernel_ms_per_contig", "ix_pca_kernel_ms_per_job",
+    "ix_kernel_hbm_roofline", "ix_device_idle", "ix_device_peak_gb",
+    "ix_rss_growth_mb_per_job", "ix_write_wait_s_per_gbase"]
+
+
+def test_the_host_notes_ride_on_the_job_lines_and_nowhere_else(
+        tiny_root, capfd):
+    """ISSUE 36, step 1: what the process used over each job and what the
+    machine was are notes; the result object and the manifest's metrics
+    are what they were."""
+    rc = run.main(["--workload", "depth30x.jobs", "--seed", "2147483671",
+                   "--seconds", "1", "--trace", "0"],
+                  require_tpu=False, root=tiny_root)
+    out = capfd.readouterr().out.splitlines()
+    assert rc == 0
+    notes = [json.loads(ln) for ln in out[:-1] if ln.startswith("{")]
+    jobs = [n for n in notes if "job" in n]
+    line = json.loads(out[-1])
+    assert [j["job"] for j in jobs] == [-1, *range(line["attempted"])]
+    for j in jobs:
+        assert set(j) == JOB_NOTE
+        assert j["ru_utime"] > 0 and j["ru_minflt"] >= 0
+        assert 0 < j["main_thread_cpu_s"] <= j["ru_utime"] + j["ru_stime"]
+        # the job's own stage spans, summed by name
+        assert j["span_s"]["host-decode"] > 0
+        assert j["span_s"]["run.depth"] <= j["seconds"]
+    machine, = (n["machine"] for n in notes if "machine" in n)
+    assert machine["fixture"] == "built"
+    assert machine["affinity"] == len(os.sched_getaffinity(0))
+    assert machine["id"] and machine["fixture_disk_free_bytes"] > 0
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    assert list(line["metrics"]) == END_TO_END
+    with open(f"{ROOT}/BENCHMARK.json") as fh:
+        bench = json.load(fh)
+    # what the manifest had it has (a later PR adds, and edits none)
+    assert END_TO_END == [m["name"] for m in bench["end_to_end"]]
+    assert all("workloads" not in m for m in bench["end_to_end"])
+    assert set(PER_LAYER) <= {m["name"] for m in bench["per_layer"]}
+    assert {("depth30x.jobs", 1), ("cohort4x.jobs", 1),
+            ("indexcov500.jobs", 1)} <= {
+        (w["name"], w["chips"]) for w in bench["workloads"]}
+    assert bench["run_seconds"] == 10
+
+
+def test_a_new_seed_leaves_one_fixture_a_configuration_and_no_output(
+        tiny_root, capfd):
+    fixtures_dir = f"{tiny_root}/benchmark/.fixtures"
+    os.makedirs(f"{fixtures_dir}/cohort4x-5")  # another configuration's
+    os.makedirs(f"{fixtures_dir}/depth30x-7.tmp123")  # a build cut short
+    for seed in (2147483672, 2147483673):
+        rc, line, _ = run_cell(tiny_root, capfd, "depth30x.jobs", seed=seed)
+        assert rc == 0 and line["correct"] is True
+        assert sorted(d for d in os.listdir(fixtures_dir)
+                      if d.startswith("depth30x-")) == [f"depth30x-{seed}"]
+        assert os.listdir(f"{tiny_root}/benchmark/.runs") == []
+    assert os.path.isdir(f"{fixtures_dir}/cohort4x-5")
+    os.rmdir(f"{fixtures_dir}/cohort4x-5")
+
+
+class FakeClock:
+    def __init__(self):
+        self.now = 100.0
+
+    def __call__(self):
+        return self.now
+
+
+@pytest.mark.parametrize("job_s,jobs,elapsed", [
+    (3.0, 4, 12.0), (2.5, 4, 10.0), (0.5, 20, 10.0), (11.0, 1, 11.0)])
+def test_the_window_ends_with_a_whole_job_and_counts_all_its_seconds(
+        monkeypatch, job_s, jobs, elapsed):
+    """A fake clock under the driver: ``--seconds`` alone decides how many
+    whole jobs a window holds, and the rate is every completed job's bases
+    over all its seconds."""
+    import types
+
+    from drivers import closed_loop_jobs as drv
+
+    clock = FakeClock()
+
+    def fake_job(ctx, index):
+        t0 = clock.now
+        clock.now += job_s
+        return {"index": index, "t0": t0, "t1": clock.now, "rc": 0,
+                "outputs": {}, "host": {}}
+
+    monkeypatch.setattr(drv, "time", types.SimpleNamespace(perf_counter=clock))
+    monkeypatch.setattr(drv, "run_job", fake_job)
+    ctx = types.SimpleNamespace(
+        mix={"warmup_jobs": 1, "traced_job": 1}, config={},
+        meta={"job_bases": 2e9}, seconds=10.0, trace=False,
+        setup_done=lambda: None)
+    got = drv.run(ctx)
+    assert [j["index"] for j in got["warmup"]] == [-1]
+    assert [j["index"] for j in got["jobs"]] == list(range(jobs))
+    assert got["t_close"] == got["jobs"][-1]["t1"]  # ends with a whole job
+    assert got["t_close"] - got["t_open"] == pytest.approx(elapsed)
+    assert got["end_to_end"] == {
+        "gbases_per_s": pytest.approx(jobs * 2.0 / elapsed)}
+
+
 def alter_a_window_sum(monkeypatch):
     from goleft_tpu.commands import depth
 
