@@ -14,6 +14,7 @@ import sys
 
 import numpy as np
 
+from .. import obs
 from ..models import emdepth as em
 from ..utils.xopen import xopen
 
@@ -65,37 +66,40 @@ def _norm_chunk(chunk: np.ndarray, med, medmed, dtype) -> np.ndarray:
 
 def _batched_em(depths: np.ndarray, med=None, medmed=None,
                 dtype=None, want_cn: bool = True):
-    """Run the EM in fixed-size window chunks: whole-genome matrices
-    (300k windows × 2504 samples ≈ 3GB f32) stream through the device
-    with ONE compile (the final chunk pads with ones and slices off).
-    ``med``/``medmed`` apply the median normalization lazily per chunk
-    (see _norm_chunk); outputs fill preallocated arrays so nothing is
-    double-held, and the (B,S) CN matrix is only produced when the
-    caller writes it (want_cn)."""
+    """Run the EM chunk by chunk: one chunk of all the windows where they
+    fit in EM_CHUNK, unpadded; else EM_CHUNK windows a chunk with the last
+    padded with ones and sliced off, so whole-genome matrices (300k
+    windows × 2504 samples ≈ 3GB f32) stream through the device with ONE
+    compile. ``med``/``medmed`` apply the median normalization lazily per
+    chunk (see _norm_chunk); outputs fill preallocated arrays so nothing
+    is double-held, and the (B,S) CN matrix is only produced when the
+    caller writes it (want_cn).
+
+    Each chunk is a ``device-compute`` span: its ``pack`` and ``h2d``
+    (the next chunk's ride inside it, staged while this one computes),
+    then ``device-wait`` and ``d2h`` of its results."""
     from ..utils.dtypes import preferred_float
 
     import jax
 
     dtype = dtype or (depths.dtype if depths.dtype.kind == "f"
                       else preferred_float())
-    B = len(depths)
-    if B <= EM_CHUNK:
-        c = _norm_chunk(depths, med, medmed, dtype)
-        lam = np.asarray(em.em_depth_batch(c))
-        return lam, (np.asarray(em.cn_batch(lam, c)) if want_cn
-                     else None)
+    B, S = depths.shape
+    if B == 0:  # no chunk: empty results of the shapes a chunk gives
+        return (np.empty((0, em.N_LAMBDA), dtype),
+                np.empty((0, S), np.int32) if want_cn else None)
+    rows = min(B, EM_CHUNK)
 
-    # multi-chip: the window axis is embarrassingly parallel, so chunks
-    # shard across this host's devices and XLA partitions the vmapped
-    # EM as pure SPMD (no collectives). Chunks are always padded to
-    # EM_CHUNK here, so the leading axis divides evenly. LOCAL devices
-    # only, and only in a single-process world: in a multi-host cnv run
-    # process 0 alone reaches the EM (the others returned after the
-    # gather), so a global mesh would address remote devices whose
-    # processes are gone and hang the SPMD program.
+    # multi-chip: the window axis is embarrassingly parallel, so padded
+    # chunks shard across this host's devices and XLA partitions the
+    # vmapped EM as pure SPMD (no collectives); EM_CHUNK rows divide
+    # evenly. LOCAL devices only, and only in a single-process world: in
+    # a multi-host cnv run process 0 alone reaches the EM (the others
+    # returned after the gather), so a global mesh would address remote
+    # devices whose processes are gone and hang the SPMD program.
     sharding = None
     devs = jax.local_devices()
-    if (jax.process_count() == 1 and len(devs) > 1
+    if (rows == EM_CHUNK and jax.process_count() == 1 and len(devs) > 1
             and EM_CHUNK % len(devs) == 0):
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -103,39 +107,38 @@ def _batched_em(depths: np.ndarray, med=None, medmed=None,
                                  PartitionSpec("w", None))
 
     def staged(lo):
-        chunk = _norm_chunk(depths[lo : lo + EM_CHUNK], med, medmed,
-                            dtype)
-        n = len(chunk)
-        if n < EM_CHUNK:
-            pad = np.ones((EM_CHUNK - n, depths.shape[1]), chunk.dtype)
-            chunk = np.concatenate([chunk, pad])
-        # async H2D: the transfer of chunk k+1 rides the link while the
-        # device chews chunk k (device_put returns immediately)
-        if sharding is not None:
-            return jax.device_put(chunk, sharding), n
-        return jax.device_put(chunk), n
+        with obs.span("pack", category="transfer"):
+            chunk = _norm_chunk(depths[lo : lo + rows], med, medmed,
+                                dtype)
+            n = len(chunk)
+            if n < rows:
+                pad = np.ones((rows - n, S), chunk.dtype)
+                chunk = np.concatenate([chunk, pad])
+        return obs.h2d((chunk,), sharding)[0], n
 
     lams = cns = None
-    offsets = list(range(0, B, EM_CHUNK))
-    pending = staged(offsets[0])
-    for ki, lo in enumerate(offsets):
-        dev, n = pending
-        # dispatch chunk k's device work FIRST (async), then do chunk
-        # k+1's host normalization + H2D while the device computes —
-        # both the host prep and the transfer hide behind compute
-        lam_dev = em.em_depth_batch(dev)
-        cn_dev = em.cn_batch(lam_dev, dev) if want_cn else None
-        if ki + 1 < len(offsets):
-            pending = staged(offsets[ki + 1])
-        lam = np.asarray(lam_dev)
+    offsets = range(0, B, rows)
+    pending = None
+    for lo in offsets:
+        with obs.span("device-compute", category="stage"):
+            dev, n = pending if pending else staged(lo)
+            # dispatch chunk k's device work FIRST (async), then do chunk
+            # k+1's host normalization + H2D while the device computes
+            lam_dev = em.em_depth_batch(dev)
+            out = (lam_dev, em.cn_batch(lam_dev, dev)) if want_cn else (
+                lam_dev,)
+            pending = staged(lo + rows) if lo + rows < B else None
+            got = obs.fetch(*out)
         if lams is None:
-            lams = np.empty((B,) + lam.shape[1:], lam.dtype)
-        lams[lo : lo + n] = lam[:n]
+            lams = np.empty((B,) + got[0].shape[1:], got[0].dtype)
+            if want_cn:
+                cns = np.empty((B,) + got[1].shape[1:], got[1].dtype)
+        lams[lo : lo + n] = got[0][:n]
         if want_cn:
-            cn = np.asarray(cn_dev)
-            if cns is None:
-                cns = np.empty((B,) + cn.shape[1:], cn.dtype)
-            cns[lo : lo + n] = cn[:n]
+            cns[lo : lo + n] = got[1][:n]
+    reg = obs.get_registry()
+    reg.counter("emdepth.windows_total").inc(B)
+    reg.counter("emdepth.chunks_total").inc(len(offsets))
     return lams, cns
 
 
@@ -145,7 +148,9 @@ def run_emdepth(matrix_path: str, out=None, normalize: bool = True,
                 mops_out: str | None = None,
                 gain_out: str | None = None,
                 candidates_out: str | None = None):
-    return call_cnvs(*read_matrix(matrix_path), out=out,
+    with obs.span("host-decode", category="stage"):
+        matrix = read_matrix(matrix_path)
+    return call_cnvs(*matrix, out=out,
                      normalize=normalize, matrix_out=matrix_out,
                      vcf_out=vcf_out, mops_out=mops_out,
                      gain_out=gain_out, candidates_out=candidates_out)
@@ -228,11 +233,12 @@ def call_cnvs(chroms, starts, ends, depths, samples, out=None,
         # Column-at-a-time so integer matrices never convert wholesale
         # to f64 (np.median would copy the full matrix); normalization
         # itself is applied lazily per EM chunk (_norm_chunk).
-        med = np.empty(depths.shape[1], dtype=np.float64)
-        for j in range(depths.shape[1]):
-            med[j] = np.median(depths[:, j])
-        med[med == 0] = 1.0
-        medmed = float(np.median(med))
+        with obs.span("normalize", category="stage"):
+            med = np.empty(depths.shape[1], dtype=np.float64)
+            for j in range(depths.shape[1]):
+                med[j] = np.median(depths[:, j])
+            med[med == 0] = 1.0
+            medmed = float(np.median(med))
 
     if mops_out or gain_out:
         _mops_outputs(chroms, starts, ends, depths, samples, med,
@@ -240,15 +246,14 @@ def call_cnvs(chroms, starts, ends, depths, samples, out=None,
     lambdas, cns = _batched_em(depths, med, medmed, dt,
                                want_cn=matrix_out is not None)
     if matrix_out:
-        with open(matrix_out, "w") as mf:
+        with obs.span("write-output", category="stage"), \
+                open(matrix_out, "w") as mf:
             mf.write("#chrom\tstart\tend\t" + "\t".join(samples) + "\n")
             for b in range(len(cns)):
                 mf.write(
                     f"{chroms[b]}\t{starts[b]}\t{ends[b]}\t"
                     + "\t".join(str(int(c)) for c in cns[b]) + "\n"
                 )
-    out.write("#chrom\tstart\tend\tsample\tCN\tlog2FC\n")
-    cache = em.Cache()
     results = []
 
     def emit(cnvs, chrom):
@@ -264,21 +269,26 @@ def call_cnvs(chroms, starts, ends, depths, samples, out=None,
     # and must not re-cast the med vector each iteration
     med_dt = med.astype(dt) if med is not None else None
     mm = np.dtype(dt).type(medmed) if med is not None else None
-    cur = None
-    for b in range(len(depths)):
-        if chroms[b] != cur:
-            emit(cache.clear(None), cur)
-            cache = em.Cache()
-            cur = chroms[b]
-        row = depths[b].astype(dt)  # always a fresh copy
-        if med_dt is not None:
-            np.divide(row, med_dt, out=row)
-            np.multiply(row, mm, out=row)
-        e = em.EMD(lambdas[b], row, int(starts[b]), int(ends[b]))
-        emit(cache.add(e), cur)
-    emit(cache.clear(None), cur)
-    for chrom, s, e, sample, cn, fc in results:
-        out.write(f"{chrom}\t{s}\t{e}\t{sample}\t{cn}\t{fc:.3f}\n")
+    with obs.span("merge", category="stage"):
+        cache = em.Cache()
+        cur = None
+        for b in range(len(depths)):
+            if chroms[b] != cur:
+                emit(cache.clear(None), cur)
+                cache = em.Cache()
+                cur = chroms[b]
+            row = depths[b].astype(dt)  # always a fresh copy
+            if med_dt is not None:
+                np.divide(row, med_dt, out=row)
+                np.multiply(row, mm, out=row)
+            e = em.EMD(lambdas[b], row, int(starts[b]), int(ends[b]))
+            emit(cache.add(e), cur)
+        emit(cache.clear(None), cur)
+    obs.get_registry().counter("emdepth.calls_total").inc(len(results))
+    with obs.span("write-output", category="stage"):
+        out.write("#chrom\tstart\tend\tsample\tCN\tlog2FC\n")
+        for chrom, s, e, sample, cn, fc in results:
+            out.write(f"{chrom}\t{s}\t{e}\t{sample}\t{cn}\t{fc:.3f}\n")
     if vcf_out:
         from ..utils.vcf import write_cnv_vcf
 

@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import get_registry
+
 MAX_CN = 8
 MAX_ITER = 10
 EPS = 0.01
@@ -196,6 +198,9 @@ class EMD:
 
     def cn(self) -> np.ndarray:
         if self._cn is None:
+            # one device round trip a window: counted, since the caller
+            # may hold this window's CN row already (--matrix-out)
+            get_registry().counter("emdepth.cn_dispatches_total").inc()
             self._cn = np.asarray(
                 cn_batch(self.lam[None], self.depths[None])
             )[0]

@@ -343,7 +343,9 @@ def test_router_retries_on_dead_worker(two_workers, tmp_path):
     traffic retried on the sibling — the client sees one clean 200."""
     f = tmp_path / "a.bam"
     f.write_bytes(b"y" * 80)
-    app = _router(two_workers)
+    # no poll inside the test: a poll between the kill and the request
+    # would eject the victim first, and the request would need no retry
+    app = _router(two_workers, poll_interval_s=60)
     with RouterThread(app) as url:
         client = ServeClient(url, timeout_s=10)
         home = client.depth(str(f))["worker"]

@@ -336,7 +336,9 @@ def test_event_journal_appends_and_filters(tmp_path):
     assert [e["slot"] for e in read_events(path, type="spawn")] \
         == [0, 1]
     cutoff = evs[1]["t"]
-    assert len(read_events(path, since=cutoff)) == 2
+    # "t" is kept to the millisecond: events appended within one share it
+    assert len(read_events(path, since=cutoff)) == sum(
+        e["t"] >= cutoff for e in evs) >= 2
 
 
 def test_event_journal_torn_tail_and_restart_survival(tmp_path):
