@@ -269,6 +269,12 @@ def call_cnvs(chroms, starts, ends, depths, samples, out=None,
     # and must not re-cast the med vector each iteration
     med_dt = med.astype(dt) if med is not None else None
     mm = np.dtype(dt).type(medmed) if med is not None else None
+    # a CN row the merge reads comes from the chunk's CN matrix where
+    # one was made (--matrix-out), else from one dispatch a window; both
+    # counters exist, at 0 or not, once a merge has run
+    reg = obs.get_registry()
+    reg.counter("emdepth.cn_rows_from_chunk_total")
+    reg.counter("emdepth.cn_dispatches_total")
     with obs.span("merge", category="stage"):
         cache = em.Cache()
         cur = None
@@ -281,10 +287,11 @@ def call_cnvs(chroms, starts, ends, depths, samples, out=None,
             if med_dt is not None:
                 np.divide(row, med_dt, out=row)
                 np.multiply(row, mm, out=row)
-            e = em.EMD(lambdas[b], row, int(starts[b]), int(ends[b]))
+            e = em.EMD(lambdas[b], row, int(starts[b]), int(ends[b]),
+                       cns[b] if cns is not None else None)
             emit(cache.add(e), cur)
         emit(cache.clear(None), cur)
-    obs.get_registry().counter("emdepth.calls_total").inc(len(results))
+    reg.counter("emdepth.calls_total").inc(len(results))
     with obs.span("write-output", category="stage"):
         out.write("#chrom\tstart\tend\tsample\tCN\tlog2FC\n")
         for chrom, s, e, sample, cn, fc in results:

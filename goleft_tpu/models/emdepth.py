@@ -179,12 +179,17 @@ def log2fc_batch(lambdas: jax.Array, depths: jax.Array) -> jax.Array:
 
 @dataclass
 class EMD:
-    """One window's EM result (mirrors the reference EMD struct)."""
+    """One window's EM result (mirrors the reference EMD struct).
+
+    ``chunk_cn`` is this window's row of the CN matrix its EM chunk
+    already computed (``cn_batch`` over the same lambdas and depths),
+    where the caller has one; without it ``cn()`` dispatches the row."""
 
     lam: np.ndarray  # (9,)
     depths: np.ndarray  # (S,)
     start: int
     end: int
+    chunk_cn: np.ndarray | None = None
     _l2: np.ndarray | None = None
     _cn: np.ndarray | None = None
 
@@ -198,32 +203,30 @@ class EMD:
 
     def cn(self) -> np.ndarray:
         if self._cn is None:
-            # one device round trip a window: counted, since the caller
-            # may hold this window's CN row already (--matrix-out)
-            get_registry().counter("emdepth.cn_dispatches_total").inc()
-            self._cn = np.asarray(
-                cn_batch(self.lam[None], self.depths[None])
-            )[0]
+            reg = get_registry()
+            if self.chunk_cn is not None:
+                reg.counter("emdepth.cn_rows_from_chunk_total").inc()
+                self._cn = self.chunk_cn
+            else:
+                # one device round trip a window
+                reg.counter("emdepth.cn_dispatches_total").inc()
+                self._cn = np.asarray(
+                    cn_batch(self.lam[None], self.depths[None])
+                )[0]
         return self._cn
 
     def same(self, other: "EMD") -> tuple[list[int], list[int], float]:
         """(non-CN2-in-both samples, changed samples, share unchanged)
-        (emdepth.go:227-247)."""
+        (emdepth.go:227-247). NaN fails every comparison, so it falls to
+        changed, as in the reference's scalar tests."""
         ee = self.log2fc()
         oo = other.log2fc()
-        non2, changed = [], []
-        n_same = 0
-        for i in range(len(ee)):
-            if LOWER < ee[i] < UPPER and LOWER < oo[i] < UPPER:
-                n_same += 1
-            elif (oo[i] >= UPPER and ee[i] >= UPPER) or (
-                oo[i] <= LOWER and ee[i] <= LOWER
-            ):
-                non2.append(i)
-                n_same += 1
-            else:
-                changed.append(i)
-        return non2, changed, n_same / len(self.depths)
+        both2 = (LOWER < ee) & (ee < UPPER) & (LOWER < oo) & (oo < UPPER)
+        non2 = ((oo >= UPPER) & (ee >= UPPER)) | (
+            (oo <= LOWER) & (ee <= LOWER))
+        changed = np.flatnonzero(~(both2 | non2)).tolist()
+        return (np.flatnonzero(non2).tolist(), changed,
+                (len(ee) - len(changed)) / len(self.depths))
 
 
 def em_depth(depths, start: int = 0, end: int = 0) -> EMD:

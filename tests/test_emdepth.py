@@ -65,6 +65,80 @@ def test_same_golden():
     assert changed == [7, 8]
 
 
+def same_by_loop(ee, oo, n):
+    """``EMD.same`` as the reference writes it, a sample at a time
+    (emdepth.go:227-247): the oracle for the array comparisons."""
+    non2, changed = [], []
+    n_same = 0
+    for i in range(len(ee)):
+        if em.LOWER < ee[i] < em.UPPER and em.LOWER < oo[i] < em.UPPER:
+            n_same += 1
+        elif (oo[i] >= em.UPPER and ee[i] >= em.UPPER) or (
+            oo[i] <= em.LOWER and ee[i] <= em.LOWER
+        ):
+            non2.append(i)
+            n_same += 1
+        else:
+            changed.append(i)
+    return non2, changed, n_same / n
+
+
+def every_pair(values):
+    """Two log2FC vectors that hold each ordered pair of ``values``."""
+    ee, oo = np.meshgrid(np.asarray(values, np.float64),
+                         np.asarray(values, np.float64))
+    return [(ee.ravel(), oo.ravel())]
+
+
+def edges():
+    out = []
+    for t in (em.LOWER, em.UPPER):
+        out += [t, np.nextafter(t, -np.inf), np.nextafter(t, np.inf)]
+    return out + [0.0]
+
+
+def random_windows():
+    rng = np.random.default_rng(39)
+    pairs = []
+    for _ in range(200):
+        ee, oo = rng.normal(0, 0.7, size=(2, 2504))
+        for v in (ee, oo):
+            v[rng.random(2504) < 0.01] = np.nan
+            v[rng.random(2504) < 0.01] = np.inf
+            v[rng.random(2504) < 0.01] = -np.inf
+        pairs.append((ee, oo))
+    return pairs
+
+
+SAME_CASES = {
+    "thresholds-and-ulps": lambda: every_pair(edges()),
+    "non-finite": lambda: every_pair([np.nan, np.inf, -np.inf, 0.0, -2.0,
+                                      1.0]),
+    "all-cn2": lambda: [(np.full(2504, 0.1),
+                         np.linspace(-0.79, 0.39, 2504))],
+    "all-aberrant": lambda: [
+        (np.r_[np.full(1252, -1.0), np.full(1252, 0.4)],
+         np.r_[np.full(1252, -np.inf), np.full(1252, 2.0)])],
+    "one-sample": lambda: [(np.array([v]), np.array([w]))
+                           for v in (-1.0, 0.0, 0.5, np.nan)
+                           for w in (-0.8, 0.0, 0.4, -np.inf)],
+    "random-2504": random_windows,
+}
+
+
+@pytest.mark.parametrize("case", list(SAME_CASES))
+def test_same_agrees_with_the_scalar_loop(case):
+    for ee, oo in SAME_CASES[case]():
+        n = len(ee)
+        mine = em.EMD(np.ones(9), np.ones(n), 0, 0, _l2=ee)
+        other = em.EMD(np.ones(9), np.ones(n), 0, 0, _l2=oo)
+        got = mine.same(other)
+        assert got == same_by_loop(ee, oo, n)
+        non2, changed, share = got
+        assert type(share) is float
+        assert all(type(i) is int for i in non2 + changed)
+
+
 def test_cache_merges_cnvs():
     rng = np.random.default_rng(2)
     S = 10

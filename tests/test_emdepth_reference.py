@@ -252,35 +252,104 @@ def known_matrix(path):
                      + "\t".join(map(str, row)) + "\n")
 
 
-@pytest.mark.parametrize("chunk,counter,want", [
-    (None, "emdepth.windows_total", 40),
-    (None, "emdepth.chunks_total", 1),
-    (16, "emdepth.chunks_total", 3),
-    (None, "emdepth.cn_dispatches_total", 4),
-    (None, "emdepth.calls_total", 1),
-    (None, "xla.h2d_bytes_total", 4 * 40 * 10),
-    (16, "xla.h2d_bytes_total", 4 * 48 * 10),
-    (None, "xla.d2h_bytes_total", 4 * 40 * 9 + 4 * 40 * 10),
-], ids=["windows", "chunks", "chunks-padded", "cn-dispatches", "calls",
-        "h2d", "h2d-padded", "d2h"])
-def test_the_counters_move_by_what_the_job_did(tmp_path, monkeypatch,
-                                               float32, chunk, counter,
-                                               want):
+def planted_matrix(path):
+    """64 samples x 300 windows at 30x over two chromosomes, seeded:
+    deletions (CN 0 and 1) and duplications (CN 3 and 4) planted in a
+    few samples, and a near-empty run of ten windows at 1x in 33 samples
+    and 3x in 31, in which every sample opens a CNV at once (no sample
+    lies in the CN2 bin, and lambda 2 falls between the two)."""
+    rng = np.random.default_rng(39)
+    depth = rng.poisson(30, size=(300, 64))
+    for scale in (0.0, 0.5, 0.5, 1.5, 1.5, 2.0):
+        s, w = int(rng.integers(64)), int(rng.integers(0, 280))
+        n = int(rng.integers(4, 20))
+        depth[w:w + n, s] = rng.poisson(30 * scale, size=min(n, 300 - w))
+    depth[150:160, :33], depth[150:160, 33:] = 1, 3
+    with open(path, "w") as fh:
+        fh.write("#chrom\tstart\tend\t"
+                 + "\t".join(f"s{i}" for i in range(64)) + "\n")
+        for w, row in enumerate(depth):
+            chrom, start = ("chr1", w * 1000) if w < 200 else (
+                "chr2", (w - 200) * 1000)
+            fh.write(f"{chrom}\t{start}\t{start + 1000}\t"
+                     + "\t".join(map(str, row)) + "\n")
+
+
+def run_cli(argv):
+    """``cli.main(argv)`` with stdout caught: (stdout, counter growth)."""
     from goleft_tpu import cli, obs
+
+    before = obs.get_registry().counters()
+    with pytest.MonkeyPatch.context() as mp:
+        out = io.StringIO()
+        mp.setattr(sys, "stdout", out)
+        assert not cli.main(argv)
+    after = obs.get_registry().counters()
+    return out.getvalue(), {k: after[k] - before.get(k, 0) for k in after}
+
+
+@pytest.mark.parametrize("make", [known_matrix, planted_matrix],
+                         ids=["known", "planted"])
+def test_the_calls_are_the_same_with_and_without_the_cn_matrix(
+        tmp_path, float32, make):
+    make(tmp_path / "m.tsv")
+    got = {}
+    for with_cn in (False, True):
+        d = tmp_path / str(with_cn)
+        d.mkdir()
+        argv = ["emdepth", "--vcf", str(d / "c.vcf"),
+                "--candidates-out", str(d / "c.tsv")]
+        if with_cn:
+            argv += ["--matrix-out", str(d / "cn.tsv")]
+        stdout, grew = run_cli(argv + [str(tmp_path / "m.tsv")])
+        got[with_cn] = (stdout, (d / "c.vcf").read_bytes(),
+                        (d / "c.tsv").read_bytes(), grew)
+    calls = got[False][0].splitlines()[1:]
+    assert len(calls) >= 1
+    for i in range(3):
+        assert got[True][i] == got[False][i]
+    bare, with_cn = got[False][3], got[True][3]
+    read = bare["emdepth.cn_dispatches_total"]
+    assert read > 0 and bare["emdepth.cn_rows_from_chunk_total"] == 0
+    assert with_cn["emdepth.cn_rows_from_chunk_total"] == read
+    assert with_cn["emdepth.cn_dispatches_total"] == 0
+    if make is planted_matrix:  # the near-empty run opened every sample
+        run = {c.split("\t")[3] for c in calls
+               if c.startswith("chr1\t") and int(c.split("\t")[1]) <= 159000
+               and int(c.split("\t")[2]) >= 151000}
+        assert len(run) == 64
+
+
+@pytest.mark.parametrize("chunk,matrix_out,counter,want", [
+    (None, True, "emdepth.windows_total", 40),
+    (None, True, "emdepth.chunks_total", 1),
+    (16, True, "emdepth.chunks_total", 3),
+    (None, True, "emdepth.cn_dispatches_total", 0),
+    (None, True, "emdepth.cn_rows_from_chunk_total", 4),
+    (None, False, "emdepth.cn_dispatches_total", 4),
+    (None, False, "emdepth.cn_rows_from_chunk_total", 0),
+    (None, True, "emdepth.calls_total", 1),
+    (None, True, "xla.h2d_bytes_total", 4 * 40 * 10),
+    (16, True, "xla.h2d_bytes_total", 4 * 48 * 10),
+    (None, True, "xla.d2h_bytes_total", 4 * 40 * 9 + 4 * 40 * 10),
+], ids=["windows", "chunks", "chunks-padded", "cn-dispatches",
+        "cn-rows-from-chunk", "cn-dispatches-no-matrix",
+        "cn-rows-from-chunk-no-matrix", "calls", "h2d", "h2d-padded", "d2h"])
+def test_the_counters_move_by_what_the_job_did(tmp_path, monkeypatch,
+                                               float32, chunk, matrix_out,
+                                               counter, want):
     from goleft_tpu.commands import emdepth_cmd
 
     known_matrix(tmp_path / "m.tsv")
     if chunk:
         monkeypatch.setattr(emdepth_cmd, "EM_CHUNK", chunk)
-    out = io.StringIO()
-    monkeypatch.setattr(sys, "stdout", out)
-    before = obs.get_registry().counters()
-    assert not cli.main(["emdepth", "--matrix-out", str(tmp_path / "cn.tsv"),
-                         str(tmp_path / "m.tsv")])
-    grew = obs.get_registry().counters()[counter] - before.get(counter, 0)
-    assert grew == want
+    argv = ["emdepth", str(tmp_path / "m.tsv")]
+    if matrix_out:
+        argv[1:1] = ["--matrix-out", str(tmp_path / "cn.tsv")]
+    out, grew = run_cli(argv)
+    assert grew[counter] == want
     if counter == "emdepth.calls_total":
-        assert out.getvalue().splitlines()[1].split("\t")[:4] == [
+        assert out.splitlines()[1].split("\t")[:4] == [
             "chr1", "6000", "10000", "s3"]
 
 
