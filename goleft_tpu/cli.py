@@ -286,6 +286,9 @@ def main(argv: list[str] | None = None) -> int:
         with obs.trace(f"run.{prog}", kind="cli",
                        argv=" ".join(argv[1:])) as root:
             trace_id = root.trace_id
+            # a reader divides a counter's growth by the runs between
+            # its two readings
+            obs.get_registry().counter("cli.runs_total").inc()
             rc = _run_command(prog, argv[1:])
             root.attrs["exit_code"] = rc
         return rc

@@ -177,15 +177,20 @@ def cohort_matrix_blocks(
     names = []
     bam_paths = []
 
+    # a bare pool does not carry the submitting thread's trace
+    load_ctx = obs.capture()
+
     def load(b):
         # lazy mmap-backed handles: residency scales with the shard
         # being decoded, not sum-of-BAM-sizes
-        h = open_bam_file(b, lazy=True)
-        if getattr(h, "is_cram", False):
-            return h, None, get_short_name(b)
-        bai_p = b + ".bai" if remote.exists(b + ".bai") else \
-            b[:-4] + ".bai"
-        return h, read_bai(bai_p), get_short_name(b)
+        with obs.attach(load_ctx), obs.span("open-inputs",
+                                            category="stage"):
+            h = open_bam_file(b, lazy=True)
+            if getattr(h, "is_cram", False):
+                return h, None, get_short_name(b)
+            bai_p = b + ".bai" if remote.exists(b + ".bai") else \
+                b[:-4] + ".bai"
+            return h, read_bai(bai_p), get_short_name(b)
 
     def _fallback_name(b):
         base = b.rsplit("/", 1)[-1]

@@ -272,10 +272,12 @@ def run_indexcov(
     ctx = obs.capture()  # a bare pool does not carry this thread's trace
 
     def _load(p):
-        # one span an index file, on the thread that reads and scans it.
-        # corrupt/truncated index -> clean CLI error naming the file,
-        # not a traceback (the codecs' contract is typed ValueError)
-        with obs.attach(ctx), timer.stage("host-decode"):
+        # one span an index file, on the thread that reads and scans it;
+        # 500 spans of ~15 ms a cohort, too many to read the CPU clock
+        # on each (obs/tracing.py). Corrupt/truncated index -> clean CLI
+        # error naming the file, not a traceback (the codecs' contract
+        # is typed ValueError)
+        with obs.attach(ctx), timer.stage("host-decode", read_cpu=False):
             try:
                 return SampleIndex(p)
             except ValueError as e:

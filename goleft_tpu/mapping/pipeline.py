@@ -335,7 +335,6 @@ def map_reads(index: MinimizerIndex, records,
     summaries. All device work rides plan Steps at the ``map`` fault
     site.
     """
-    from .. import obs
     from ..obs.compiles import TRACKER
     from ..plan import Executor as PlanExecutor, Step
     from ..resilience.policy import DEFAULT_POLICY
@@ -393,8 +392,7 @@ def map_reads(index: MinimizerIndex, records,
                     trigger="map_seed"):
                 fn = _seed_jit(r_pad, index.k, index.w,
                                index.max_occ, params.band, smax)
-                s, d, rv = obs.dispatch("map_seed", fn, pk, nm, rl,
-                                        *tables)
+                s, d, rv = fn(pk, nm, rl, *tables)
             return (np.asarray(s), np.asarray(d), np.asarray(rv))
 
         key = ("map-seed", index.ref_key, params.key(), r_pad, b)
@@ -458,7 +456,7 @@ def map_reads(index: MinimizerIndex, records,
                                "w_pad": w_pad, "b": b},
                     cache_size_fn=swalign._sw_jit_cache_size,
                     trigger="map_extend"):
-                return obs.dispatch("map_extend", thunk)
+                return thunk()
 
         key = ("map-extend", index.ref_key, params.key(), r_pad,
                w_pad, b)

@@ -37,7 +37,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "Span", "SpanContext", "TRACER", "Tracer",
     "backend_provenance", "configure_logging", "capture", "attach",
-    "device_span", "device_span_attrs", "dispatch", "env_provenance",
+    "device_span", "device_span_attrs", "env_provenance",
     "fetch", "get_logger", "get_registry", "get_tracer", "h2d", "span",
     "trace",
 ]
@@ -67,13 +67,7 @@ def attach(ctx: "SpanContext | None"):
     return TRACER.attach(ctx)
 
 
-# ---- device-event instrumentation ----
-
-def set_device_events(enabled: bool) -> None:
-    """Turn per-dispatch fencing on/off (GOLEFT_TPU_DEVICE_EVENTS=1
-    preseeds it; no command switches it on)."""
-    TRACER.device_events = bool(enabled)
-
+# ---- device spans and the dispatch seam ----
 
 def device_span(name: str, **attrs):
     """A span carrying the backend/platform/device-kind attribute set
@@ -85,8 +79,8 @@ def device_span(name: str, **attrs):
 
 def _under_jit_trace() -> bool:
     """True when called during jax tracing (vmap/jit of a wrapped
-    dispatch): instrumenting there would record compile-time as device
-    time and bake a host callback into the program."""
+    dispatch): the call is then part of the enclosing program's trace,
+    not a dispatch, and the observers stay out of it."""
     try:
         import jax
 
@@ -95,31 +89,12 @@ def _under_jit_trace() -> bool:
         return False
 
 
-def dispatch(name: str, fn, *args, **kwargs):
-    """Run ``fn(*args, **kwargs)`` as an honest device event.
-
-    When device events are off (the default) this is a plain call —
-    async dispatch keeps its pipelining. When on
-    (GOLEFT_TPU_DEVICE_EVENTS=1), the call is wrapped in a span with
-    backend/platform/device-kind attributes and fenced with
-    ``block_until_ready`` so the span's duration is the dispatch's
-    device time, not the microseconds of enqueueing it.
-    """
-    if not TRACER.device_events or _under_jit_trace():
-        return fn(*args, **kwargs)
-    import jax
-
-    with device_span(f"device.{name}", fenced=True):
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-    return out
-
-
 class InstrumentedDispatch:
-    """Transparent proxy over a jitted callable: ``__call__`` routes
-    through :func:`dispatch`; every other attribute (``_cache_size``,
-    ``lower``, …) forwards to the wrapped function, so compile-cache
-    cross-checks and AOT tooling keep working."""
+    """Transparent proxy over a jitted callable: ``__call__`` runs it
+    under the compile and memory observers (serve's ``/debug/compiles``
+    and ``/debug/memory`` read them); every other attribute
+    (``_cache_size``, ``lower``, …) forwards to the wrapped function, so
+    compile-cache cross-checks and AOT tooling keep working."""
 
     def __init__(self, fn, name: str):
         self.__wrapped__ = fn
@@ -142,8 +117,7 @@ class InstrumentedDispatch:
                              cache_size_fn=cache_size,
                              trigger="dispatch"), \
                 MEM_TRACKER.observe(family):
-            return dispatch(self._obs_name, self.__wrapped__,
-                            *args, **kwargs)
+            return self.__wrapped__(*args, **kwargs)
 
     def __getattr__(self, item):
         return getattr(self.__wrapped__, item)

@@ -10,7 +10,9 @@ batch span parents the executors' decode/compute/format stages.
 Design constraints (why this is not a logging framework):
 
   - recording must be cheap enough for the hot paths that already use
-    ``StageTimer`` (one perf_counter pair + one lock-guarded append);
+    ``StageTimer`` (one perf_counter pair + one lock-guarded append; a
+    ``stage`` span adds one ``thread_time`` pair and two counter
+    increments);
   - spans cross threads: the prefetch producers and the serve
     dispatcher record work on behalf of a consumer/request that lives
     on another thread, so the ambient context is thread-local but
@@ -22,6 +24,23 @@ Design constraints (why this is not a logging framework):
   - export is Chrome trace-event JSON (the ``traceEvents`` array
     format) so ``--trace-out`` artifacts load directly in Perfetto /
     chrome://tracing next to the XLA profiler's own dumps.
+
+Busy or waiting: a span of category ``stage`` (:data:`CPU_CATEGORIES`)
+also reads its thread's CPU clock (``time.thread_time``) at open and
+close. The CPU seconds ride as the ``cpu_s`` attribute and add to the
+registry counter ``span.cpu_seconds_total.<span name>``; the span's
+wall seconds add to ``span.wall_seconds_total.<span name>``. A reader
+takes the off-CPU seconds (blocked on a lock, I/O, the device, or not
+scheduled) as the growth of the second less that of the first: both
+counters only grow, and a span whose CPU clock steps past its wall
+clock (a kernel that ticks the thread clock in 10 ms steps) keeps its
+excess in the sum, where the steps average out. ``transfer``, ``output``,
+``wait`` and ``device`` spans and the run roots read no clock; nor does
+a span opened with ``read_cpu=False``, which a call site passes where
+its spans are too many and too short for two system calls each (under
+a user-space kernel such as gVisor a ``thread_time`` is a system call
+of some 6 us). There is no
+switch: the reading is always on.
 
 Stdlib-only; jax never imports here (device attributes are the
 caller's business — see obs/provenance.py). A process that has already
@@ -42,10 +61,19 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
+from .metrics import REGISTRY
+
 # perf_counter gives monotonic durations; the offset maps them onto the
 # epoch so exported timestamps line up across processes (and with the
 # jax profiler's traces, which use epoch-based clocks)
 _EPOCH_OFFSET = time.time() - time.perf_counter()
+
+#: span categories whose spans read their thread's CPU clock
+CPU_CATEGORIES = frozenset({"stage"})
+#: prefixes of the per-span-name counters of those spans' CPU and wall
+#: seconds: the off-CPU seconds are the second's growth less the first's
+CPU_PREFIX = "span.cpu_seconds_total."
+WALL_PREFIX = "span.wall_seconds_total."
 
 
 def _profiler_annotation(name: str):
@@ -114,11 +142,6 @@ class Tracer:
         # tuple read without the lock — empty for every process that
         # never registers one, so the hot path pays one truth test
         self._listeners: tuple = ()
-        # GOLEFT_TPU_DEVICE_EVENTS=1 turns on per-dispatch device
-        # fencing (obs.dispatch): off by default, and under --trace-out
-        # too, so the async dispatch pipelines keep their overlap
-        self.device_events = bool(
-            os.environ.get("GOLEFT_TPU_DEVICE_EVENTS"))
         # when the memory plane arms it (obs.memplane.MemorySampler.
         # start), a zero-arg callable returning current RSS bytes:
         # every span then carries mem_delta_bytes / mem_peak_bytes
@@ -184,15 +207,21 @@ class Tracer:
     # ---- span recording ----
 
     @contextlib.contextmanager
-    def span(self, name: str, category: str = "", **attrs):
+    def span(self, name: str, category: str = "", read_cpu: bool = True,
+             **attrs):
         """Open a child of this thread's innermost open span (or a
-        trace root when the stack is empty)."""
+        trace root when the stack is empty). A span of one of
+        :data:`CPU_CATEGORIES` records its thread's CPU seconds as
+        ``cpu_s`` and counts them and its wall seconds by name, unless
+        ``read_cpu`` is false."""
         th = threading.current_thread()
         parent = self._ctx.stack[-1] if self._ctx.stack else None
         # captured once: close() may disarm the probe mid-span, and a
         # delta needs both readings from the same probe
         probe = self.mem_probe
         rss0 = probe() if probe is not None else 0
+        cpu0 = (time.thread_time()
+                if read_cpu and category in CPU_CATEGORIES else None)
         sp = Span(
             name=name,
             span_id=next(self._ids),
@@ -210,6 +239,11 @@ class Tracer:
                 yield sp
         finally:
             sp.t1 = time.perf_counter()
+            if cpu0 is not None:
+                cpu = time.thread_time() - cpu0
+                sp.attrs["cpu_s"] = cpu
+                REGISTRY.counter(CPU_PREFIX + name).inc(cpu)
+                REGISTRY.counter(WALL_PREFIX + name).inc(sp.t1 - sp.t0)
             if probe is not None:
                 rss1 = probe()
                 # boundary-observed: delta across the span, peak of

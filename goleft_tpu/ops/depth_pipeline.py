@@ -181,16 +181,11 @@ def shard_depth_pipeline_packed(
                           window)
 
 
-# Device-event instrumentation: the module's dispatch boundaries are
-# proxies that (only when device events are on —
-# GOLEFT_TPU_DEVICE_EVENTS=1) wrap each call in a span carrying
-# backend/platform/device-kind attributes and fence it with
-# block_until_ready, so per-dispatch device time is honest instead of
-# enqueue-microseconds. Off (the default), a call is a flag check away
-# from the raw jitted function, async dispatch intact. Jit attributes
-# (_cache_size, lower, …) forward through — the compile observatory
-# and the AOT compile tests read them — and calls made INSIDE a jax
-# trace (the vmapped wrappers in commands/depth.py and
+# The module's dispatch boundaries are proxies that run each call under
+# the compile and memory observers, async dispatch intact. Jit
+# attributes (_cache_size, lower, …) forward through — the compile
+# observatory and the AOT compile tests read them — and calls made
+# INSIDE a jax trace (the vmapped wrappers in commands/depth.py and
 # commands/cohortdepth.py close over these names) pass straight
 # through untouched.
 shard_depth_pipeline = _InstrumentedDispatch(

@@ -375,8 +375,6 @@ def forward_pairs_partial(reads, quals, haps, *,
     reg = get_registry()
     reg.counter("pairhmm.pairs_total").inc(n)
 
-    from .. import obs
-
     pex = PlanExecutor(policy=policy)
     groups = bucket_pairs(enc_reads, enc_haps, bucket)
     for (r_pad, h_pad), idxs in sorted(groups.items()):
@@ -398,9 +396,8 @@ def forward_pairs_partial(reads, quals, haps, *,
                     cache_size_fn=lambda: _FORWARD_JIT._cache_size()
                     if _FORWARD_JIT is not None else 0,
                     trigger="pairhmm_forward"):
-                contribs, shifts = obs.dispatch(
-                    "pairhmm_forward", _forward_bucket, *packed,
-                    trans, rescale=rescale)
+                contribs, shifts = _forward_bucket(
+                    *packed, trans, rescale=rescale)
             return np.asarray(contribs), np.asarray(shifts)
 
         reg.counter("pairhmm.buckets_total").inc()

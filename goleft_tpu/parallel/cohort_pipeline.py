@@ -65,8 +65,8 @@ def build_cohort_step(mesh: Mesh, shard_len: int, window: int,
         }
 
     in_shard = NamedSharding(mesh, P("data", "seq"))
-    # dispatch boundary: span + block_until_ready fence when device
-    # events are on (obs.dispatch), plain jitted call otherwise
+    # dispatch boundary: the jitted call under the compile and memory
+    # observers
     return _InstrumentedDispatch(
         jax.jit(step, in_shardings=(in_shard,) * 3), "cohort_step")
 

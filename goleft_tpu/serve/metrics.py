@@ -12,9 +12,10 @@ while tests constructing :class:`~goleft_tpu.serve.server.ServeApp`
 directly get a private registry and stay isolated.
 
 Stage wall-clocks (decode/compute/format per batch) ride the same
-``utils.profiling.StageTimer`` the CLI pipelines use — now a bounded
-ring (spans_dropped counts evictions; totals/counts are exact
-forever), so a long-lived daemon's per-request state stays bounded.
+``utils.profiling.StageTimer`` the CLI pipelines use: totals and counts,
+exact forever, one pair a stage name. The stages' spans live in the
+process tracer's bounded ring, whose evictions ``stage_spans_dropped``
+reports, so a long-lived daemon's per-request state stays bounded.
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ import threading
 import time
 from collections import deque
 
+from ..obs import get_tracer
 from ..obs.metrics import MetricsRegistry
 from ..utils.profiling import StageTimer
 
@@ -206,7 +208,7 @@ class ServeMetrics:
             "latency_windows": self.registry.histogram_windows(
                 _LATENCY),
             "stage_seconds": self.timer.as_dict(),
-            "stage_spans_dropped": self.timer.spans_dropped,
+            "stage_spans_dropped": get_tracer().spans_dropped,
         }
         if queue_depth is not None:
             out["queue_depth"] = queue_depth

@@ -6,7 +6,8 @@ in Perfetto silently, so the exact normalized shape is committed),
 concurrent cross-thread span recording under the prefetch pool,
 metrics-registry snapshot determinism, the serve daemon's /metrics
 being derived solely from the unified registry (byte-for-byte), the
-bounded StageTimer ring, p99/max percentiles, the run manifest schema,
+StageTimer's totals and counts, p99/max percentiles, each work span's
+CPU seconds and off-CPU counter, the run manifest schema,
 and the CLI's global --trace-out/--metrics-out/--log-level/-v flags.
 """
 
@@ -38,8 +39,7 @@ def _golden_span_script(tracer: Tracer) -> None:
         assert root.trace_id.startswith("cli-")
         with tracer.span("decode", category="stage", shard=0):
             pass
-        with tracer.span("compute", category="device",
-                         platform="cpu", fenced=True):
+        with tracer.span("compute", category="device", platform="cpu"):
             pass
         ctx = tracer.capture()
 
@@ -71,6 +71,8 @@ def _normalize(doc: dict) -> dict:
         if "parent_id" in args:
             args["parent_id"] = span_map[args["parent_id"]]
         args["trace_id"] = "TRACE"
+        if "cpu_s" in args:  # a work span's CPU seconds: there, not equal
+            args["cpu_s"] = "CPU"
         events.append({
             "name": e["name"], "cat": e["cat"], "ph": "X",
             "ts": 0, "dur": 0, "pid": "PID",
@@ -242,7 +244,7 @@ def test_serve_metrics_snapshot_is_registry_derived_byte_for_byte():
         "latency_s": reg.histograms("serve.latency_s."),
         "latency_windows": reg.histogram_windows("serve.latency_s."),
         "stage_seconds": m.timer.as_dict(),
-        "stage_spans_dropped": m.timer.spans_dropped,
+        "stage_spans_dropped": obs.get_tracer().spans_dropped,
         "queue_depth": 2,
         "cache": {"hits": 1},
     }
@@ -264,21 +266,21 @@ def test_serve_app_uses_private_registry_by_default():
         app.close()
 
 
-# ---------------- StageTimer ring + percentiles ----------------
+# ---------------- StageTimer + percentiles ----------------
 
 
-def test_stagetimer_ring_bounds_spans_not_totals():
+def test_stagetimer_keeps_totals_and_counts_not_spans():
     from goleft_tpu.utils.profiling import StageTimer
 
-    tm = StageTimer(max_spans=4)
+    tm = StageTimer()
     for _ in range(10):
         with tm.stage("s"):
             pass
-    assert len(tm.spans) == 4
-    assert tm.spans_dropped == 6
-    assert tm.counts["s"] == 10           # totals/counts unaffected
+    assert tm.counts["s"] == 10
     assert tm.as_dict()["s"]["calls"] == 10
-    assert tm.wall() > 0.0
+    assert tm.totals["s"] > 0.0
+    # the spans live in the tracer's ring alone
+    assert not hasattr(tm, "spans") and not hasattr(tm, "spans_dropped")
 
 
 def test_percentiles_include_p99_and_max():
@@ -293,49 +295,166 @@ def test_percentiles_include_p99_and_max():
     assert percentiles([]) == {"count": 0}
 
 
-# ---------------- device events ----------------
+# ---------------- busy or waiting: a work span's CPU seconds ----------
 
 
-def test_instrumented_dispatch_records_fenced_device_span():
-    from goleft_tpu.ops import depth_pipeline as dp
+def _seconds(name: str) -> tuple[float, float]:
+    """(wall, cpu) seconds the registry holds for spans named ``name``."""
+    got = obs.get_registry().counters()
+    return (got.get(f"span.wall_seconds_total.{name}", 0.0),
+            got.get(f"span.cpu_seconds_total.{name}", 0.0))
 
-    i32 = np.int32
-    seg = np.zeros(64, np.int32)
-    keep = np.zeros(64, bool)
-    args = (seg, seg, keep, i32(0), i32(0), i32(256), i32(2500),
-            i32(4), i32(0))
-    tracer = obs.get_tracer()
-    obs.set_device_events(True)
+
+def _offcpu(name: str) -> float:
+    wall, cpu = _seconds(name)
+    return wall - cpu
+
+
+def _sleep():
+    import time
+
+    time.sleep(0.05)
+
+
+def _spin():
+    import time
+
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < 0.05:
+        pass
+
+
+@pytest.mark.parametrize("body,category", [
+    (_sleep, "stage"),       # asleep: off the CPU
+    (_spin, "stage"),        # busy: on it
+    (_sleep, "wait"),        # a wait reads no clock at all
+    (_spin, "transfer"),     # nor does a transfer
+], ids=["sleep", "busy-loop", "wait-category", "transfer-category"])
+def test_a_span_counts_its_seconds_off_the_cpu(body, category, request):
+    name = f"offcpu-{request.node.callspec.id}"
+    before = _offcpu(name)
+    with obs.span(name, category=category) as sp:
+        body()
+    grew = _offcpu(name) - before
+    if category != "stage":
+        assert "cpu_s" not in sp.attrs
+        counters = obs.get_registry().counters()
+        assert f"span.wall_seconds_total.{name}" not in counters
+        assert f"span.cpu_seconds_total.{name}" not in counters
+        return
+    if body is _sleep:
+        assert grew >= 0.04
+        assert sp.attrs["cpu_s"] < 0.02
+    else:
+        # against the span's own readings, not a fixed limit: preemption
+        # on a loaded host or a coarse thread clock may move either
+        assert sp.attrs["cpu_s"] > sp.duration() / 2
+    # what is not on the CPU of the span's wall seconds is off it, with
+    # nothing clipped
+    assert grew == pytest.approx(sp.duration() - sp.attrs["cpu_s"],
+                                 abs=1e-9)
+
+
+def test_a_stage_opened_not_to_read_the_cpu_counts_nothing():
+    from goleft_tpu.utils.profiling import StageTimer
+
+    tm = StageTimer()
+    with tm.stage("offcpu-not-read", read_cpu=False):
+        pass
+    sp = next(s for s in obs.get_tracer().snapshot()
+              if s.name == "offcpu-not-read")
+    assert sp.category == "stage" and "cpu_s" not in sp.attrs
+    assert _seconds("offcpu-not-read") == (0.0, 0.0)
+    assert tm.counts["offcpu-not-read"] == 1
+
+
+def test_spans_on_two_threads_each_count_their_own_threads_cpu():
+    """One thread spins while the other sleeps, side by side: each span
+    reads its own thread's CPU clock, not the process's."""
+    got = {}
+    start = threading.Barrier(2)
+
+    def work(name, body):
+        start.wait()
+        with obs.span(name, category="stage") as sp:
+            body()
+        got[name] = sp
+
+    threads = [threading.Thread(target=work, args=("two-threads-busy",
+                                                   _spin)),
+               threading.Thread(target=work, args=("two-threads-idle",
+                                                   _sleep))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
+    assert got["two-threads-busy"].attrs["cpu_s"] > 0.03
+    assert got["two-threads-idle"].attrs["cpu_s"] < 0.02
+    assert got["two-threads-busy"].thread_id != \
+        got["two-threads-idle"].thread_id
+
+
+def test_offcpu_counter_loses_no_update_across_threads():
+    """More threads than cores close spans of one name at once, with the
+    interpreter switching threads as often as it can: the counter holds
+    every span's wall and CPU seconds."""
+    import sys
+
+    name = "offcpu-stress"
+    before = _seconds(name)
+    done = []
+    lock = threading.Lock()
+
+    def work():
+        mine = []
+        for _ in range(200):
+            with obs.span(name, category="stage") as sp:
+                pass
+            mine.append((sp.duration(), sp.attrs["cpu_s"]))
+        with lock:
+            done.extend(mine)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
     try:
-        dp.shard_depth_pipeline_cls_packed(*args, length=256,
-                                           window=256)
-        spans = [sp for sp in tracer.snapshot()
-                 if sp.name ==
-                 "device.shard_depth_pipeline_cls_packed"]
-        assert spans, "no device-event span recorded"
-        sp = spans[-1]
-        assert sp.attrs["fenced"] is True
-        assert sp.attrs["platform"] == "cpu"
-        assert "device_kind" in sp.attrs
-        # the vmapped wrapper traces the SAME proxied fn inside jit:
-        # the trace-state guard must keep instrumentation out of the
-        # traced program (this would raise otherwise)
-        from goleft_tpu.commands.depth import _batched_cls_packed
-
-        out = _batched_cls_packed()(
-            seg[None], seg[None], keep[None], i32(0), i32(0),
-            i32(256), i32(2500), i32(4), i32(0),
-            length=256, window=256)
-        assert np.asarray(out[0]).shape[0] == 1
+        threads = [threading.Thread(target=work)
+                   for _ in range(2 * (os.cpu_count() or 1) + 2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
     finally:
-        obs.set_device_events(False)
-    # off again: a call must not add device spans
-    n0 = sum(1 for sp in tracer.snapshot()
-             if sp.name == "device.shard_depth_pipeline_cls_packed")
-    dp.shard_depth_pipeline_cls_packed(*args, length=256, window=256)
-    n1 = sum(1 for sp in tracer.snapshot()
-             if sp.name == "device.shard_depth_pipeline_cls_packed")
-    assert n1 == n0
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert len(done) == 200 * len(threads)
+    after = _seconds(name)
+    for i in (0, 1):  # wall, cpu
+        assert after[i] - before[i] == pytest.approx(
+            sum(d[i] for d in done), rel=1e-9, abs=1e-9)
+
+
+def test_cli_runs_total_grows_by_one_per_main(tmp_path, capsys):
+    from goleft_tpu.cli import main as cli_main
+
+    bam = write_bam_and_bai(
+        str(tmp_path / "s.bam"),
+        random_reads(np.random.default_rng(40), 10, 0, 1_000),
+        ref_names=("chr1",), ref_lens=(1_000,),
+        header_text="@HD\tVN:1.6\tSO:coordinate\n"
+                    "@SQ\tSN:chr1\tLN:1000\n@RG\tID:r\tSM:s40\n")
+
+    def runs():
+        return obs.get_registry().counters().get("cli.runs_total", 0)
+
+    for _ in range(2):
+        before = runs()
+        assert cli_main(["samplename", bam]) == 0
+        assert capsys.readouterr().out.strip() == "s40"
+        assert runs() == before + 1
+
+
+# ---------------- the dispatch seam ----------------
 
 
 def test_instrumented_dispatch_forwards_jit_attrs():
@@ -463,7 +582,6 @@ def test_depth_cli_writes_trace_and_manifest(tmp_path, monkeypatch):
     # --trace-out fences nothing: what the timeline shows for the
     # device is where the host waited for it and fetched from it
     assert {"device-wait", "d2h"} <= names
-    assert not obs.get_tracer().device_events
     man = load_manifest(m_out)
     # (the file holds the process's whole ring, earlier tests' too)
     assert not any(e["name"].startswith("device.")
@@ -477,3 +595,36 @@ def test_depth_cli_writes_trace_and_manifest(tmp_path, monkeypatch):
     dev = jax.devices()[0]
     assert man["backend"]["platform"] == dev.platform
     assert man["backend"]["device_kind"] == dev.device_kind
+    # each stage span carries its thread's CPU seconds, and the run its
+    # counters of the stages' wall and CPU seconds
+    decode = next(e for e in doc["traceEvents"] if e.get("ph") == "X"
+                  and e["name"] == "host-decode"
+                  and e["args"]["trace_id"] == man["trace_id"])
+    assert 0.0 <= decode["args"]["cpu_s"]
+    counters = man["metrics"]["counters"]
+    assert counters["cli.runs_total"] >= 1
+    assert "span.wall_seconds_total.host-decode" in counters
+    assert "span.cpu_seconds_total.host-decode" in counters
+
+
+def test_cohortdepth_opens_each_input_under_a_span(tmp_path, capsys):
+    """The cohort's opening (a BAM handle and its index a sample, on the
+    load pool) is one ``open-inputs`` stage span a BAM, under the run's
+    trace, before the first ``host-decode``."""
+    from goleft_tpu.cli import main as cli_main
+    from test_prefetch import _golden_cohort
+
+    fa, bams = _golden_cohort(tmp_path)
+    assert cli_main(["cohortdepth", "--engine", "device", "-w", "100",
+                     "-r", fa, *bams]) == 0
+    assert capsys.readouterr().out.count("\n") == 21
+    spans = obs.get_tracer().snapshot()
+    root = [s for s in spans if s.name == "run.cohortdepth"][-1]
+    run = [s for s in spans if s.trace_id == root.trace_id]
+    opened = [s for s in run if s.name == "open-inputs"]
+    assert len(opened) == len(bams) == 3
+    assert {s.category for s in opened} == {"stage"}
+    assert all(s.parent_id == root.span_id and "cpu_s" in s.attrs
+               for s in opened)
+    first_decode = min(s.t0 for s in run if s.name == "host-decode")
+    assert max(s.t1 for s in opened) <= first_decode
