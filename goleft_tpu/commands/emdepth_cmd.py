@@ -92,7 +92,7 @@ def _batched_em(depths: np.ndarray, med=None, medmed=None,
 
     # multi-chip: the window axis is embarrassingly parallel, so padded
     # chunks shard across this host's devices and XLA partitions the
-    # vmapped EM as pure SPMD (no collectives); EM_CHUNK rows divide
+    # row-wise EM as pure SPMD (no collectives); EM_CHUNK rows divide
     # evenly. LOCAL devices only, and only in a single-process world: in
     # a multi-host cnv run process 0 alone reaches the EM (the others
     # returned after the gather), so a global mesh would address remote

@@ -184,10 +184,13 @@ def test_emdepth_em_chunk(one_chip):
     from goleft_tpu.models.emdepth import cn_batch, em_depth_batch
 
     d = spec(one_chip, (EM_CHUNK, 2504), jnp.float32)
-    compiled(em_depth_batch, d)
+    exes = [compiled(em_depth_batch, d)]
     lam = jax.eval_shape(em_depth_batch, d)
     assert lam.dtype == jnp.float32
-    compiled(cn_batch, spec(one_chip, lam.shape, lam.dtype), d)
+    exes.append(compiled(cn_batch, spec(one_chip, lam.shape, lam.dtype), d))
+    # no gather: the λ table is read by selects (test_emdepth_kernels.py)
+    for exe in exes:
+        assert " gather(" not in exe.as_text()
 
 
 def test_cohort_pca_gram_step(one_chip):
